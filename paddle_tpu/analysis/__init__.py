@@ -33,7 +33,7 @@ CLI: tools/tpu_lint.py (`make lint`). Docs: ANALYSIS.md. Fixture
 corpus: tests/lint_fixtures/ via tests/test_tpu_lint.py.
 
 This package is stdlib-only BY CONTRACT — importing jax (or anything
-that imports jax) here would claim the TPU grant from the lint CLI and
+that imports jax) here would start a jax backend from the lint CLI and
 blow the <60 s CI budget.
 """
 from .diagnostics import Diagnostic, Severity, format_text  # noqa: F401

@@ -2,7 +2,7 @@
 promotions from dy2static / the collective layer).
 
 Deliberately stdlib-only: the linter must run on a cold CPU interpreter
-in CI without importing jax (no TPU grant, <60 s budget — see
+in CI without importing jax (no backend start-up, <60 s budget — see
 ANALYSIS.md), and the runtime recorders in `paddle_tpu.jit.dy2static` /
 `paddle_tpu.distributed.collective` import this module from inside the
 package, so it must stay dependency-free in both directions.

@@ -16,10 +16,7 @@ Two entry levels:
 from __future__ import annotations
 
 import jax
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental
-    from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.ring_attention import ring_flash_attention, ulysses_attention

@@ -105,10 +105,7 @@ def collective_perf(comm_type="allreduce", round=5, size_and_time=None):
         n = size // 4
         x = jnp.ones((max(n, 8),), jnp.float32)
         if mesh is not None and mesh.devices.size > 1:
-            try:
-                from jax import shard_map
-            except ImportError:  # older jax: experimental
-                from ...jax_compat import shard_map
+            from jax import shard_map
             f = jax.jit(shard_map(lambda a: jax.lax.psum(a, "data"),
                                   mesh=mesh,
                                   in_specs=P("data"), out_specs=P()))

@@ -37,10 +37,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental
-    from ..jax_compat import shard_map
+from jax import shard_map
 
 __all__ = ["pipeline_forward", "stack_stage_params", "PipelineMicroScheduler"]
 

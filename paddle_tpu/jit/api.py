@@ -553,6 +553,20 @@ class TracedFunction:
         rep["op_counts"] = counts
         return rep
 
+    def compiled_texts(self) -> List[str]:
+        """Optimized HLO text of every compiled program in the guard
+        cache (same re-lowering contract as cost_report). What a chip
+        check reads to prove a Pallas kernel is IN the program — a
+        `tpu_custom_call` — and not an interpret-mode or XLA stand-in.
+        Raises when a program cannot be re-lowered: a check must not
+        pass on a text it never saw."""
+        rep = self._account_programs(
+            lambda lowered: {"text": lowered.compile().as_text()})
+        errors = [p["error"] for p in rep["programs"] if "error" in p]
+        if errors:
+            raise RuntimeError(f"re-lowering failed: {errors}")
+        return [p["text"] for p in rep["programs"]]
+
     def _track_value(self, key, name, v):
         """One signature entry for a guarded value (closure cell or
         module global). Entries carry a type tag ("t"ensor / "s"calar /

@@ -43,10 +43,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..jax_compat import patch_pltpu
-
-patch_pltpu()
-
 __all__ = ["flash_attention_bshd", "flash_attention_varlen_bshd",
            "flashmask_attention_bshd"]
 

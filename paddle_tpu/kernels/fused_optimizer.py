@@ -1,7 +1,7 @@
 """Fused multi-tensor AdamW update — one Pallas pass over flat buckets.
 
-Round-4 measured the AdamW update AT the HBM roofline (~21 ms for 608M
-fp32 states, RELAY_STATUS.md r4): the update is pure bytes, so the only
+An early chip reading put the AdamW update AT the HBM roofline (~21 ms
+for 608M fp32 states; not re-measured since): the update is pure bytes, so the only
 levers left are (a) narrower state bytes (bf16 moments, already
 storable via `moment_dtype="bfloat16"`) and (b) ONE read and ONE write
 per state byte instead of the per-parameter upcast/downcast round trips
@@ -27,7 +27,7 @@ SCALAR PREFETCH (an fp32 vector in SMEM) so a changing step count never
 recompiles the kernel. Block rows are picked against the SAME A3 VMEM
 estimator tpu-lint runs (`analysis/vmem.py::fits_vmem`,
 `fp32_copies=5` for the g/w/m/v/update fp32 temporaries a block
-materializes) — `pick_block_rows_fused` is the chip-blind cross-check
+materializes) — `pick_block_rows_fused` is the off-chip cross-check
 anchor for the lint fixtures. Untileable-or-tiny buckets and the
 ZeRO-1 path use `_adamw_math` through XLA instead (`use_pallas=False`):
 under GSPMD a pallas_call is an opaque custom call the partitioner can
@@ -55,12 +55,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..jax_compat import patch_pltpu
-
-patch_pltpu()
-
-from .flash_attention import _I0, _interpret_mode  # noqa: E402
-from ..analysis.vmem import fits_vmem  # noqa: E402
+from .flash_attention import _I0, _interpret_mode
+from ..analysis.vmem import fits_vmem
 
 __all__ = ["BucketLayout", "build_bucket_layout", "pack_bucket",
            "unpack_bucket", "adamw_scalars", "adamw_update_bytes",

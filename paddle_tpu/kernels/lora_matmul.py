@@ -52,10 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..analysis.vmem import estimate_vmem_bytes, VMEM_BUDGET_BYTES
-from ..jax_compat import patch_pltpu
 from .flash_attention import _interpret_mode
-
-patch_pltpu()
 
 __all__ = ["lora_matmul", "lora_matmul_xla", "lora_matmul_supported",
            "pick_lora_blocks", "lora_blockspecs", "lora_delta_bytes"]

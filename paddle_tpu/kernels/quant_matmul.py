@@ -43,10 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..analysis.vmem import estimate_vmem_bytes, VMEM_BUDGET_BYTES
-from ..jax_compat import patch_pltpu
 from .flash_attention import _interpret_mode
-
-patch_pltpu()
 
 __all__ = ["quant_matmul", "quant_matmul_supported", "pick_quant_blocks",
            "quant_matmul_blockspecs", "dequant_matmul_xla"]

@@ -29,7 +29,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jax_compat import axis_size as _axis_size
 
 from .flash_attention import _bwd_with_delta as _flash_step_bwd
 from .flash_attention import _fwd as _flash_step_fwd
@@ -68,7 +67,7 @@ def _combine(o_acc, l_acc, o_j, lse_j):
 def _ring_fwd(q, k, v, sm_scale, causal, axis_name, rep, block_q, block_k):
     """q: (B*H, S, D); k, v: (B*Hkv, S, D) local shards. Returns
     (out (B*H,S,D) in q.dtype, lse (B*H,S) f32)."""
-    P_ = _axis_size(axis_name)
+    P_ = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % P_) for i in range(P_)]
     BH, S, D = q.shape
@@ -113,7 +112,7 @@ def _ring_bwd_loop(q, k, v, out, lse, dout, sm_scale, causal, axis_name, rep,
     """Rotate the (q, do, delta, lse, dq) bundle around the ring; accumulate
     dk/dv at the local K/V owner; dq returns home after P hops. delta is
     precomputed at the query owner so the full output never travels."""
-    P_ = _axis_size(axis_name)
+    P_ = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % P_) for i in range(P_)]
     BH, S, D = q.shape
@@ -251,7 +250,7 @@ def ulysses_attention(q, k, v, axis_name="sep", causal=True, sm_scale=None,
     the axis size (Hkv is head-repeated if needed). Differentiable through
     all_to_all — no custom vjp required.
     """
-    P_ = _axis_size(axis_name)
+    P_ = lax.axis_size(axis_name)
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     if H % Hkv != 0:

@@ -20,7 +20,7 @@ class ClipGradByGlobalNorm:
         """Global-norm clip over fp32 UPCASTS of the raw gradients —
         fully on-device and traceable (a leftover host-fetch `float()`
         reduction here used to break the whole train step out of
-        to_static AND pay a per-step relay round trip). The scale is a
+        to_static AND pay a per-step host round trip). The scale is a
         function of the gradients only: `moment_dtype`/`fused` narrow
         optimizer STORAGE after clipping, so the clip sees identical
         fp32 values whatever the accumulators store

@@ -11,6 +11,7 @@ num_heads, head_dim).
 """
 from __future__ import annotations
 
+import collections
 import math
 
 import jax
@@ -23,6 +24,34 @@ __all__ = ["scaled_dot_product_attention", "flash_attention",
            "flash_attn_unpadded", "flashmask_attention", "sdp_kernel"]
 
 _USE_PALLAS = [True]
+
+# Every call the Pallas path refused and the XLA composition served
+# instead, by (api, q shape, k shape, dtype, reason). Falling through
+# is legal (decode's Sq=1, odd test shapes) but must be countable: a
+# chip run asserts its shapes took the kernel. Also exposed as the
+# "attention_pallas_refusals" provider of profiler.counters().
+_refusals: collections.Counter = collections.Counter()
+
+
+def _refusal_counters() -> dict:
+    return {repr(k): n for k, n in _refusals.items()}
+
+
+def _note_refusal(api, q_shape, k_shape, dtype, err):
+    from ... import profiler        # lazy, like ops.dispatch's hook
+    profiler.register_counter_provider("attention_pallas_refusals",
+                                       _refusal_counters)   # idempotent
+    _refusals[(api, tuple(q_shape), tuple(k_shape), str(dtype),
+               str(err))] += 1
+
+
+def pallas_refusals(reset=False) -> dict:
+    """{(api, q_shape, k_shape, dtype, reason): calls} served by the XLA
+    composition because the Pallas kernel refused the shape."""
+    out = dict(_refusals)
+    if reset:
+        _refusals.clear()
+    return out
 
 
 def _sdpa_ref(q, k, v, mask=None, dropout_p=0.0, causal=False, key=None,
@@ -52,6 +81,37 @@ def _sdpa_ref(q, k, v, mask=None, dropout_p=0.0, causal=False, key=None,
     return jnp.swapaxes(out, 1, 2)  # B,S,H,D
 
 
+def _per_shard(fn, q_shape, k_shape):
+    """`fn(q, k, v)` over (B, S, H, D) under the ambient hybrid mesh.
+    GSPMD cannot partition a Mosaic kernel ("cannot be automatically
+    partitioned"), so with a multi-device mesh live the kernel runs
+    inside a shard_map manual over EVERY axis: batch split over 'data',
+    heads over 'model' (wherever the axis divides them; replicated
+    otherwise). Each shard attends its own (batch, head) slice, which
+    needs no collective. A mesh that splits the sequence ('sep') needs
+    the ring kernel, and inside the pipeline's partial-manual map no
+    second map can open: both raise ValueError (-> XLA composition)."""
+    from ...distributed.fleet.mpu import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    sizes = dict(mesh.shape)
+    for axis in ("sep", "pipe"):
+        if sizes.get(axis, 1) > 1:
+            raise ValueError(f"flash kernel cannot run per shard of a "
+                             f"{axis!r}-split mesh {sizes}")
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    batch = "data" if sizes.get("data", 1) > 1 and \
+        q_shape[0] % sizes["data"] == 0 else None
+    heads = "model" if sizes.get("model", 1) > 1 and \
+        q_shape[2] % sizes["model"] == 0 and \
+        k_shape[2] % sizes["model"] == 0 else None
+    spec = P(batch, None, heads, None)
+    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)
+
+
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None):
@@ -64,9 +124,14 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                 tuple(query.shape), tuple(key.shape), query.dtype)
             def _f(q, k, v):
                 return pallas_fa.flash_attention_bshd(q, k, v, causal=is_causal)
-            return apply_op("flash_attention", _f, query, key, value)
-        except ValueError:
-            pass  # unsupported shape: fall through to the XLA composition
+            return apply_op(
+                "flash_attention",
+                _per_shard(_f, tuple(query.shape), tuple(key.shape)),
+                query, key, value)
+        except ValueError as e:
+            # unsupported shape: fall through to the XLA composition
+            _note_refusal("scaled_dot_product_attention", query.shape,
+                          key.shape, query.dtype, e)
     drop_key = rng_key() if (dropout_p > 0.0 and training) else None
     def _f(q, k, v, m):
         return _sdpa_ref(q, k, v, m, dropout_p, is_causal, drop_key, training)
@@ -138,8 +203,9 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
             out = apply_op("flash_attn_unpadded", _f, query, key, value,
                            cu_seqlens_q, cu_seqlens_k)
             return out, None
-        except ValueError:
-            pass
+        except ValueError as e:
+            _note_refusal("flash_attn_unpadded", query.shape, key.shape,
+                          query.dtype, e)
 
     drop_key = rng_key() if (dropout > 0.0 and training) else None
 
@@ -215,8 +281,9 @@ def flashmask_attention(query, key, value, startend_row_indices=None,
 
             return apply_op("flashmask_attention", _f, query, key, value,
                             startend_row_indices)
-        except ValueError:
-            pass
+        except ValueError as e:
+            _note_refusal("flashmask_attention", query.shape, key.shape,
+                          query.dtype, e)
 
     def _build_mask(idx, sq, sk):
         # idx: (B, H, Sk, C); rows r of column c are masked per bounds

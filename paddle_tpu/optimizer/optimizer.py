@@ -15,8 +15,8 @@ bf16 optimizer states (TPU-native extension): `moment_dtype="bfloat16"`
 (or FLAGS_bf16_optimizer_states=1 as the global default) STORES every
 accumulator in bf16 while the update math still runs in fp32 (upcast on
 read, downcast on store; master weights stay fp32). The AdamW update is
-HBM-bound at the roofline (measured ~21 ms for 608M fp32 states,
-RELAY_STATUS.md r4), so halving the moment bytes is the one remaining
+HBM-bound at the roofline (an early chip reading of ~21 ms for 608M
+fp32 states, not re-measured since), so halving the moment bytes is the one remaining
 flagship-MFU lever. Reference analog: the low-precision moments path of
 fused_adam / PaddleNLP's bf16 optimizer
 (paddle/phi/kernels/fusion/gpu/fused_adam_kernel.cu uses MT=fp32 compute

@@ -41,9 +41,9 @@ Reading the numbers honestly:
 Consumers: `TracedFunction.comm_report()` (jit/api.py, beside
 `cost_report()`), the serving `ProgramCache.comm_table()`, `bench.py`'s
 `comm_bytes`/`comm_bytes_per_axis` JSON fields, the
-`dryrun_multichip` evidence line, and the chip_hour COMM step
-(tools/chip_comm.py). All analysis failures degrade to an error record
-— accounting must never take down the program it describes.
+`dryrun_multichip` evidence line, and tools/chip_comm.py. All analysis
+failures degrade to an error record — accounting must never take down
+the program it describes.
 """
 from __future__ import annotations
 
@@ -77,6 +77,9 @@ _SHAPE_RE = re.compile(
 _INSTR_RE = re.compile(
     r"=\s*(?P<result>[^=]*?)\s(?P<kind>"
     + "|".join(COLLECTIVE_KINDS) + r")(?P<async>-start)?\(")
+# any instruction's definition head: "[ROOT] %name = " (shape follows)
+_DEF_RE = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*")
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _EXPLICIT_GROUPS_RE = re.compile(r"\{\{[0-9,{} ]*\}\}|\{\}")
 _IOTA_GROUPS_RE = re.compile(
     r"\[([0-9,]+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
@@ -190,25 +193,48 @@ class CollectiveOp:
                 f"axis={self.axis_label}, groups of {self.group_size})")
 
 
+def _close_paren(text: str, start: int) -> int:
+    """Index just past the ')' closing the '(' whose inside starts at
+    `start` (len(text) when it never closes)."""
+    depth, end = 1, start
+    while end < len(text) and depth:
+        depth += (text[end] == "(") - (text[end] == ")")
+        end += 1
+    return end
+
+
+def _result_shapes(lines: Sequence[str]) -> Dict[str, str]:
+    """{instruction name: its result-shape text} for every `%name = shape
+    op(...)` line. The installed jax prints operands by name only
+    (`all-reduce(%param.1)`), so an operand's shape is its definition's."""
+    shapes: Dict[str, str] = {}
+    for line in lines:
+        m = _DEF_RE.match(line)
+        if m is None:
+            continue
+        rest = line[m.end():]
+        if rest.startswith("("):        # tuple shape: up to its closing paren
+            shapes[m.group(1)] = rest[:_close_paren(rest, 1)]
+        else:
+            shapes[m.group(1)] = rest.split(" ", 1)[0]
+    return shapes
+
+
 def parse_hlo_collectives(hlo_text: str) -> List[CollectiveOp]:
     """Every collective instruction in an HLO module text. `-done` halves
     of async pairs carry no shape/group info of their own and are
     skipped (the `-start` is the accounted op)."""
     ops: List[CollectiveOp] = []
-    for line in hlo_text.splitlines():
+    lines = hlo_text.splitlines()
+    shapes = None
+    for line in lines:
         m = _INSTR_RE.search(line)
         if m is None:
             continue
         kind = m.group("kind")
         # operand text: between the op's '(' and its matching ')'
         start = m.end()
-        depth, end = 1, start
-        while end < len(line) and depth:
-            if line[end] == "(":
-                depth += 1
-            elif line[end] == ")":
-                depth -= 1
-            end += 1
+        end = _close_paren(line, start)
         operand_text = line[start:end - 1]
         attr_text = line[end:]
         # metadata repeats the source op name; groups/pairs live in the
@@ -222,9 +248,17 @@ def parse_hlo_collectives(hlo_text: str) -> List[CollectiveOp]:
         else:
             groups = parse_replica_groups(attr_text)
             group_size = len(groups[0]) if groups else 0
+        operand_bytes = _shape_bytes(operand_text)
+        if not operand_bytes:
+            # operands printed by name only: resolve each definition
+            if shapes is None:
+                shapes = _result_shapes(lines)
+            operand_bytes = sum(
+                _shape_bytes(shapes.get(name, ""))
+                for name in _OPERAND_NAME_RE.findall(operand_text))
         ops.append(CollectiveOp(
             kind=kind,
-            operand_bytes=_shape_bytes(operand_text),
+            operand_bytes=operand_bytes,
             result_bytes=_shape_bytes(m.group("result")),
             groups=groups, group_size=group_size))
     return ops

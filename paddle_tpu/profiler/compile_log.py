@@ -22,7 +22,7 @@ compile timeline), `profiler.TrainingMonitor` (per-step event deltas +
 Prometheus counters), `tools/train_report.py` (offline timeline).
 
 Deliberately stdlib-only and jax-free: importing this module must never
-claim the TPU grant (CLAUDE.md), and the serving ProgramCache logs
+initialize a jax backend (one process per chip), and the serving ProgramCache logs
 through it from inside engine hot paths.
 """
 from __future__ import annotations

@@ -32,35 +32,47 @@ Reading the numbers honestly:
   number (`bench.py` reports it as `peak_hbm_bytes`).
 
 Consumers: `TracedFunction.cost_report()` (jit/api.py), the serving
-`ProgramCache.cost_table()`, `bench.py`'s JSON line, and the
-chip_hour COST_MFU step.
+`ProgramCache.cost_table()` and `bench.py`'s JSON line.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
 __all__ = ["ProgramCost", "compiled_cost", "lowered_cost", "jit_cost",
-           "shape_structs", "peak_flops_per_chip", "analytic_mfu",
-           "PEAK_FLOPS"]
+           "shape_structs", "chip_peaks", "peak_flops_per_chip",
+           "analytic_mfu", "CHIP_PEAKS"]
 
-# bf16 peak FLOP/s per chip by device kind — the table bench.py carries
-# (tests assert the two agree; bench.py must stay import-light because
-# its supervisor never touches the package).
-PEAK_FLOPS = {
-    "v5 lite": 197e12, "v5e": 197e12, "v5litepod": 197e12,
-    "v5p": 459e12, "v5": 459e12,
-    "v4": 275e12,
-    "v6": 918e12, "v6e": 918e12, "trillium": 918e12,
-    "cpu": 1e12,  # nominal, CPU is correctness-only
+# Published per-chip peaks: (bf16 FLOP/s, HBM bytes/s), matched as a
+# substring of the lower-cased `device_kind` JAX reports ("TPU v5 lite"
+# is a v5e), most specific first. Source: Google Cloud TPU documentation,
+# the "TPU v5e" / "TPU v5p" / "TPU v4" / "TPU v6e" system-architecture
+# pages. The ONE table of the tree: a device that is not in it is an
+# error, never a default — a share of an invented peak is not a number.
+CHIP_PEAKS = {
+    "v5 lite": (197e12, 819e9), "v5e": (197e12, 819e9),
+    "v5litepod": (197e12, 819e9),
+    "v5p": (459e12, 2765e9), "v5": (459e12, 2765e9),
+    "v4": (275e12, 1228e9),
+    "v6 lite": (918e12, 1640e9), "v6e": (918e12, 1640e9),
+    "trillium": (918e12, 1640e9),
 }
 
 
-def peak_flops_per_chip(device_kind: str) -> float:
+def chip_peaks(device_kind: str):
+    """(bf16 peak FLOP/s, HBM peak bytes/s) of one chip of `device_kind`;
+    raises ValueError for a device the table does not hold."""
     kind = str(device_kind).lower()
-    for k, v in PEAK_FLOPS.items():
+    for k, v in CHIP_PEAKS.items():
         if k in kind:
             return v
-    return 197e12
+    raise ValueError(
+        f"no published peaks for device kind {device_kind!r} (known: "
+        f"{sorted(CHIP_PEAKS)}); add it to profiler/cost.py CHIP_PEAKS "
+        f"with its source before quoting a utilization")
+
+
+def peak_flops_per_chip(device_kind: str) -> float:
+    return chip_peaks(device_kind)[0]
 
 
 class ProgramCost:
@@ -192,7 +204,9 @@ def lowered_cost(lowered) -> ProgramCost:
 def shape_structs(tree):
     """Abstract a pytree of arrays to ShapeDtypeStructs (non-array
     leaves pass through), so a program can be re-lowered for accounting
-    without holding or moving any data."""
+    without holding or moving any data. An array that was placed keeps
+    its sharding, so a mesh-sharded program re-lowers as the program
+    that ran and not as its one-device twin."""
     import jax
 
     def _abs(leaf):
@@ -200,7 +214,9 @@ def shape_structs(tree):
         dtype = getattr(leaf, "dtype", None)
         if shape is None or dtype is None:
             return leaf
-        return jax.ShapeDtypeStruct(tuple(shape), dtype)
+        placed = isinstance(leaf, jax.Array) and leaf.committed
+        return jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=leaf.sharding if placed else None)
     return jax.tree_util.tree_map(_abs, tree)
 
 

@@ -23,7 +23,7 @@ process holding the same geometry:
 Entry format (one file per key, name = sha1(key repr)):
 
     line 1: header JSON {magic, format, fingerprint, key, body_sha256,
-            body_len, saved_unix}
+            body_len, saved_unix, devices}
     rest:   the pickled (payload, in_tree, out_tree) triple
 
 The fingerprint folds in jax/jaxlib versions, backend, device kind and
@@ -57,7 +57,7 @@ __all__ = ["CompileCache", "cache_fingerprint", "FORMAT_VERSION",
            "FAULT_CORRUPT"]
 
 MAGIC = "PTCC"
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2      # 2: the header names the execution devices
 
 # Fires in _read_entry with the raw body in hand: a payload means "the
 # disk lied" — bytes are flipped BEFORE checksum verification, so the
@@ -143,7 +143,12 @@ class CompileCache:
         header = {"magic": MAGIC, "format": FORMAT_VERSION,
                   "fingerprint": self.fingerprint(), "key": repr(key),
                   "body_sha256": hashlib.sha256(body).hexdigest(),
-                  "body_len": len(body), "saved_unix": int(time.time())}
+                  "body_len": len(body), "saved_unix": int(time.time()),
+                  # the devices the program runs on, in assignment
+                  # order: a load must name them, or the installed jax
+                  # spreads the executable over EVERY local device
+                  "devices": [d.id for d in
+                              compiled.runtime_executable().local_devices()]}
         os.makedirs(self.path, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=self.path, suffix=".tmp")
         try:
@@ -179,7 +184,7 @@ class CompileCache:
                     self._header_ok(self.entry_path(key)):
                 continue
             try:
-                compiled = fn.lower(*avals).compile()
+                compiled = prog.lower().compile()
             except Exception:                             # noqa: BLE001
                 continue
             if self.save_entry(key, compiled):
@@ -228,7 +233,7 @@ class CompileCache:
                              f"{h.get('body_len')}")
         if hashlib.sha256(body).hexdigest() != h.get("body_sha256"):
             raise ValueError("body checksum mismatch")
-        return pickle.loads(body)
+        return pickle.loads(body) + (h.get("devices"),)
 
     def load(self, key: tuple):
         """The deserialized executable for `key`, or None (counted as
@@ -239,10 +244,14 @@ class CompileCache:
             self.counters["misses"] += 1
             return None
         try:
-            payload, in_tree, out_tree = self._read_entry(key)
+            payload, in_tree, out_tree, dev_ids = self._read_entry(key)
+            import jax
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
-            loaded = deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in dev_ids])
         except Exception:                                 # noqa: BLE001
             self.counters["rejects"] += 1
             self.rejected_keys.add(key)

@@ -7,7 +7,7 @@ string-matching messages:
 * `EngineOverloaded` — admission control shed the request (bounded
   queue); retry-after semantics belong to the caller.
 * `TransientDeviceError` — a device/transport error the supervisor
-  believes is retryable (UNAVAILABLE, relay loss). Raised internally
+  believes is retryable (UNAVAILABLE, connection loss). Raised internally
   and by fault injection; callers normally never see it because the
   supervisor retries it away.
 * `PoisonedComputation` — a deterministic numeric failure (NaN/Inf)

@@ -110,6 +110,15 @@ class _TrackedProgram:
             return out
         return self.fn(*args)
 
+    def lower(self):
+        """Re-lower this program from the recorded arg avals. Under
+        no_grad like every launch site: a retrace in grad mode would
+        send the paged-attention kernel through jax.vjp, which Pallas
+        refuses for scalar-prefetch grids."""
+        from ..core.autograd import no_grad
+        with no_grad():
+            return self.fn.lower(*self.arg_avals)
+
     def cost_report(self) -> Optional[dict]:
         """XLA cost/memory accounting of this program (lazy, cached):
         re-lowers from the recorded arg avals — only possible for
@@ -120,8 +129,7 @@ class _TrackedProgram:
             return None
         try:
             from ..profiler import cost as _cost
-            rec = _cost.lowered_cost(
-                self.fn.lower(*self.arg_avals)).to_dict()
+            rec = _cost.lowered_cost(self.lower()).to_dict()
         except Exception as e:   # accounting must never break serving
             # transient failures are NOT cached — the next call retries
             rec = {"error": f"{type(e).__name__}: {e}"[:200]}
@@ -152,8 +160,7 @@ class _TrackedProgram:
         if self.arg_avals is None or not hasattr(self.fn, "lower"):
             return None
         try:
-            rec = _comm.lowered_comm(
-                self.fn.lower(*self.arg_avals), mesh=mesh).to_dict()
+            rec = _comm.lowered_comm(self.lower(), mesh=mesh).to_dict()
         except Exception as e:   # accounting must never break serving
             # transient failures are NOT cached — the next call retries
             return {"error": f"{type(e).__name__}: {e}"[:200]}
@@ -279,6 +286,11 @@ class ProgramCache:
         attribution; `ServingEngine.comm_table()` does."""
         return {k: p.comm_report(mesh=mesh)
                 for k, p in self._programs.items()}
+
+    def compiled_text(self, key: tuple) -> str:
+        """Optimized HLO text of one launched program — where a chip
+        check looks for the paged-attention `tpu_custom_call`."""
+        return self._programs[key].lower().compile().as_text()
 
     def family_costs(self) -> Dict[str, dict]:
         """Per-family aggregate of cost_table(): program count, summed

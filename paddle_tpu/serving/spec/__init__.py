@@ -1,7 +1,7 @@
 """paddle_tpu.serving.spec — speculative decoding for the serving engine.
 
 Decode is memory-bandwidth-bound (the paged kernel runs near the HBM
-roofline — BENCH_OPS/RELAY_STATUS), so per-sequence tokens/step is the
+roofline — BENCH_OPS.md), so per-sequence tokens/step is the
 remaining throughput lever. The reference serves this need through its
 fused multi-token attention paths (`block_multi_head_attention` /
 `masked_multihead_attention`, SURVEY A.2); the TPU-native analog built

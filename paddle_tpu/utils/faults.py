@@ -33,9 +33,7 @@ compile cache's read path. The disaggregated prefill/decode tier
 each armed kv_page send — mid-stream death), `fleet.handoff_stall`
 (the supervisor's kv frame relay eats the frame — phase-deadline
 trigger; host-armed) and `fleet.decode_reject` (the adopt handler
-refuses the batch with a typed reject). `bench.py` uses the
-BENCH_FAULT_INJECT env var instead — its supervisor must stay
-importable without this package.
+refuses the batch with a typed reject).
 """
 from __future__ import annotations
 

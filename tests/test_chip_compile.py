@@ -1,0 +1,232 @@
+"""The main path's Pallas kernels compiled for a DESCRIBED TPU v5e, at the
+widths chip_smoke.py runs (Llama-2-7B train, Llama-3-8B serve).
+
+Interpret mode on the CPU hides what Mosaic refuses (unaligned slices,
+VMEM budgets, i64 under the package's x64 mode, kernels GSPMD would have
+to partition). The TPU compiler is installed here and compiles for a
+topology that is described, not attached — so these cases guard every
+later PR at no chip time. Nothing runs: a passing compile is not a chip
+run.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that skips when it
+cannot be — never at import, never in conftest.py, never autouse; every
+compile happens in the test's own process (the worker that loaded
+libtpu keeps its lock); all cases live in this ONE file so one xdist
+worker owns them; JAX's persistent cache is off around them (an entry
+compiled for a described chip cannot be read back without one).
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from paddle_tpu.kernels import flash_attention as fa
+
+MARKER = "tpu_custom_call"
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_mesh(topo):
+    """mesh(dp, mp) over the four described chips, on the hybrid axes."""
+    from paddle_tpu.distributed.fleet.topology import build_mesh
+
+    def mesh(dp, mp):
+        return build_mesh(dp=dp, mp=mp, devices=list(topo.devices))
+    return mesh
+
+
+@pytest.fixture()
+def for_chip():
+    """Steer the kernels to the Mosaic lowering (off-TPU they default to
+    interpret mode, cached for the process) and keep the persistent
+    compilation cache out of it; both restored after the test."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev_interpret = fa._INTERPRET_CACHE[0]
+    prev_cache = jax.config.jax_enable_compilation_cache
+    fa._INTERPRET_CACHE[0] = False
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        fa._INTERPRET_CACHE[0] = prev_interpret
+        jax.config.update("jax_enable_compilation_cache", prev_cache)
+        cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# Train phase: batch 2 x seq 2048, 32 heads x 128 (LlamaConfig() widths).
+QKV = (2, 2048, 32, 128)
+
+
+def test_flash_forward(for_chip, one_chip):
+    q = _sds(QKV, BF16, one_chip)
+    text = _compiled_text(
+        lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=True),
+        q, q, q)
+    assert MARKER in text
+
+
+def test_flash_backward(for_chip, one_chip):
+    q = _sds(QKV, BF16, one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention_bshd(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert text.count(MARKER) >= 2          # forward + backward kernels
+
+
+def test_flash_varlen(for_chip, one_chip):
+    q = _sds((1, 4096, 32, 128), BF16, one_chip)
+    seg = _sds((1, 4096), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, sq, sk: fa.flash_attention_varlen_bshd(
+            q, k, v, sq, sk, causal=True), q, q, q, seg, seg)
+    assert MARKER in text
+
+
+def test_flashmask(for_chip, one_chip):
+    q = _sds(QKV, BF16, one_chip)
+    idx = _sds((2, 1, 2048, 1), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, i: fa.flashmask_attention_bshd(q, k, v, i,
+                                                       causal=True),
+        q, q, q, idx)
+    assert MARKER in text
+
+
+def test_flash_per_shard_under_hybrid_mesh(for_chip, chip_mesh):
+    """The sdpa functional under a live dp2 x mp2 mesh: GSPMD cannot
+    partition a Mosaic kernel, so the call must arrive wrapped in a
+    shard_map (batch over 'data', heads over 'model')."""
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet.mpu import mesh_scope
+    from paddle_tpu.nn.functional.flash_attention import (
+        pallas_refusals, scaled_dot_product_attention)
+    mesh = chip_mesh(2, 2)
+    q = _sds(QKV, BF16, NamedSharding(mesh, P("data", None, "model", None)))
+
+    def attend(q, k, v):
+        with paddle.no_grad():
+            return scaled_dot_product_attention(
+                paddle.Tensor(q), paddle.Tensor(k), paddle.Tensor(v),
+                is_causal=True)._data
+
+    before = sum(pallas_refusals().values())
+    with mesh_scope(mesh):
+        text = _compiled_text(attend, q, q, q)
+    assert MARKER in text
+    assert sum(pallas_refusals().values()) == before    # kernel was taken
+
+
+# Serve phase: llama_3_8b() widths, batch bucket 8, the 1,024-page pool.
+@pytest.mark.parametrize("page,kv_dtype", [(16, None), (128, "int8")])
+def test_paged_attention_decode(for_chip, one_chip, page, kv_dtype):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    B, H, KVH, D, pages = 8, 32, 8, 128, 1024
+    q = _sds((B, H, D), BF16, one_chip)
+    cache = _sds((pages, KVH, page, D),
+                 jnp.int8 if kv_dtype else BF16, one_chip)
+    bt = _sds((B, 2048 // page), jnp.int32, one_chip)
+    sl = _sds((B,), jnp.int32, one_chip)
+    if kv_dtype:
+        scale = _sds((pages, KVH, page), jnp.float32, one_chip)
+        text = _compiled_text(
+            lambda q, k, v, bt, sl, ks, vs: paged_attention_decode(
+                q, k, v, bt, sl, k_scale=ks, v_scale=vs),
+            q, cache, cache, bt, sl, scale, scale)
+    else:
+        text = _compiled_text(paged_attention_decode, q, cache, cache, bt, sl)
+    assert MARKER in text
+
+
+def test_paged_attention_decode_tp4(for_chip, chip_mesh):
+    """The TP lowering: a fully manual shard_map over the four chips
+    (Mosaic refuses the kernel under a partial-manual map)."""
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode_tp
+    mesh = chip_mesh(1, 4)
+
+    def ns(*spec):
+        return NamedSharding(mesh, P(*spec))
+
+    B, H, KVH, D, pages, page = 8, 32, 8, 128, 1024, 16
+    q = _sds((B, H, D), BF16, ns(None, "model", None))
+    cache = _sds((pages, KVH, page, D), BF16, ns(None, "model", None, None))
+    bt = _sds((B, 128), jnp.int32, ns())
+    sl = _sds((B,), jnp.int32, ns())
+    text = _compiled_text(
+        lambda q, k, v, bt, sl: paged_attention_decode_tp(q, k, v, bt, sl,
+                                                          mesh),
+        q, cache, cache, bt, sl)
+    assert MARKER in text
+
+
+def test_quant_matmul(for_chip, one_chip):
+    from paddle_tpu.kernels.quant_matmul import quant_matmul
+    M, K, N = 32, 4096, 14336
+    text = _compiled_text(
+        quant_matmul, _sds((M, K), BF16, one_chip),
+        _sds((K, N), jnp.int8, one_chip), _sds((N,), BF16, one_chip))
+    assert MARKER in text
+
+
+def test_lora_matmul(for_chip, one_chip):
+    from paddle_tpu.kernels.lora_matmul import lora_matmul
+    B, H, R, N, S = 8, 4096, 16, 4096, 4
+    text = _compiled_text(
+        lora_matmul, _sds((B, H), BF16, one_chip),
+        _sds((B,), jnp.int32, one_chip),
+        _sds((S, H, R), jnp.float32, one_chip),
+        _sds((S, R, N), jnp.float32, one_chip))
+    assert MARKER in text
+
+
+@pytest.mark.parametrize("moment_dtype", [jnp.float32, BF16])
+def test_fused_adamw_bucket(for_chip, one_chip, moment_dtype):
+    from paddle_tpu.kernels.fused_optimizer import (LANES, adamw_scalars,
+                                                    fused_adamw_bucket)
+    rows = 1 << 20
+    scalars = adamw_scalars(1e-3, 0.9, 0.999, 1e-8, 0.01, 1)
+    bucket = _sds((rows, LANES), jnp.float32, one_chip)
+    grads = _sds((rows, LANES), BF16, one_chip)
+    moment = _sds((rows, LANES), moment_dtype, one_chip)
+    text = _compiled_text(
+        lambda g, w, m, v, s: fused_adamw_bucket(g, w, m, v, s,
+                                                 param_dtype=BF16),
+        grads, bucket, moment, moment,
+        _sds(np.shape(scalars), jnp.float32, one_chip))
+    assert MARKER in text
+
+
+def test_rms_norm_rows(for_chip, one_chip):
+    from paddle_tpu.kernels.fused_norm import rms_norm_rows
+    text = _compiled_text(rms_norm_rows, _sds((2048, 4096), BF16, one_chip),
+                          _sds((4096,), BF16, one_chip))
+    assert MARKER in text

@@ -10,10 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental
-    from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.kernels.ring_attention import (ring_flash_attention,

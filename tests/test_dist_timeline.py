@@ -200,9 +200,9 @@ def test_cross_host_merge_is_flagged(tmp_path):
 
 
 def test_dist_report_is_stdlib_only():
-    """Importing the reporter must not drag in jax (a plain python start
-    claims the TPU grant — the tool must run while a fleet holds the
-    chip). The --demo path is the documented exception."""
+    """Importing the reporter must not drag in jax (a process that
+    starts a jax backend may take the chip — the tool must run while a
+    fleet holds it). The --demo path is the documented exception."""
     out = subprocess.run(
         [sys.executable, "-c",
          "import sys; sys.path.insert(0, 'tools'); import dist_report; "

@@ -302,10 +302,7 @@ def test_async_collective_task_contract():
     task = dist.stream.all_reduce(t, sync_op=False, use_calc_stream=True)
     assert task.is_completed()          # use_calc_stream forces the wait
     # in-trace: collectives still return Task, wait() is a no-op on tracers
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: experimental
-        from paddle_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
     g = dist.new_group(list(range(4)), axis_name="data")
@@ -323,10 +320,7 @@ def test_async_collective_task_contract():
 
 # -------------------------------------------------------- collectives in-trace
 def test_collectives_inside_shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: experimental
-        from paddle_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     devs = np.asarray(jax.devices()[:4])
     mesh = Mesh(devs, ("data",))
@@ -346,10 +340,7 @@ def test_collectives_inside_shard_map():
 def test_global_scatter_gather_roundtrip():
     """Explicit EP collectives (global_scatter/global_gather parity): each
     EP rank exchanges per-expert token slabs; gather inverts scatter."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax: experimental
-        from paddle_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh
     from paddle_tpu.distributed.moe import global_gather, global_scatter
 

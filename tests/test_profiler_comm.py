@@ -32,10 +32,7 @@ from paddle_tpu.profiler.exposition import parse_exposition_names
 
 from _env_probes import gspmd_tp_mesh, skip_unless
 
-try:
-    from jax import shard_map
-except ImportError:
-    from paddle_tpu.jax_compat import shard_map
+from jax import shard_map
 
 
 # ------------------------------------------------------------ HLO parse

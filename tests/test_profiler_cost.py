@@ -66,13 +66,19 @@ def test_shape_structs_passthrough():
     assert sds["b"] == 3 and sds["c"] is None
 
 
-def test_peak_flops_table_matches_bench():
-    """cost.py and bench.py carry the same peak table (bench must stay
-    import-light, so the table is duplicated — this pin is the sync)."""
-    for kind in ("v5 lite", "v5e", "v5p", "v4", "v6e", "trillium", "cpu",
-                 "something-unknown"):
-        assert cost.peak_flops_per_chip(kind) == \
-            bench.peak_flops_per_chip(kind), kind
+def test_peak_table_known_kinds_and_unknown_raises():
+    """One peak table (profiler/cost.py CHIP_PEAKS), matched on the
+    device_kind JAX reports; a device it does not hold is an error, not
+    a default — and there is no "cpu" row to compute a share of."""
+    assert cost.chip_peaks("TPU v5 lite") == (197e12, 819e9)
+    assert cost.peak_flops_per_chip("TPU v5p") == 459e12
+    assert cost.peak_flops_per_chip("TPU v4") == 275e12
+    assert cost.peak_flops_per_chip("TPU v6 lite") == 918e12
+    for kind in ("cpu", "something-unknown"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            cost.chip_peaks(kind)
+    with pytest.raises(ValueError):
+        cost.ProgramCost(flops=1e12).mfu(1.0, device_kind="cpu")
 
 
 def test_jit_cost_matmul_exact():
@@ -184,7 +190,7 @@ def test_llama_flops_formula_flagship_config():
     assert abs(xla / hand - 1.0) < 0.05, (xla, hand)
     # and the analytic-MFU helper agrees with bench.py's arithmetic
     dt = 1.0
-    peak = bench.peak_flops_per_chip("v5e")
+    peak = cost.peak_flops_per_chip("v5e")
     assert cost.analytic_mfu(hand, dt, peak_flops=peak) == \
         pytest.approx(hand / dt / peak)
 

@@ -20,6 +20,8 @@ from paddle_tpu.serving import (EngineFailure, EngineOverloaded,
 from paddle_tpu.serving.supervisor import FATAL, POISON, TRANSIENT
 from paddle_tpu.utils import faults
 
+from _engine_steps import drain
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -101,11 +103,17 @@ def test_fault_registry_triggers_and_counts():
 
 def test_classify_failure():
     assert classify_failure(TransientDeviceError("x")) == TRANSIENT
-    assert classify_failure(RuntimeError("UNAVAILABLE: relay gone")) \
+    assert classify_failure(RuntimeError("UNAVAILABLE: device gone")) \
         == TRANSIENT
     assert classify_failure(FloatingPointError("nan")) == POISON
     assert classify_failure(RuntimeError("RESOURCE_EXHAUSTED: OOM")) == FATAL
     assert classify_failure(ValueError("whatever")) == FATAL
+    # a program the chip's compiler refuses must surface, never retry
+    assert classify_failure(NotImplementedError(
+        "Mosaic kernels cannot be automatically partitioned. Please wrap "
+        "the call in a shard_map.")) == FATAL
+    assert classify_failure(RuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel")) == FATAL
 
 
 # ---------------------------------------------------------- lifecycle
@@ -213,7 +221,7 @@ def test_transient_step_failures_retry_bit_identical(model):
                          exc=TransientDeviceError("UNAVAILABLE: injected"),
                          times=3, after=2), \
          faults.injected("serving.engine.prefill_chunk",
-                         exc=TransientDeviceError("injected relay loss"),
+                         exc=TransientDeviceError("injected connection loss"),
                          times=2, after=1):
         out = eng.run()
     got = {i: out[r] for i, r in enumerate(rids)}
@@ -409,8 +417,7 @@ def test_kill_and_resume_completes_with_correct_outputs(model):
                          exc=RuntimeError("INTERNAL: device wedged"),
                          times=-1):
         with pytest.raises(EngineFailure) as ei:
-            while eng.has_work():
-                eng.step()
+            drain(eng)
     snap = json.loads(json.dumps(ei.value.snapshot))   # serializable
     eng.shutdown()
 

@@ -31,6 +31,8 @@ from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.engine import MAX_DECODE_STEPS
 from paddle_tpu.utils import faults
 
+from _engine_steps import drain, step_until
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -246,9 +248,9 @@ def test_quarantine_per_launch(model):
     rids = [eng.add_request(p, max_new_tokens=m)
             for p, m, _e in prompts]
     from paddle_tpu.serving import RequestState
-    while not all(eng.requests[r].state is RequestState.DECODE
-                  for r in rids):
-        eng.step()              # chunked prefills may straddle steps
+    # chunked prefills may straddle steps
+    step_until(eng, lambda: all(
+        eng.requests[r].state is RequestState.DECODE for r in rids))
     pre = len(eng.requests[rids[1]].output_ids)
     assert pre >= 1
     # armed only once every row decodes: the next launch is a 4-row
@@ -288,8 +290,7 @@ def test_snapshot_resume_at_k_boundary(model):
                          exc=RuntimeError("INVALID_ARGUMENT: boom"),
                          times=1):
         with pytest.raises(EngineFailure):
-            while eng.has_work():
-                eng.step()
+            drain(eng)
     snap = eng.last_snapshot
     assert snap is not None and snap["requests"]
     for k in (4, 1):

@@ -148,6 +148,7 @@ def _paging_trace(model, work, kv_dtype):
     trace = []
     page_maps = {}
     while eng.has_work():
+        assert len(trace) < 500
         eng.step()
         trace.append((eng.allocator.num_used, eng.allocator.num_free))
         for i, rid in enumerate(rids):   # keyed by workload index: the
@@ -298,8 +299,8 @@ def test_quant_configs_ride_program_keys_and_stay_bounded(model):
     assert sum(counts.values()) == eng.num_compiled_programs
     for fam, n in counts.items():
         assert n <= eng.max_program_count(fam)
-    # quant config + mesh shape ride every key
-    assert all(key[-3:] == ("int8", "w_full", ("tp", 1))
+    # quant config + mesh shape ride every key, before the sampling tuple
+    assert all(key[-4:-1] == ("int8", "w_full", ("tp", 1))
                for key in eng.programs.keys())
     eng.shutdown()
 
@@ -318,7 +319,7 @@ def test_wq_int8_engine_decodes_and_stays_bounded():
     outs = _drain(eng, work)
     assert [len(t) for t in outs] == [m for _, m in work]
     assert eng.num_compiled_programs <= eng.max_program_count()
-    assert all(key[-3:-1] == ("int8", "int8")
+    assert all(key[-4:-2] == ("int8", "int8")
                for key in eng.programs.keys())
     eng.reset_prefix_cache()
     assert eng.allocator.num_used == 0

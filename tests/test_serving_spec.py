@@ -22,6 +22,8 @@ from paddle_tpu.serving import (BlockAllocator, DraftModelProposer,
                                 NgramProposer, ServingEngine)
 from paddle_tpu.utils import faults
 
+from _engine_steps import step_until
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -119,8 +121,14 @@ def test_draft_extension_oom_rolls_back_all_or_nothing(model):
     ref = plain.run()[rid]
 
     eng = ServingEngine(model, proposer=NgramProposer(), spec_k=4, **kw)
-    with faults.injected("serving.kv.alloc_page", payload=True,
-                         prob=0.5, times=40, seed=3):
+    # the draft storm makes every verify step carry a full-K draft, so
+    # extensions cross page boundaries whatever tokens this jax's
+    # numerics make the model emit (the ngram drafts alone stopped
+    # reaching a boundary, and the OOM path went unexercised)
+    with faults.injected("serving.spec.draft_storm", payload=True,
+                         times=-1), \
+            faults.injected("serving.kv.alloc_page", payload=True,
+                            prob=0.5, times=40, seed=3):
         rid = eng.add_request([9, 9, 9, 9] * 4, max_new_tokens=12)
         out = eng.run()[rid]
     assert out == ref
@@ -318,10 +326,11 @@ def test_spec_budget_accounting_and_program_grid(model):
               batch_buckets=[4], prefill_buckets=[16],
               pages_buckets=[4], temperature=0.0)
     eng = ServingEngine(model, proposer=NgramProposer(), spec_k=4, **kw)
+    # 16 new tokens each: the first admitted must still be decoding
+    # when the fourth joins (at 8 they finished before it did)
     for _ in range(4):
-        eng.add_request([1, 2] * 4, max_new_tokens=8)
-    while not eng.scheduler.running or len(eng.scheduler.running) < 4:
-        eng.step()
+        eng.add_request([1, 2] * 4, max_new_tokens=16)
+    step_until(eng, lambda: len(eng.scheduler.running) == 4, cap=50)
     eng.add_request([3, 4] * 6, max_new_tokens=4)
     eng.run()
     # the late prompt (12 tokens) needed more than one chunk under the

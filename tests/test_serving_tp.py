@@ -134,7 +134,8 @@ def test_tp_greedy_identity_and_bit_identical_host_traces(model):
         assert traces == base_traces, f"TP={tp} perturbed host state"
         # mesh shape rides every program key; families report through
         # the unified ProgramCache and match the single-device engine
-        assert all(k[-1] == ("tp", tp) for k in keys)
+        # (the sampling tuple is the key's last axis, the mesh the one before)
+        assert all(k[-2] == ("tp", tp) for k in keys)
         assert counts == base_counts
         assert snap["prefix_hits"] == base_snap["prefix_hits"]
         assert snap["kv_tp_degree"] == tp

@@ -18,7 +18,7 @@ Perfetto / chrome://tracing) and prints the digest:
   per-rank value and flags disagreement instead of summing it 8x).
 
 Deliberately stdlib-only: loading this module must never import jax
-(every plain `python` start claims the TPU grant — CLAUDE.md), so the
+(a process that starts a jax backend may take the chip), so the
 report runs anywhere, including while a launched fleet holds the chip.
 `--demo` is the one exception: it lazily imports paddle_tpu to run a
 tiny threaded ZB pipeline and write real per-rank exports first.

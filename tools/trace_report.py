@@ -13,7 +13,7 @@ recorder — and prints:
   program-launch counts per family, fault/retry totals).
 
 Deliberately stdlib-only: loading this module must never import jax
-(every plain `python` start claims the TPU grant — CLAUDE.md), so the
+(a process that starts a jax backend may take the chip), so the
 report runs anywhere, including while an engine holds the chip.
 
 Usage:  python tools/trace_report.py TRACE.json [--slowest 3]

@@ -13,7 +13,7 @@ compile-event log) and prints:
   a compile storm reads as a table, not a debugger hunt).
 
 Deliberately stdlib-only: loading this module must never import jax
-(every plain `python` start claims the TPU grant — CLAUDE.md), so the
+(a process that starts a jax backend may take the chip), so the
 report runs anywhere, including while a trainer holds the chip. The
 `--demo` flag is the one exception: it lazily imports paddle_tpu to run
 a tiny monitored CPU training loop and write the artifact it then
