@@ -229,6 +229,7 @@ class TracedFunction:
         self._compiled_count = 0   # programs ever compiled (trace + retraces)
         self.__wrapped__ = fn
         functools.update_wrapper(self, self._callable)
+        self._span_fn = self._fn_name()    # `fn=` of the call's span
 
     def _check_spec(self, tensor_arrays):
         """input_spec-driven guard (parity: the reference's
@@ -318,42 +319,45 @@ class TracedFunction:
             # eagerly (the whole-function subset of the reference's
             # call-site graph break, jit/api.py not_to_static)
             return self._callable(*args, **kwargs)
-        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs),
-                                                     is_leaf=_is_tensor)
-        tensor_arrays = []
-        static_leaves = []
-        sg_flags = []
-        for l in leaves:
-            if isinstance(l, Tensor):
-                tensor_arrays.append(l._data)
-                static_leaves.append(_TENSOR_SLOT)
-                sg_flags.append(l.stop_gradient)
-            else:
-                static_leaves.append(l)
-                sg_flags.append(True)
-        self._sg_flags = sg_flags
-        if self._input_spec is not None:
-            self._check_spec(tensor_arrays)
-        # Guard evaluation: when a Profiler is recording, the key build
-        # (closure/global fingerprints + the re-conversion check) gets
-        # its own host span (ISSUE 11) — guard time is real per-call
-        # work in closure-heavy loops and was invisible before.
-        prof = _profiler
-        if prof._tracer.enabled:
-            with prof.RecordEvent("to_static.guard"):
-                key = self._guard_key(treedef, static_leaves,
-                                      tensor_arrays, sg_flags)
-        else:
+        # the call's own spans (profiler.RecordEvent: on the profiler's
+        # clock in any trace being taken, a flag check otherwise):
+        # to_static.call over guard, collect_state, dispatch, load_state
+        with _profiler.RecordEvent("to_static.call", fn=self._span_fn):
+            return self._call_captured(args, kwargs)
+
+    def _call_captured(self, args, kwargs):
+        # Guard evaluation: flattening the arguments, the key build
+        # (closure/global fingerprints + the re-conversion check) and the
+        # cache lookup are real per-call work in closure-heavy loops, so
+        # they have their own span (ISSUE 11)
+        with _profiler.RecordEvent("to_static.guard"):
+            leaves, treedef = jax.tree_util.tree_flatten((args, kwargs),
+                                                         is_leaf=_is_tensor)
+            tensor_arrays = []
+            static_leaves = []
+            sg_flags = []
+            for l in leaves:
+                if isinstance(l, Tensor):
+                    tensor_arrays.append(l._data)
+                    static_leaves.append(_TENSOR_SLOT)
+                    sg_flags.append(l.stop_gradient)
+                else:
+                    static_leaves.append(l)
+                    sg_flags.append(True)
+            self._sg_flags = sg_flags
+            if self._input_spec is not None:
+                self._check_spec(tensor_arrays)
             key = self._guard_key(treedef, static_leaves, tensor_arrays,
                                   sg_flags)
-        entry = self._cache.get(key)
+            entry = self._cache.get(key)
         if entry is _EAGER_FALLBACK:       # guard hit on a broken graph
             return self._callable(*args, **kwargs)
         if entry is None:
             entry = self._make_jitted(treedef, static_leaves, len(tensor_arrays))
             self._cache[key] = entry
         jitted, out_box = entry.jitted, entry.out_box
-        state = self._bundle.collect()
+        with _profiler.RecordEvent("to_static.collect_state"):
+            state = self._bundle.collect()
         # time every call until the entry stabilizes: the first call is
         # the trace+compile (a guard miss is only alertable if it
         # carries its cost), and the next call(s) may recompile inside
@@ -361,7 +365,10 @@ class TracedFunction:
         # see _CacheEntry. Steady state pays one attribute check.
         t0 = None if entry.stable else time.perf_counter()
         try:
-            out_arrays, new_state = jitted(state, tensor_arrays)
+            # on a fresh entry this is the trace and the compile
+            # (compile_log says so); in steady state, the enqueue
+            with _profiler.RecordEvent("to_static.dispatch"):
+                out_arrays, new_state = jitted(state, tensor_arrays)
         except _graph_break_errors() as e:
             if self._full_graph:
                 raise RuntimeError(
@@ -375,11 +382,13 @@ class TracedFunction:
         if t0 is not None:
             self._note_compiled(entry, state, tensor_arrays,
                                 time.perf_counter() - t0)
-        self._bundle.load(new_state)
-        self._clear_tracer_grads()
-        out_treedef = out_box[0]
-        out_leaves = [Tensor(a) if hasattr(a, "dtype") else a for a in out_arrays]
-        return jax.tree_util.tree_unflatten(out_treedef, out_leaves)
+        with _profiler.RecordEvent("to_static.load_state"):
+            self._bundle.load(new_state)
+            self._clear_tracer_grads()
+            out_treedef = out_box[0]
+            out_leaves = [Tensor(a) if hasattr(a, "dtype") else a
+                          for a in out_arrays]
+            return jax.tree_util.tree_unflatten(out_treedef, out_leaves)
 
     def _guard_key(self, treedef, static_leaves, tensor_arrays, sg_flags):
         # sg_flags is read by the traced closure, so it MUST be part of the

@@ -322,6 +322,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret_mode(),
+        name="flash_attention_fwd",
     )(*args)
     return out, lse3[:, 0, :]
 
@@ -491,6 +492,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, q, k, v, delta, lse,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret_mode(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, delta3, dout, lse3, *extra_args)
 
     dk, dv = pl.pallas_call(
@@ -523,6 +525,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, q, k, v, delta, lse,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret_mode(),
+        name="flash_attention_bwd_dkv",
     )(q, k, v, delta3, dout, lse3, *extra_args)
     return dq, dk, dv
 

@@ -109,4 +109,5 @@ def rms_norm_rows(x, weight, residual=None, eps=1e-6, block_rows=256):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((r, h), x.dtype),
         interpret=_interpret_mode(),
+        name="rms_norm",
     )(*args)

@@ -278,6 +278,7 @@ def fused_adamw_bucket(grads, weights, m, v, scalars, param_dtype=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret_mode(),
+        name="fused_adamw",
     )(scalars, grads, weights, m, v)
     if has_master:
         return outs

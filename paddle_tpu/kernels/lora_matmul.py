@@ -241,6 +241,7 @@ def lora_matmul(x2d, adapter_ids, a_stack, b_stack, blocks=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret_mode(),
+        name="lora_matmul",
         # tpu-lint-hint: vmem-dtypes=float32,float32,float32,int32
     )(x2d, a_stack, b_stack, ids_row)
 

@@ -347,6 +347,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret_mode(),
+        name="paged_attention_decode",
     )(bt, sl, qg, *([k_cache] * fold), *([v_cache] * fold), *scale_args)
     return out.reshape(B, H, D)
 

@@ -208,6 +208,7 @@ def quant_matmul(x2d, qw, scale, blocks=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret_mode(),
+        name="quant_matmul",
         # tpu-lint-hint: vmem-dtypes=float32,int8,float32
     )(x2d, qw, scale[None, :].astype(jnp.float32))
 

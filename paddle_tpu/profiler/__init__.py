@@ -25,6 +25,8 @@ from contextlib import ContextDecorator
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
+import jax
+
 __all__ = ["ProfilerState", "ProfilerTarget", "TracerEventType",
            "RecordEvent", "Profiler", "make_scheduler", "benchmark",
            "export_chrome_tracing", "load_profiler_result",
@@ -123,46 +125,55 @@ def counters() -> dict:
 
 
 class RecordEvent(ContextDecorator):
-    """Host span; shows on the device timeline via TraceAnnotation.
+    """Host span: a `jax.profiler.TraceAnnotation` in ANY trace that is
+    being taken (paddle's `Profiler`, a bare `jax.profiler.start_trace`,
+    TensorBoard's capture), so it lands on the profiler's clock beside
+    the device ops. Outside a profiling session it is the annotation's
+    own flag check (`is_enabled()`) and nothing is built. Keyword
+    metadata (`step=`, `bucket=`, `fn=`) rides on the annotation: nesting
+    on one thread gives a span its parent, the metadata gives it its
+    identifier. The host clock is read, and paddle's own host-span list
+    appended to, only behind an active `Profiler`.
 
     Parity: paddle.profiler.RecordEvent (event_tracing.h:32 emission
     points are the generated ad_funcs; here ops.dispatch hooks this when
     FLAGS_benchmark or an active profiler asks for op spans)."""
 
+    _ann = None                        # the open annotation, in a trace
+    _t0 = 0                            # the start, behind a Profiler
+
     def __init__(self, name: str,
-                 event_type: TracerEventType = TracerEventType.UserDefined):
+                 event_type: TracerEventType = TracerEventType.UserDefined,
+                 **meta):
         self.name = name
         self.event_type = event_type
-        self._ann = None
-        self._t0 = None
-
-    def begin(self):
-        self._t0 = time.perf_counter_ns()
-        if _tracer.enabled:
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
-
-    def end(self):
-        if self._t0 is None:
-            return
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        _tracer.add(self.name, self.event_type, self._t0,
-                    time.perf_counter_ns(), threading.get_ident())
-        self._t0 = None
+        self.meta = meta
 
     def __enter__(self):
-        self.begin()
+        annotation = jax.profiler.TraceAnnotation
+        if annotation.is_enabled():
+            self._ann = annotation(self.name, **self.meta)
+            self._ann.__enter__()
+        if _tracer.enabled:
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self.end()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._t0:
+            if _tracer.enabled:
+                _tracer.add(self.name, self.event_type, self._t0,
+                            time.perf_counter_ns(), threading.get_ident())
+            self._t0 = 0
         return False
+
+    def begin(self):
+        self.__enter__()
+
+    def end(self):
+        self.__exit__()
 
 
 def make_scheduler(*, closed: int, ready: int, record: int, repeat: int = 0,
@@ -285,20 +296,21 @@ class Profiler:
         _tracer.events = []
         if any(t in (ProfilerTarget.TPU, ProfilerTarget.GPU)
                for t in self.targets):
+            d = self.log_dir or default_log_dir()
+            os.makedirs(d, exist_ok=True)
             try:
-                import jax
-                d = self.log_dir or default_log_dir()
-                os.makedirs(d, exist_ok=True)
                 jax.profiler.start_trace(d)
-                self._device_tracing = True
             except Exception:
-                self._device_tracing = False
+                # a device trace that was asked for and did not start is
+                # an error, not a host-only recording in silence
+                _tracer.enabled = False
+                raise
+            self._device_tracing = True
 
     def _stop_tracing(self):
         _tracer.enabled = False
         if self._device_tracing:
             try:
-                import jax
                 jax.profiler.stop_trace()
             except Exception:
                 pass

@@ -82,6 +82,7 @@ from ..core.autograd import no_grad
 from ..core.tensor import Tensor
 from ..jit.api import functional_call
 from ..models.generation import _filter_logits, _sample_arr
+from .. import profiler
 from ..utils import faults
 from ..utils.nan_inf import poison_scope
 from .errors import (EngineFailure, EngineOverloaded,
@@ -682,6 +683,10 @@ class ServingEngine:
         self._cur_rids = ()          # requests in the launch being run
         self._step_ev = {"programs": []}
         self._step_t0: Optional[float] = None
+        # the running step's number: metadata of the `serving.step`
+        # span, `step` of the flight recorder's record and of the
+        # RequestTracer's launch spans (the join between the clocks)
+        self._step_no = 0
         self._last_launch_s: Optional[float] = None
 
         from jax.sharding import PartitionSpec as P
@@ -873,15 +878,18 @@ class ServingEngine:
         tr.mark("admitted", now, cached_tokens=req.cached_tokens,
                 resumed=resumed, engine=self.metrics.name)
 
-    def _tr_launch(self, rids, name: str, t0: int, **args):
+    def _tr_launch(self, rids, name: str, t0: int, t1=None, **args):
         """One span per PARTICIPATING request for a batched launch —
         the per-request timeline view of shared device work. The args
         are identical across the batch, so the record is built once
-        (`span_many`) — the traced decode hot path stays cheap."""
+        (`span_many`) — the traced decode hot path stays cheap. The span
+        ends at `t1`, or now."""
         if self.tracer is None:
             return
-        self.tracer.span_many(rids, name, t0, self.tracer.now_ns(),
-                              engine=self.metrics.name, **args)
+        self.tracer.span_many(rids, name, t0,
+                              self.tracer.now_ns() if t1 is None else t1,
+                              engine=self.metrics.name,
+                              step=self._step_no, **args)
 
     def _tr_mark(self, rid: int, name: str, **args):
         if self.tracer is None:
@@ -1201,29 +1209,33 @@ class ServingEngine:
         return jax.jit(program, donate_argnums=self._donate)
 
     def _run_chunk(self, chunk):
-        from .. import profiler
         req = chunk.request
-        ids = req.resume_ids[chunk.start:chunk.start + chunk.length]
-        S = _bucket_for(chunk.length, self.prefill_buckets)
-        P = _bucket_for(
-            self.allocator.pages_needed(chunk.start + chunk.length),
-            self.pages_buckets)
-        prog = self._get_program(("chunk", S, P) + self._qkey,
-                                 lambda: self._build_chunk(S, P))
-        bt = np.full((P,), PAD_PAGE, np.int32)
-        npages = min(len(req.seq.pages), P)
-        bt[:npages] = req.seq.pages[:npages]
-        padded = np.zeros((1, S), np.int32)
-        padded[0, :chunk.length] = ids
-        # the RNG key is drawn ONCE, before the supervised launch, so a
-        # transient-failure retry re-runs the identical program (bit-
-        # identical token) instead of burning a new key per attempt
-        key = self._next_key() if chunk.is_last else self._null_key
-        largs = self._lora_launch_args([req], 1)
+        with profiler.RecordEvent("serving.build_inputs"):
+            ids = req.resume_ids[chunk.start:chunk.start + chunk.length]
+            S = _bucket_for(chunk.length, self.prefill_buckets)
+            P = _bucket_for(
+                self.allocator.pages_needed(chunk.start + chunk.length),
+                self.pages_buckets)
+            prog = self._get_program(("chunk", S, P) + self._qkey,
+                                     lambda: self._build_chunk(S, P))
+            bt = np.full((P,), PAD_PAGE, np.int32)
+            npages = min(len(req.seq.pages), P)
+            bt[:npages] = req.seq.pages[:npages]
+            padded = np.zeros((1, S), np.int32)
+            padded[0, :chunk.length] = ids
+            # the RNG key is drawn ONCE, before the supervised launch, so
+            # a transient-failure retry re-runs the identical program
+            # (bit-identical token) instead of burning a new key per
+            # attempt
+            key = self._next_key() if chunk.is_last else self._null_key
+            largs = self._lora_launch_args([req], 1)
+            self._cur_rids = (req.request_id,)
+            self._step_ev["programs"].append(f"chunk:S{S}:P{P}")
 
         def launch():
             faults.fire(FAULT_CHUNK)
-            with profiler.RecordEvent("serving.prefill_chunk"), \
+            with profiler.RecordEvent("serving.prefill_chunk",
+                                      bucket=[S, P]), \
                     poison_scope(f"serving.prefill_chunk[req="
                                  f"{req.request_id}]"), no_grad(), \
                     self._trace_scope():
@@ -1234,24 +1246,29 @@ class ServingEngine:
                     jnp.int32(chunk.length), jnp.asarray(bt), key,
                     *largs)
 
-        self._cur_rids = (req.request_id,)
-        self._step_ev["programs"].append(f"chunk:S{S}:P{P}")
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         tok, ok, *caches = self.supervisor.run(launch,
                                                label="prefill_chunk")
-        self._tr_launch((req.request_id,), "prefill_chunk", t_tr,
-                        start=chunk.start, length=chunk.length,
-                        bucket=[S, P], last=chunk.is_last)
-        self._store_caches(*caches)
-        if faults.fire(FAULT_NAN) is not None:
-            ok = False
-        self.metrics.on_prefill(chunk.length)
-        # the chunk wrote its own tokens' K/V and its attention gathered
-        # the whole live prefix (cached tokens + this chunk) per layer
-        self.metrics.on_kv_bytes(
-            written=chunk.length * self.kv_bytes_per_token,
-            read=(chunk.start + chunk.length) * self.kv_bytes_per_token)
-        return tok, bool(ok)
+        with profiler.RecordEvent("serving.bookkeeping"):
+            self._tr_launch((req.request_id,), "prefill_chunk", t_tr,
+                            start=chunk.start, length=chunk.length,
+                            bucket=[S, P], last=chunk.is_last)
+            self._store_caches(*caches)
+            if faults.fire(FAULT_NAN) is not None:
+                ok = False
+            self.metrics.on_prefill(chunk.length)
+            # the chunk wrote its own tokens' K/V and its attention
+            # gathered the whole live prefix (cached tokens + this chunk)
+            # per layer
+            self.metrics.on_kv_bytes(
+                written=chunk.length * self.kv_bytes_per_token,
+                read=(chunk.start + chunk.length)
+                * self.kv_bytes_per_token)
+        with profiler.RecordEvent("serving.fetch"):
+            ok = bool(ok)              # host fetch = the honest sync
+            if ok and chunk.is_last:
+                tok = int(tok)
+        return tok, ok
 
     # ----------------------------------------------------------- decode
     def _build_decode(self, B: int, P: int):
@@ -1281,30 +1298,34 @@ class ServingEngine:
         return jax.jit(program, donate_argnums=self._donate)
 
     def _run_decode(self, reqs: List[Request]):
-        from .. import profiler
-        B = _bucket_for(len(reqs), self.batch_buckets)
-        max_pages = max(len(r.seq.pages) for r in reqs)
-        P = _bucket_for(max_pages, self.pages_buckets)
-        prog = self._get_program(("decode", B, P) + self._qkey,
-                                 lambda: self._build_decode(B, P))
-        ids = np.zeros((B, 1), np.int32)
-        sl = np.zeros((B,), np.int32)
-        seqs = [r.seq for r in reqs]
-        bt = np.full((B, P), PAD_PAGE, np.int32)
-        bt[:len(reqs)] = self.allocator.block_table(seqs, P)
-        for i, r in enumerate(reqs):
-            ids[i, 0] = r.output_ids[-1]
-            sl[i] = r.seq.num_tokens
-        key = self._next_key()    # drawn once: retries re-run identically
-        rids = [r.request_id for r in reqs]
-        largs = self._lora_launch_args(reqs, B)
-        if self.lora is not None:
-            self.metrics.on_adapter_mix(
-                len({r.adapter for r in reqs if r.adapter is not None}))
+        with profiler.RecordEvent("serving.build_inputs"):
+            B = _bucket_for(len(reqs), self.batch_buckets)
+            max_pages = max(len(r.seq.pages) for r in reqs)
+            P = _bucket_for(max_pages, self.pages_buckets)
+            prog = self._get_program(("decode", B, P) + self._qkey,
+                                     lambda: self._build_decode(B, P))
+            ids = np.zeros((B, 1), np.int32)
+            sl = np.zeros((B,), np.int32)
+            seqs = [r.seq for r in reqs]
+            bt = np.full((B, P), PAD_PAGE, np.int32)
+            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
+            for i, r in enumerate(reqs):
+                ids[i, 0] = r.output_ids[-1]
+                sl[i] = r.seq.num_tokens
+            key = self._next_key()  # drawn once: retries re-run identically
+            rids = [r.request_id for r in reqs]
+            largs = self._lora_launch_args(reqs, B)
+            if self.lora is not None:
+                self.metrics.on_adapter_mix(
+                    len({r.adapter for r in reqs if r.adapter is not None}))
+            self._cur_rids = tuple(rids)
+            self._step_ev["programs"].append(f"decode:B{B}:P{P}")
+            self._step_ev["decode_k"] = 1
 
         def launch():
             faults.fire(FAULT_DECODE)
-            with profiler.RecordEvent("serving.decode_step"), \
+            with profiler.RecordEvent("serving.decode_step",
+                                      bucket=[B, P]), \
                     poison_scope(f"serving.decode_step[reqs={rids}]"), \
                     no_grad(), self._trace_scope():
                 return prog(
@@ -1313,33 +1334,35 @@ class ServingEngine:
                     jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
                     key, *largs)
 
-        self._cur_rids = tuple(rids)
-        self._step_ev["programs"].append(f"decode:B{B}:P{P}")
-        self._step_ev["decode_k"] = 1
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
         toks, oks, *caches = self.supervisor.run(launch,
                                                  label="decode_step")
-        toks = np.asarray(toks)        # host fetch = the honest sync
-        self._last_launch_s = _perf_counter() - t0
-        self._tr_launch(rids, "decode_step", t_tr, batch=len(reqs),
-                        bucket=[B, P], k=1)
-        self._store_caches(*caches)
-        # bytes-moved accounting: this step wrote one token per live row
-        # and the attention kernel read every live token's K/V
-        self.metrics.on_kv_bytes(
-            written=len(reqs) * self.kv_bytes_per_token,
-            read=sum(r.seq.num_tokens for r in reqs)
-            * self.kv_bytes_per_token)
-        oks = np.asarray(oks)[:len(reqs)].copy()
-        poison = faults.fire(FAULT_NAN)
-        if poison is not None:
-            for i in self._poison_rows(poison, reqs):
-                oks[i] = False
-        for r in reqs:
-            # this step wrote the K/V of each row's input token
-            r.num_computed = r.seq.num_tokens
-        self.metrics.on_decode(len(reqs))
+        with profiler.RecordEvent("serving.fetch"):
+            toks = np.asarray(toks)    # host fetch = the honest sync
+            # the TPOT sample and the request's launch span end here,
+            # with the tokens on the host
+            self._last_launch_s = _perf_counter() - t0
+            t1_tr = self.tracer.now_ns() if self.tracer is not None else 0
+            oks = np.asarray(oks)[:len(reqs)].copy()
+        with profiler.RecordEvent("serving.bookkeeping"):
+            self._tr_launch(rids, "decode_step", t_tr, t1_tr,
+                            batch=len(reqs), bucket=[B, P], k=1)
+            self._store_caches(*caches)
+            # bytes-moved accounting: this step wrote one token per live
+            # row and the attention kernel read every live token's K/V
+            self.metrics.on_kv_bytes(
+                written=len(reqs) * self.kv_bytes_per_token,
+                read=sum(r.seq.num_tokens for r in reqs)
+                * self.kv_bytes_per_token)
+            poison = faults.fire(FAULT_NAN)
+            if poison is not None:
+                for i in self._poison_rows(poison, reqs):
+                    oks[i] = False
+            for r in reqs:
+                # this step wrote the K/V of each row's input token
+                r.num_computed = r.seq.num_tokens
+            self.metrics.on_decode(len(reqs))
         return toks, oks
 
     @staticmethod
@@ -1395,38 +1418,44 @@ class ServingEngine:
         """One supervised ("multi_decode", B, K, P) launch. `reqs[i]`'s
         sequence is already extended by caps[i] - 1 slots; returns
         (toks (B, K), n_emit (B,), oks (B,), launch seconds)."""
-        from .. import profiler
-        B = _bucket_for(len(reqs), self.batch_buckets)
-        max_pages = max(len(r.seq.pages) for r in reqs)
-        P = _bucket_for(max_pages, self.pages_buckets)
-        prog = self._get_program(("multi_decode", B, K, P) + self._qkey,
-                                 lambda: self._build_multi_decode(B, K, P))
-        ids = np.zeros((B,), np.int32)
-        sl = np.zeros((B,), np.int32)
-        cp = np.zeros((B,), np.int32)
-        eos = np.full((B,), -1, np.int32)
-        bt = np.full((B, P), PAD_PAGE, np.int32)
-        seqs = [r.seq for r in reqs]
-        bt[:len(reqs)] = self.allocator.block_table(seqs, P)
-        for i, (r, c) in enumerate(zip(reqs, caps)):
-            ids[i] = r.output_ids[-1]
-            # seq_lens counts through the FIRST input token (the
-            # forward_paged convention); the extension slots grew
-            # num_tokens past it, so subtract them back out
-            sl[i] = r.seq.num_tokens - (c - 1)
-            cp[i] = c
-            if r.eos_token_id is not None:
-                eos[i] = r.eos_token_id
-        key = self._next_key()    # drawn once: retries re-run identically
-        rids = [r.request_id for r in reqs]
-        largs = self._lora_launch_args(reqs, B)
-        if self.lora is not None:
-            self.metrics.on_adapter_mix(
-                len({r.adapter for r in reqs if r.adapter is not None}))
+        with profiler.RecordEvent("serving.build_inputs"):
+            B = _bucket_for(len(reqs), self.batch_buckets)
+            max_pages = max(len(r.seq.pages) for r in reqs)
+            P = _bucket_for(max_pages, self.pages_buckets)
+            prog = self._get_program(
+                ("multi_decode", B, K, P) + self._qkey,
+                lambda: self._build_multi_decode(B, K, P))
+            ids = np.zeros((B,), np.int32)
+            sl = np.zeros((B,), np.int32)
+            cp = np.zeros((B,), np.int32)
+            eos = np.full((B,), -1, np.int32)
+            bt = np.full((B, P), PAD_PAGE, np.int32)
+            seqs = [r.seq for r in reqs]
+            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
+            for i, (r, c) in enumerate(zip(reqs, caps)):
+                ids[i] = r.output_ids[-1]
+                # seq_lens counts through the FIRST input token (the
+                # forward_paged convention); the extension slots grew
+                # num_tokens past it, so subtract them back out
+                sl[i] = r.seq.num_tokens - (c - 1)
+                cp[i] = c
+                if r.eos_token_id is not None:
+                    eos[i] = r.eos_token_id
+            key = self._next_key()  # drawn once: retries re-run identically
+            rids = [r.request_id for r in reqs]
+            largs = self._lora_launch_args(reqs, B)
+            if self.lora is not None:
+                self.metrics.on_adapter_mix(
+                    len({r.adapter for r in reqs if r.adapter is not None}))
+            self._cur_rids = tuple(rids)
+            self._step_ev["programs"].append(
+                f"multi_decode:B{B}:K{K}:P{P}")
+            self._step_ev["decode_k"] = K
 
         def launch():
             faults.fire(FAULT_MULTI)
-            with profiler.RecordEvent("serving.multi_decode_step"), \
+            with profiler.RecordEvent("serving.multi_decode_step",
+                                      bucket=[B, K, P]), \
                     poison_scope(f"serving.multi_decode_step[reqs="
                                  f"{rids}]"), no_grad(), \
                     self._trace_scope():
@@ -1436,38 +1465,38 @@ class ServingEngine:
                     jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
                     jnp.asarray(cp), jnp.asarray(eos), key, *largs)
 
-        self._cur_rids = tuple(rids)
-        self._step_ev["programs"].append(f"multi_decode:B{B}:K{K}:P{P}")
-        self._step_ev["decode_k"] = K
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
         toks, n_emit, oks, *caches = self.supervisor.run(
             launch, label="multi_decode_step")
         # the host fetch is the sync: convert
         # BEFORE stamping the launch time so TPOT covers device work
-        toks = np.asarray(toks)
-        n_emit = np.asarray(n_emit).astype(int)
-        oks = np.asarray(oks)[:len(reqs)].copy()
+        with profiler.RecordEvent("serving.fetch"):
+            toks = np.asarray(toks)
+            n_emit = np.asarray(n_emit).astype(int)
+            oks = np.asarray(oks)[:len(reqs)].copy()
         dt = _perf_counter() - t0
-        self._tr_launch(rids, "multi_decode_step", t_tr, batch=len(reqs),
-                        bucket=[B, K, P], k=K)
-        self._store_caches(*caches)
-        # bytes-moved accounting: every live row writes one token's K/V
-        # per step (frozen steps idempotently rewrite the last token),
-        # and each step's attention reads the row's then-current prefix
-        # (frozen rows re-read at their frozen length)
-        base_lens = sl[:len(reqs)].astype(int)
-        reads = sum(int(b0) * K + sum(min(j, int(e)) for j in range(K))
-                    for b0, e in zip(base_lens, n_emit[:len(reqs)]))
-        self.metrics.on_kv_bytes(
-            written=len(reqs) * K * self.kv_bytes_per_token,
-            read=reads * self.kv_bytes_per_token)
-        for r in reqs:
-            r.num_computed = r.seq.num_tokens
-        poison = faults.fire(FAULT_NAN)
-        if poison is not None:
-            for i in self._poison_rows(poison, reqs):
-                oks[i] = False
+        with profiler.RecordEvent("serving.bookkeeping"):
+            self._tr_launch(rids, "multi_decode_step", t_tr,
+                            batch=len(reqs), bucket=[B, K, P], k=K)
+            self._store_caches(*caches)
+            # bytes-moved accounting: every live row writes one token's
+            # K/V per step (frozen steps idempotently rewrite the last
+            # token), and each step's attention reads the row's
+            # then-current prefix (frozen rows re-read at their frozen
+            # length)
+            base_lens = sl[:len(reqs)].astype(int)
+            reads = sum(int(b0) * K + sum(min(j, int(e)) for j in range(K))
+                        for b0, e in zip(base_lens, n_emit[:len(reqs)]))
+            self.metrics.on_kv_bytes(
+                written=len(reqs) * K * self.kv_bytes_per_token,
+                read=reads * self.kv_bytes_per_token)
+            for r in reqs:
+                r.num_computed = r.seq.num_tokens
+            poison = faults.fire(FAULT_NAN)
+            if poison is not None:
+                for i in self._poison_rows(poison, reqs):
+                    oks[i] = False
         return toks, n_emit, oks, dt
 
     def _multi_decode_step(self, decodes: List[Request], emitted):
@@ -1516,46 +1545,47 @@ class ServingEngine:
             n_emit = np.ones((len(decodes),), int)
             caps = [1] * len(decodes)
             isolated = True
-        total_emitted = 0
-        for i, req in enumerate(decodes):
-            base = req.seq.num_tokens - (caps[i] - 1)  # through input tok
-            if not oks[i]:
-                # per-launch quarantine: pages (extension slots
-                # included) freed WITHOUT donation, no token delivered
-                self._quarantine(req)
-                continue
-            e = int(n_emit[i])
-            reason = None
-            n_done = 0
-            for j in range(e):
-                reason = self._emit(req, int(toks[i, j]), emitted)
-                n_done += 1
+        with profiler.RecordEvent("serving.emit"):
+            total_emitted = 0
+            for i, req in enumerate(decodes):
+                base = req.seq.num_tokens - (caps[i] - 1)  # through input tok
+                if not oks[i]:
+                    # per-launch quarantine: pages (extension slots
+                    # included) freed WITHOUT donation, no token delivered
+                    self._quarantine(req)
+                    continue
+                e = int(n_emit[i])
+                reason = None
+                n_done = 0
+                for j in range(e):
+                    reason = self._emit(req, int(toks[i, j]), emitted)
+                    n_done += 1
+                    if reason is not None:
+                        break
+                # valid K/V: the input token + the emitted tokens actually
+                # CONSUMED as later in-graph inputs (n_done - 1 of them);
+                # unused extension slots roll back so donation/resume never
+                # sees past-freeze garbage
+                valid = base + max(n_done, 1) - 1
+                if req.seq.num_tokens > valid:
+                    self.allocator.truncate_sequence(req.seq, valid)
+                req.num_computed = valid
+                total_emitted += n_done
                 if reason is not None:
-                    break
-            # valid K/V: the input token + the emitted tokens actually
-            # CONSUMED as later in-graph inputs (n_done - 1 of them);
-            # unused extension slots roll back so donation/resume never
-            # sees past-freeze garbage
-            valid = base + max(n_done, 1) - 1
-            if req.seq.num_tokens > valid:
-                self.allocator.truncate_sequence(req.seq, valid)
-            req.num_computed = valid
-            total_emitted += n_done
-            if reason is not None:
-                self.scheduler.finish(req, reason)
-                self._on_finished(req)
-        if not isolated:
-            self.metrics.on_decode(total_emitted)
-            self.metrics.on_decode_launch(K, len(decodes), total_emitted,
-                                          dt)
-        else:
-            # the isolation path's solo launches counted decode_tokens
-            # inside _run_decode; record their row count too (one row
-            # per solo launch, k=1, no timing) or the
-            # tokens-per-launch ratio would keep a numerator with no
-            # denominator and read ABOVE its true value after any
-            # degraded event
-            self.metrics.on_decode_launch(1, len(decodes), 0, None)
+                    self.scheduler.finish(req, reason)
+                    self._on_finished(req)
+            if not isolated:
+                self.metrics.on_decode(total_emitted)
+                self.metrics.on_decode_launch(K, len(decodes), total_emitted,
+                                              dt)
+            else:
+                # the isolation path's solo launches counted decode_tokens
+                # inside _run_decode; record their row count too (one row
+                # per solo launch, k=1, no timing) or the
+                # tokens-per-launch ratio would keep a numerator with no
+                # denominator and read ABOVE its true value after any
+                # degraded event
+                self.metrics.on_decode_launch(1, len(decodes), 0, None)
 
     # ------------------------------------------- speculative verify (ISSUE 5)
     def _build_verify(self, B: int, K: int, P: int):
@@ -1683,35 +1713,41 @@ class ServingEngine:
         """One supervised ("verify", B, K, P) launch. `reqs[i]`'s
         sequence is already extended by len(drafts[i]); returns
         (toks (B, K+1), n_acc (B,), oks (B,))."""
-        from .. import profiler
-        B = _bucket_for(len(reqs), self.batch_buckets)
-        K = _bucket_for(max((len(d) for d in drafts), default=0) or 1,
-                        self.spec_buckets)
-        max_pages = max(len(r.seq.pages) for r in reqs)
-        P = _bucket_for(max_pages, self.pages_buckets)
-        prog = self._get_program(("verify", B, K, P) + self._qkey,
-                                 lambda: self._build_verify(B, K, P))
-        S = K + 1
-        ids = np.zeros((B, S), np.int32)
-        sl = np.zeros((B,), np.int32)
-        dl = np.zeros((B,), np.int32)
-        bt = np.full((B, P), PAD_PAGE, np.int32)
-        seqs = [r.seq for r in reqs]
-        bt[:len(reqs)] = self.allocator.block_table(seqs, P)
-        for i, (r, d) in enumerate(zip(reqs, drafts)):
-            ids[i, 0] = r.output_ids[-1]
-            ids[i, 1:1 + len(d)] = d
-            dl[i] = len(d)
-            # seq_lens counts through the FIRST input token (the
-            # forward_paged convention); the drafts extended num_tokens
-            # past it, so subtract them back out
-            sl[i] = r.seq.num_tokens - len(d)
-        key = self._next_key()    # drawn once: retries re-run identically
-        rids = [r.request_id for r in reqs]
+        with profiler.RecordEvent("serving.build_inputs"):
+            B = _bucket_for(len(reqs), self.batch_buckets)
+            K = _bucket_for(max((len(d) for d in drafts), default=0) or 1,
+                            self.spec_buckets)
+            max_pages = max(len(r.seq.pages) for r in reqs)
+            P = _bucket_for(max_pages, self.pages_buckets)
+            prog = self._get_program(("verify", B, K, P) + self._qkey,
+                                     lambda: self._build_verify(B, K, P))
+            S = K + 1
+            ids = np.zeros((B, S), np.int32)
+            sl = np.zeros((B,), np.int32)
+            dl = np.zeros((B,), np.int32)
+            bt = np.full((B, P), PAD_PAGE, np.int32)
+            seqs = [r.seq for r in reqs]
+            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
+            for i, (r, d) in enumerate(zip(reqs, drafts)):
+                ids[i, 0] = r.output_ids[-1]
+                ids[i, 1:1 + len(d)] = d
+                dl[i] = len(d)
+                # seq_lens counts through the FIRST input token (the
+                # forward_paged convention); the drafts extended
+                # num_tokens past it, so subtract them back out
+                sl[i] = r.seq.num_tokens - len(d)
+            key = self._next_key()  # drawn once: retries re-run identically
+            rids = [r.request_id for r in reqs]
+            self._cur_rids = tuple(rids)
+            self._step_ev["programs"].append(f"verify:B{B}:K{K}:P{P}")
+            # tokens-per-launch context for the step record: a verify
+            # launch can emit up to K drafts + 1 correction/bonus per row
+            self._step_ev["decode_k"] = K + 1
 
         def launch():
             faults.fire(FAULT_VERIFY)
-            with profiler.RecordEvent("serving.verify_step"), \
+            with profiler.RecordEvent("serving.verify_step",
+                                      bucket=[B, K, P]), \
                     poison_scope(f"serving.verify_step[reqs={rids}]"), \
                     no_grad(), self._trace_scope():
                 return prog(
@@ -1720,33 +1756,33 @@ class ServingEngine:
                     jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
                     jnp.asarray(dl), key)
 
-        self._cur_rids = tuple(rids)
-        self._step_ev["programs"].append(f"verify:B{B}:K{K}:P{P}")
-        # tokens-per-launch context for the step record: a verify
-        # launch can emit up to K drafts + 1 correction/bonus per row
-        self._step_ev["decode_k"] = K + 1
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         toks, n_acc, oks, *caches = self.supervisor.run(
             launch, label="verify_step")
-        if self.tracer is not None:
-            t1 = self.tracer.now_ns()
-            for rid, d in zip(rids, drafts):
-                self.tracer.span(rid, "verify_step", t_tr, t1,
-                                 engine=self.metrics.name,
-                                 batch=len(reqs), drafted=len(d),
-                                 bucket=[B, K, P])
-        self._store_caches(*caches)
-        self.metrics.on_kv_bytes(
-            written=int(sum(1 + len(d) for d in drafts))
-            * self.kv_bytes_per_token,
-            read=sum(r.seq.num_tokens for r in reqs)
-            * self.kv_bytes_per_token)
-        oks = np.asarray(oks)[:len(reqs)].copy()
+        with profiler.RecordEvent("serving.bookkeeping"):
+            if self.tracer is not None:
+                t1 = self.tracer.now_ns()
+                for rid, d in zip(rids, drafts):
+                    self.tracer.span(rid, "verify_step", t_tr, t1,
+                                     engine=self.metrics.name,
+                                     step=self._step_no,
+                                     batch=len(reqs), drafted=len(d),
+                                     bucket=[B, K, P])
+            self._store_caches(*caches)
+            self.metrics.on_kv_bytes(
+                written=int(sum(1 + len(d) for d in drafts))
+                * self.kv_bytes_per_token,
+                read=sum(r.seq.num_tokens for r in reqs)
+                * self.kv_bytes_per_token)
+        with profiler.RecordEvent("serving.fetch"):
+            oks = np.asarray(oks)[:len(reqs)].copy()
+            toks = np.asarray(toks)
+            n_acc = np.asarray(n_acc).astype(int)
         poison = faults.fire(FAULT_NAN)
         if poison is not None:
             for i in self._poison_rows(poison, reqs):
                 oks[i] = False
-        return (np.asarray(toks), np.asarray(n_acc).astype(int), oks)
+        return toks, n_acc, oks
 
     def _spec_decode_step(self, decodes: List[Request], emitted):
         """The speculative replacement for the plain decode launch:
@@ -1803,48 +1839,49 @@ class ServingEngine:
             drafts = [[] for _ in decodes]
             isolated = True   # solo launches counted their own tokens
 
-        total_drafted = total_accepted = total_emitted = total_rb = 0
-        rows = 0
-        for i, req in enumerate(decodes):
-            d = drafts[i]
-            base = req.seq.num_tokens - len(d)   # tokens through input
-            if not oks[i]:
-                # quarantine frees the whole sequence (no donation) —
-                # rejected-draft pages go with it
-                self._quarantine(req)
-                continue
-            n_emit = 0
-            reason = None
-            for j in range(int(n_accs[i]) + 1):
-                reason = self._emit(req, int(toks[i, j]), emitted)
-                n_emit += 1
+        with profiler.RecordEvent("serving.emit"):
+            total_drafted = total_accepted = total_emitted = total_rb = 0
+            rows = 0
+            for i, req in enumerate(decodes):
+                d = drafts[i]
+                base = req.seq.num_tokens - len(d)   # tokens through input
+                if not oks[i]:
+                    # quarantine frees the whole sequence (no donation) —
+                    # rejected-draft pages go with it
+                    self._quarantine(req)
+                    continue
+                n_emit = 0
+                reason = None
+                for j in range(int(n_accs[i]) + 1):
+                    reason = self._emit(req, int(toks[i, j]), emitted)
+                    n_emit += 1
+                    if reason is not None:
+                        break
+                # valid K/V: the input token + the accepted drafts actually
+                # CONSUMED (n_emit - 1 of them); everything past it rolls
+                # back so donation/resume never sees speculative garbage
+                valid = base + n_emit - 1
+                rolled = req.seq.num_tokens - valid
+                if rolled:
+                    self.allocator.truncate_sequence(req.seq, valid)
+                req.num_computed = valid
+                total_drafted += len(d)
+                total_accepted += n_emit - 1
+                total_emitted += n_emit
+                total_rb += rolled
+                rows += 1
                 if reason is not None:
-                    break
-            # valid K/V: the input token + the accepted drafts actually
-            # CONSUMED (n_emit - 1 of them); everything past it rolls
-            # back so donation/resume never sees speculative garbage
-            valid = base + n_emit - 1
-            rolled = req.seq.num_tokens - valid
-            if rolled:
-                self.allocator.truncate_sequence(req.seq, valid)
-            req.num_computed = valid
-            total_drafted += len(d)
-            total_accepted += n_emit - 1
-            total_emitted += n_emit
-            total_rb += rolled
-            rows += 1
-            if reason is not None:
-                self.scheduler.finish(req, reason)
-                self._on_finished(req)
-        # decode_tokens counts tokens EMITTED by decode-side launches
-        # (1/request for plain decode) so tokens/s stays honest. The
-        # isolation path counted its own solo launches and verified
-        # nothing — recording a spec step for it would drag
-        # spec_tokens_per_step below its true value.
-        if not isolated:
-            self.metrics.on_decode(total_emitted)
-            self.metrics.on_spec_step(total_drafted, total_accepted,
-                                      total_emitted, total_rb, rows)
+                    self.scheduler.finish(req, reason)
+                    self._on_finished(req)
+            # decode_tokens counts tokens EMITTED by decode-side launches
+            # (1/request for plain decode) so tokens/s stays honest. The
+            # isolation path counted its own solo launches and verified
+            # nothing — recording a spec step for it would drag
+            # spec_tokens_per_step below its true value.
+            if not isolated:
+                self.metrics.on_decode(total_emitted)
+                self.metrics.on_spec_step(total_drafted, total_accepted,
+                                          total_emitted, total_rb, rows)
 
     # ---------------------------------------------------- CoW page copies
     def _apply_copies(self, copies):
@@ -2013,6 +2050,14 @@ class ServingEngine:
         if self.failed:
             raise EngineFailure("engine has failed; resume from "
                                 "last_snapshot", snapshot=self.last_snapshot)
+        # one root span a step and one span a phase (PERF.md lists them):
+        # `step` is the number this step's flight-recorder record and the
+        # RequestTracer's launch spans carry
+        self._step_no = int(self.metrics.counters["engine_steps"]) + 1
+        with profiler.RecordEvent("serving.step", step=self._step_no):
+            return self._step()
+
+    def _step(self):
         emitted = []
         # flight recorder (ISSUE 10): per-step accumulator + counter
         # baseline for the deltas the step record reports
@@ -2024,11 +2069,12 @@ class ServingEngine:
             "requests_quarantined", "requests_aborted",
             "deadline_expired", "prefix_hits", "spec_drafted_tokens",
             "spec_accepted_tokens")}
-        self._cancel_boundary()
-        sched = self.scheduler.schedule()
-        for req in sched.preempted:
-            self.metrics.on_preempt()
-            self._tr_preempt(req)
+        with profiler.RecordEvent("serving.schedule"):
+            self._cancel_boundary()
+            sched = self.scheduler.schedule()
+            for req in sched.preempted:
+                self.metrics.on_preempt()
+                self._tr_preempt(req)
 
         for chunk in sched.prefills:
             req = chunk.request
@@ -2046,29 +2092,8 @@ class ServingEngine:
                     self._quarantine(req)
                     continue
                 self._fail(exc)
-            if not ok:
-                self._quarantine(req)
-                continue
-            req.num_computed = chunk.start + chunk.length
-            if chunk.is_last:
-                reason = self._emit(req, int(tok), emitted)
-                if reason is not None:
-                    self.scheduler.finish(req, reason)
-                    self._on_finished(req)
-                elif self.role == "prefill" and not req.colocate:
-                    # disaggregated prefill (ISSUE 18): the request's
-                    # block-aligned pages donate to the radix tree and
-                    # the request finishes "handoff" instead of joining
-                    # the decode batch — the fleet pulls the pages to a
-                    # decode-role worker via export_prefix. The first
-                    # token was already emitted above, so the decode
-                    # side resumes from index 1 with zero token loss.
-                    req.handoff_prefix_len = \
-                        self.scheduler.finish_handoff(req)
-                    self.metrics.counters["prefill_handoffs"] += 1
-                    self._on_finished(req)
-                else:
-                    self.scheduler.on_prefilled(req)
+            with profiler.RecordEvent("serving.emit"):
+                self._after_chunk(chunk, tok, ok, emitted)
 
         decodes = [r for r in sched.decodes
                    if r.state is not RequestState.FINISHED]
@@ -2083,20 +2108,52 @@ class ServingEngine:
             else:
                 self._plain_decode_step(decodes, emitted)
 
-        self.metrics.on_step()
-        self.metrics.update_gauges(
-            queue_depth=self.scheduler.queue_depth,
-            running=len(self.scheduler.running),
-            kv_used_pages=self.allocator.num_used,
-            kv_occupancy=self.allocator.occupancy(),
-            cached_pages=self.radix.num_cached_pages if self.radix else 0,
-            radix_nodes=self.radix.num_nodes if self.radix else 0,
-            radix_evicted_pages=(self.radix.num_evicted_pages
-                                 if self.radix else None),
-            **self._spill_gauges())
-        self._record_step(pre, n_chunks=len(sched.prefills),
-                          n_decode=len(decodes), n_emitted=len(emitted))
+        with profiler.RecordEvent("serving.bookkeeping"):
+            self.metrics.on_step()
+            self.metrics.update_gauges(
+                queue_depth=self.scheduler.queue_depth,
+                running=len(self.scheduler.running),
+                kv_used_pages=self.allocator.num_used,
+                kv_occupancy=self.allocator.occupancy(),
+                cached_pages=(self.radix.num_cached_pages
+                              if self.radix else 0),
+                radix_nodes=self.radix.num_nodes if self.radix else 0,
+                radix_evicted_pages=(self.radix.num_evicted_pages
+                                     if self.radix else None),
+                **self._spill_gauges())
+            self._record_step(pre, n_chunks=len(sched.prefills),
+                              n_decode=len(decodes),
+                              n_emitted=len(emitted))
         return emitted
+
+    def _after_chunk(self, chunk, tok, ok, emitted):
+        """What a chunk's result does to its request: quarantine, or the
+        computed length and, after the last chunk, the first token and
+        finish / hand-off / joining the decode batch."""
+        req = chunk.request
+        if not ok:
+            self._quarantine(req)
+            return
+        req.num_computed = chunk.start + chunk.length
+        if not chunk.is_last:
+            return
+        reason = self._emit(req, tok, emitted)
+        if reason is not None:
+            self.scheduler.finish(req, reason)
+            self._on_finished(req)
+        elif self.role == "prefill" and not req.colocate:
+            # disaggregated prefill (ISSUE 18): the request's
+            # block-aligned pages donate to the radix tree and the
+            # request finishes "handoff" instead of joining the decode
+            # batch — the fleet pulls the pages to a decode-role worker
+            # via export_prefix. The first token was already emitted
+            # above, so the decode side resumes from index 1 with zero
+            # token loss.
+            req.handoff_prefix_len = self.scheduler.finish_handoff(req)
+            self.metrics.counters["prefill_handoffs"] += 1
+            self._on_finished(req)
+        else:
+            self.scheduler.on_prefilled(req)
 
     def _record_step(self, pre: Dict[str, int], *, n_chunks: int,
                      n_decode: int, n_emitted: int):
@@ -2167,27 +2224,29 @@ class ServingEngine:
                 degraded = True
             else:
                 self._fail(exc)
-        n0 = len(emitted)
-        for i, req in enumerate(decodes):
-            if not oks[i]:
-                self._quarantine(req)
-                continue
-            reason = self._emit(req, int(toks[i]), emitted)
-            if reason is not None:
-                self.scheduler.finish(req, reason)
-                self._on_finished(req)
-        if not degraded:
-            # TPOT sample: launch wall seconds / tokens emitted, so the
-            # per-token percentiles stay comparable across K (ISSUE 13)
-            self.metrics.on_decode_launch(1, len(decodes),
-                                          len(emitted) - n0,
-                                          self._last_launch_s)
-        else:
-            # solo isolation launches counted decode_tokens in
-            # _run_decode; keep the tokens-per-launch denominator
-            # honest (no TPOT sample — solo timings aren't a batch
-            # launch's)
-            self.metrics.on_decode_launch(1, len(decodes), 0, None)
+        with profiler.RecordEvent("serving.emit"):
+            n0 = len(emitted)
+            for i, req in enumerate(decodes):
+                if not oks[i]:
+                    self._quarantine(req)
+                    continue
+                reason = self._emit(req, int(toks[i]), emitted)
+                if reason is not None:
+                    self.scheduler.finish(req, reason)
+                    self._on_finished(req)
+            if not degraded:
+                # TPOT sample: launch wall seconds / tokens emitted, so
+                # the per-token percentiles stay comparable across K
+                # (ISSUE 13)
+                self.metrics.on_decode_launch(1, len(decodes),
+                                              len(emitted) - n0,
+                                              self._last_launch_s)
+            else:
+                # solo isolation launches counted decode_tokens in
+                # _run_decode; keep the tokens-per-launch denominator
+                # honest (no TPOT sample — solo timings aren't a batch
+                # launch's)
+                self.metrics.on_decode_launch(1, len(decodes), 0, None)
 
     def _isolate_poisoned(self, reqs: List[Request]):
         """Degraded mode for an UNATTRIBUTED poison failure of a decode

@@ -230,3 +230,97 @@ def test_rms_norm_rows(for_chip, one_chip):
     text = _compiled_text(rms_norm_rows, _sds((2048, 4096), BF16, one_chip),
                           _sds((4096,), BF16, one_chip))
     assert MARKER in text
+
+
+# The names the benchmark's kernel metrics match (PERF.md lists them once).
+# Each case compiles inside a function called `program`, as the engine's
+# decode programs are: unnamed, the custom call would be `program.N`.
+def _flash_args(one_chip):
+    return (_sds(QKV, BF16, one_chip),) * 3
+
+
+def _flash_fwd(one_chip):
+    return (lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=True),
+            _flash_args(one_chip))
+
+
+def _flash_bwd(one_chip):
+    def loss(q, k, v):
+        out = fa.flash_attention_bshd(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2)), _flash_args(one_chip)
+
+
+def _paged16(one_chip):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    B, H, KVH, D, pages, page = 8, 32, 8, 128, 1024, 16
+    cache = _sds((pages, KVH, page, D), BF16, one_chip)
+    return paged_attention_decode, (
+        _sds((B, H, D), BF16, one_chip), cache, cache,
+        _sds((B, 2048 // page), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip))
+
+
+def _rms_norm(one_chip):
+    from paddle_tpu.kernels.fused_norm import rms_norm_rows
+    return rms_norm_rows, (_sds((2048, 4096), BF16, one_chip),
+                           _sds((4096,), BF16, one_chip))
+
+
+def _fused_adamw(one_chip):
+    from paddle_tpu.kernels.fused_optimizer import (LANES, adamw_scalars,
+                                                    fused_adamw_bucket)
+    rows = 1 << 16
+    scalars = adamw_scalars(1e-3, 0.9, 0.999, 1e-8, 0.01, 1)
+    f32 = _sds((rows, LANES), jnp.float32, one_chip)
+    return (lambda g, w, m, v, s: fused_adamw_bucket(g, w, m, v, s,
+                                                     param_dtype=BF16),
+            (_sds((rows, LANES), BF16, one_chip), f32, f32, f32,
+             _sds(np.shape(scalars), jnp.float32, one_chip)))
+
+
+def _lora(one_chip):
+    from paddle_tpu.kernels.lora_matmul import lora_matmul
+    B, H, R, N, S = 8, 4096, 16, 4096, 4
+    return lora_matmul, (_sds((B, H), BF16, one_chip),
+                         _sds((B,), jnp.int32, one_chip),
+                         _sds((S, H, R), jnp.float32, one_chip),
+                         _sds((S, R, N), jnp.float32, one_chip))
+
+
+def _quant(one_chip):
+    from paddle_tpu.kernels.quant_matmul import quant_matmul
+    M, K, N = 32, 4096, 14336
+    return quant_matmul, (_sds((M, K), BF16, one_chip),
+                          _sds((K, N), jnp.int8, one_chip),
+                          _sds((N,), BF16, one_chip))
+
+
+KERNEL_NAMES = [("flash_attention_fwd", _flash_fwd),
+                ("flash_attention_bwd_dq", _flash_bwd),
+                ("flash_attention_bwd_dkv", _flash_bwd),
+                ("paged_attention_decode", _paged16),
+                ("rms_norm", _rms_norm), ("fused_adamw", _fused_adamw),
+                ("lora_matmul", _lora), ("quant_matmul", _quant)]
+
+
+def _custom_call_names(text):
+    import re
+    return re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*"
+                      r'custom_call_target="tpu_custom_call"', text, re.M)
+
+
+@pytest.mark.parametrize("name,case", KERNEL_NAMES,
+                         ids=[n for n, _ in KERNEL_NAMES])
+def test_kernel_name_in_compiled_text(for_chip, one_chip, name, case):
+    """The `name=` of each `pallas_call` is the compiled instruction's
+    name (through jit, jvp and transpose as a substring): what a device
+    trace, and with it the benchmark's kernel metrics, can match."""
+    fn, args = case(one_chip)
+
+    def program(*a):
+        return fn(*a)
+
+    calls = _custom_call_names(_compiled_text(program, *args))
+    assert any(name in c for c in calls), calls
+    assert not any(c.startswith("program") for c in calls), calls
