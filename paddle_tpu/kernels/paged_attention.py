@@ -16,18 +16,26 @@ Design:
     int32 block table;
   * decode query (B, H, D) is viewed as (B, KVH, G, D) with G = H//KVH
     grouped-query heads sharing one KV head;
-  * grid (B, max_pages) with the page dimension innermost: the block
-    table and per-sequence lengths ride scalar prefetch, the page index
-    map gathers `block_tables[b, i]` so Pallas streams exactly the
-    pages this sequence owns (double-buffered HBM->VMEM), one whole
-    page (all kv heads) per step;
-  * online softmax over pages with (G, 128) lane-broadcast running
-    stats; pages past ceil(len/page_size) skip all compute via pl.when;
-  * positions >= seq_len inside the last page are masked in-block.
+  * the block table and per-sequence lengths ride scalar prefetch; a
+    grid step GATHERS `fold` pages of one sequence (one BlockSpec
+    each, all kv heads, `block_tables[b, i*fold + f]`, double-buffered
+    HBM->VMEM) and COMPUTES on them as one token tile of T = fold *
+    page_size tokens per kv head, whatever the page size
+    (`_fold_pages`: 256 tokens or two pages a step, VMEM allowing) — a
+    page is only the unit of the gather;
+  * online softmax over token tiles with (KVH, G, 128) lane-broadcast
+    running stats and the kv heads batched in one dot_general; steps
+    past ceil(len/T) are not in the grid: it is the flat list of the
+    rows' live steps, its bound their sum (a dynamic grid bound), and
+    scalar prefetch names each step's row, step and first table slot;
+  * positions >= seq_len inside the last tile are masked in-block.
 
-The kernel is bandwidth-bound (one pass over the live KV), which is the
-same regime the reference's CUDA kernel targets; MXU utilisation is
-irrelevant at decode G sizes.
+What bounds it (PR 28, v5e; `_decode_kernel` has the numbers): with
+page-sized compute tiles the kernel was bound by what it did with a
+page once in VMEM (82 GB/s at page 16); on token tiles it streams the
+live KV at 420-560 GB/s at page 16 and 740 GB/s at page 128, and what
+is left at page 16 is the scalar core's work per gathered page. MXU
+utilisation is irrelevant at decode G sizes.
 
 Quantized KV pages (ISSUE 6): the cache may instead hold int8 values
 with fp32 scales at PER-(slot, kv-head) granularity, stored page-major
@@ -108,18 +116,42 @@ def paged_page_bytes(num_kv_heads, page_size, head_dim, kv_dtype=None):
     return 2 * num_kv_heads * page_size * (head_dim * width + scale_b)
 
 
-def _decode_kernel(bt_ref, sl_ref, q_ref, *rest_refs, sm_scale, page_size,
-                   nsteps, kvh, fold, quantized=False):
-    """Grid (B, nsteps); one step streams `fold` gathered pages for ALL
-    kv heads. Folding matters: with one 16-token page per step the DMAs
-    are 64 KB and per-step overhead dominates (measured 78 GB/s on v5e;
-    401 GB/s once ~128 tokens move per step), so small serving pages
-    are batched until a step carries >= ~128 tokens' worth of KV.
+def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
+                   *rest_refs, sm_scale, page_size, fold, quantized=False):
+    """One step of the flat grid: `row_ref[w]`'s step `step_ref[w]`. It
+    GATHERS `fold` pages (one BlockSpec fetch each, all kv heads) and
+    COMPUTES on them as one token tile: the pages are upcast and joined
+    along the token axis into a (KVH, T, D) K tile and V tile, T = fold
+    * page_size, and the step is one batched (G, D) x (D, T) dot, one
+    mask / max / exp / sum over lane-dense (KVH, G, T) scores, one
+    update of m / l / acc and one batched (G, T) x (T, D) dot. A page
+    is only the unit of the gather.
+
+    History. Round 4 (v5e, B16 KVH8 D128 S2048): one 16-token page a
+    step ran at 78 GB/s; folding the FETCHES to 128 tokens a step gave
+    96 at page 16 and 401-472 at page 128 — the fold fixed the copies
+    and left the compute on page-sized tiles, 64 per-(page, head)
+    softmax updates a step on (4, 16) scores, 6 % of a vreg each.
+    PR 28 (v5e, the serving cell's call: B64 H32 KVH8 D128 page 16,
+    64-page tables, f32 q). Ragged 100-1,000: that kernel 1.93 ms
+    (82 GB/s); the same fetches with the body touching one vreg a page
+    0.38 ms; the same body over one resident page 1.65 ms — bound by
+    what it did with a page in VMEM, not by the copies. Joined tiles
+    over the same (B, table / fold) grid: 0.51 / 0.44 / 0.43 ms at
+    T 128 / 256 / 512 with the heads batched in one dot_general (0.72 /
+    0.74 / 0.59 with a per-head loop). What was left went with B x
+    table width, not with the tokens (a KVH 2 shard, a quarter of the
+    bytes, took 0.38 ms): every slot of every step costs the scalar
+    core its index map, compare and copy issue, live or dead. At the
+    cell's contexts (mean 434 of 1,024): 1.52 ms before, 0.42 on that
+    grid, 0.31 on the flat grid of live steps, 0.27 (420 GB/s) with the
+    table flattened so that a slot's page is one scalar load away; full
+    2,048-token rows 1.38 -> 0.24 ms at page 16 (558 GB/s) and 0.295 ->
+    0.18 at page 128 (736 GB/s).
 
     quantized=True streams int8 value pages plus their fp32 per-slot
-    scale pages (same gathered page ids) and dequantizes on the VMEM
-    side — K/V bytes moved drop ~2x, which is the whole win in this
-    bandwidth-bound regime."""
+    scale pages (same gathered page ids) and dequantizes each page on
+    the VMEM side before the join — K/V bytes moved drop ~2x."""
     k_refs = rest_refs[:fold]
     v_refs = rest_refs[fold:2 * fold]
     if quantized:
@@ -127,11 +159,26 @@ def _decode_kernel(bt_ref, sl_ref, q_ref, *rest_refs, sm_scale, page_size,
         vs_refs = rest_refs[3 * fold:4 * fold]
         o_ref, acc_ref, m_ref, l_ref = rest_refs[4 * fold:]
     else:
+        ks_refs = vs_refs = (None,) * fold
         o_ref, acc_ref, m_ref, l_ref = rest_refs[2 * fold:]
     sm_scale = np.float32(sm_scale)
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    w = pl.program_id(0)
+    b = row_ref[w]
+    i = step_ref[w]
     sl = sl_ref[b]
+    tile_tokens = fold * page_size
+
+    def tile(page_refs, scale_refs):
+        """The step's gathered pages as one (KVH, T, D) fp32 tile. A
+        page's (page_size, D) slice is whole sublane tiles once it is
+        fp32, so the join is tile placement, not a relayout."""
+        pages = []
+        for page_ref, scale_ref in zip(page_refs, scale_refs):
+            page = page_ref[0].astype(jnp.float32)      # (KVH, page, D)
+            if quantized:
+                page = page * scale_ref[0][:, :, None]  # fp32 dequant
+            pages.append(page)
+        return pages[0] if fold == 1 else jnp.concatenate(pages, axis=1)
 
     @pl.when(i == 0)
     def _init():
@@ -139,43 +186,31 @@ def _decode_kernel(bt_ref, sl_ref, q_ref, *rest_refs, sm_scale, page_size,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(i * fold * page_size < sl)
-    def _step():
-        for f in range(fold):                          # static unroll
-            for h in range(kvh):                       # static unroll
-                q = q_ref[0, h].astype(jnp.float32)    # (G, D)
-                k = k_refs[f][0, h].astype(jnp.float32)  # (page, D)
-                v = v_refs[f][0, h].astype(jnp.float32)
-                if quantized:
-                    k = k * ks_refs[f][0, h][:, None]  # fp32 dequant
-                    v = v * vs_refs[f][0, h][:, None]
-                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-                s = s * sm_scale                       # (G, page)
-                G, P = s.shape
-                pos = ((i * fold + f) * page_size
-                       + jax.lax.broadcasted_iota(jnp.int32, (G, P), 1))
-                s = jnp.where(pos < sl, s, NEG_INF)
-                m_prev = m_ref[h, :, :1]
-                l_prev = l_ref[h, :, :1]
-                m_cur = jnp.max(s, axis=1, keepdims=True)
-                m_new = jnp.maximum(m_prev, m_cur)
-                p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-                alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
-                                  jnp.exp(m_prev - m_new))
-                l_ref[h] = jnp.broadcast_to(
-                    l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
-                    l_ref.shape[1:])
-                m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+    q = q_ref[0].astype(jnp.float32)                # (KVH, G, D)
+    s = jax.lax.dot_general(q, tile(k_refs, ks_refs),
+                            (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32)
+    s = s * sm_scale                                # (KVH, G, T)
+    pos = (i * tile_tokens
+           + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
+    s = jnp.where(pos < sl, s, NEG_INF)
+    m_prev = m_ref[:, :, :1]
+    l_prev = l_ref[:, :, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                      jnp.exp(m_prev - m_new))
+    l_ref[...] = jnp.broadcast_to(
+        l_prev * alpha + jnp.sum(p, axis=2, keepdims=True), l_ref.shape)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, tile(v_refs, vs_refs), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(i == nsteps - 1)
+    @pl.when((i + 1) * tile_tokens >= sl)
     def _finalize():
-        for h in range(kvh):
-            l = jnp.maximum(l_ref[h, :, :1], np.float32(1e-30))
-            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], np.float32(1e-30))
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def check_supported_paged(q_shape, cache_shape, dtype, kv_dtype=None):
@@ -215,28 +250,44 @@ def check_supported_paged(q_shape, cache_shape, dtype, kv_dtype=None):
                          "pages)")
 
 
-def _fold_pages(page_size, max_pages, fold_tokens=None):
-    """Pages batched per grid step: max(128 tokens, 2 pages), clamped to
-    the table width. Single source of truth for the kernel AND the
-    static legality enumeration (they drifted once — don't re-fork)."""
-    if fold_tokens is None:
-        fold_tokens = max(128, 2 * page_size)
-    return max(1, min(fold_tokens // page_size, max_pages))
+# A grid step's token tile: 256 tokens, or two pages where a page is
+# larger — as wide as the round-4 sweep found best at page 128 (2-page
+# steps) and the best of 128 / 256 / 512 at page 16 over ragged serving
+# lengths (PR 28, the serving cell's call: 0.32 / 0.31 / 0.35 ms; 512
+# wins by a tenth only where every row fills its table) — VMEM
+# allowing: a tile token costs its K and V page slices double-buffered
+# plus both fp32 upcasts.
+_TILE_TOKENS = 256
+_TILE_VMEM_BYTES = 8 << 20
+
+
+def _fold_pages(page_size, max_pages, num_kv_heads, head_dim, cache_dtype):
+    """Pages gathered per grid step, from the shapes alone: the token
+    tile over the page size, clamped to the table width. Single source
+    of truth for the kernel AND the static legality enumeration (they
+    drifted once — don't re-fork)."""
+    width = jnp.dtype(cache_dtype).itemsize
+    per_token = num_kv_heads * head_dim * (2 * 2 * width + 2 * 4)
+    tokens = min(max(_TILE_TOKENS, 2 * page_size),
+                 _TILE_VMEM_BYTES // per_token)
+    return max(1, min(max(tokens, 128) // page_size, max_pages))
 
 
 def paged_blockspecs(B, H, KVH, D, page_size, num_pages, max_pages=None,
-                     fold_tokens=None, quantized=False):
+                     quantized=False):
     """The exact (block_shape, array_shape) pairs the pallas_call below
     constructs — including the `fold` repetition of the k/v page specs
-    the folded grid uses — plus the VMEM scratch shapes; enumerable for
+    the gather uses — plus the VMEM scratch shapes; enumerable for
     the static legality test without running the kernel. quantized=True
     appends the fp32 scale-page specs ((1, KVH, page_size) blocks over
     (num_pages, KVH, page_size) arrays — legal because both trailing
-    block dims equal the array dims) the int8 path adds."""
+    block dims equal the array dims) the int8 path adds; the pages are
+    int8 then, bf16 otherwise (the two dtypes the engine stores)."""
     G = H // KVH
     if max_pages is None:
         max_pages = num_pages
-    fold = _fold_pages(page_size, max_pages, fold_tokens)
+    fold = _fold_pages(page_size, max_pages, KVH, D,
+                       jnp.int8 if quantized else jnp.bfloat16)
     page = ((1, KVH, page_size, D), (num_pages, KVH, page_size, D))
     scale = ((1, KVH, page_size), (num_pages, KVH, page_size))
     specs = (
@@ -250,9 +301,38 @@ def paged_blockspecs(B, H, KVH, D, page_size, num_pages, max_pages=None,
     return specs, scratch
 
 
+def _live_steps(seq_lens, tile_tokens, steps_per_row, fold):
+    """The kernel's grid: the flat list of the rows' LIVE steps. Row b
+    runs ceil(len / T) of them (one for an empty row, so that every
+    output block is written); the bound is their sum, known on the
+    device only. A step past a row's length costs the scalar core as
+    much as a live one (_decode_kernel), and at serving lengths half
+    the table is such steps.
+
+    Returns (total, row_of, step_of, first_of): for flat step w its
+    row, its step within the row and its first slot in the flattened
+    (B * steps_per_row * fold) block table — one scalar load then names
+    a page, where (row, step) would take three. Every entry, live or
+    not, names slots inside the table, and the caller appends a tail of
+    zeros: the pipeline evaluates index maps some steps AHEAD of the
+    running one, past the bound too, and a scalar load is not
+    bounds-checked."""
+    B = seq_lens.shape[0]
+    steps_of = jnp.clip((seq_lens + (tile_tokens - 1)) // tile_tokens,
+                        1, steps_per_row)
+    ends = jnp.cumsum(steps_of, dtype=jnp.int32)
+    flat = jnp.arange(B * steps_per_row + 1, dtype=jnp.int32)
+    row_of = jnp.minimum(
+        jnp.sum(flat[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        B - 1)
+    step_of = jnp.minimum(flat - (ends - steps_of)[row_of],
+                          steps_per_row - 1)
+    first_of = (row_of * steps_per_row + step_of) * fold
+    return ends[-1], row_of, step_of, first_of
+
+
 def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
-                           sm_scale=None, fold_tokens=None,
-                           k_scale=None, v_scale=None):
+                           sm_scale=None, k_scale=None, v_scale=None):
     """One decode step of attention over a paged KV cache.
 
     q:            (B, H, D) — current-step queries.
@@ -287,53 +367,65 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         sm_scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, KVH, G, D)
     bt = block_tables.astype(jnp.int32)
-    sl = seq_lens.astype(jnp.int32)
+    # a length past the table reads the whole table, and no further
+    sl = jnp.minimum(seq_lens.astype(jnp.int32), max_pages * page_size)
 
-    # Fold pages so one grid step moves >= max(128 tokens, 2 pages) of
-    # KV (swept on v5e at B16 KVH8 D128 S2048: 16-token steps ran at
-    # 78 GB/s — DMA-latency-bound — vs 96/188/268 GB/s folded at
-    # page 16/32/64, and 2-page folds at page 128 hit 472 GB/s vs 401
-    # unfolded; folds deeper than this regressed every small-page
-    # config). Pad the block table to a fold multiple; padded slots
-    # reuse page 0 and are masked by seq_lens.
-    fold = _fold_pages(page_size, max_pages, fold_tokens)
+    # A step gathers `fold` pages and computes on them as one token
+    # tile (_decode_kernel has the measurements). Pad the block table
+    # to a fold multiple; padded slots reuse page 0 and are masked by
+    # seq_lens.
+    fold = _fold_pages(page_size, max_pages, KVH, D, k_cache.dtype)
     if max_pages % fold != 0:
         pad = fold - max_pages % fold
         bt = jnp.pad(bt, ((0, 0), (0, pad)))
         max_pages += pad
-    nsteps = max_pages // fold
+
+    total, row_of, step_of, first_of = _live_steps(
+        sl, fold * page_size, max_pages // fold, fold)
+    # Every scalar-prefetch array ends in 128+ zero words (row 0, step 0,
+    # slot 0, the pad page, length 0: all valid) on a 128-word boundary.
+    # Without a tail a v5e HALTED (on-device check) on the engine's small
+    # buckets — B 2 x 4-, 8-, 16-page tables, off and on with the data —
+    # because the index maps are evaluated past the last step and read
+    # whatever follows the arrays in SMEM as a row and a page id; 8 more
+    # valid entries on the three step arrays were enough in a probe
+    # (PR 28), interpret mode clamps the read and shows nothing.
+    prefetch = [jnp.pad(a, (0, -a.shape[0] % 128 + 128))
+                for a in (bt.reshape(-1), first_of, sl, row_of, step_of)]
 
     kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                               page_size=page_size, nsteps=nsteps,
-                               kvh=KVH, fold=fold, quantized=quantized)
+                               page_size=page_size, fold=fold,
+                               quantized=quantized)
+
+    def row_block(w, slots, first_of, sl, row_of, step_of):
+        return row_of[w], _I0, _I0, _I0
 
     def page_spec(f):
         return pl.BlockSpec(
             (1, KVH, page_size, D),
-            lambda b, i, bt, sl, f=f: (bt[b, i * fold + f],
-                                       _I0, _I0, _I0))
+            lambda w, slots, first_of, *_, f=f: (
+                slots[first_of[w] + f], _I0, _I0, _I0))
 
     def scale_spec(f):
         # same gathered page id as the value page it scales
         return pl.BlockSpec(
             (1, KVH, page_size),
-            lambda b, i, bt, sl, f=f: (bt[b, i * fold + f], _I0, _I0))
+            lambda w, slots, first_of, *_, f=f: (
+                slots[first_of[w] + f], _I0, _I0))
 
     scale_specs = ([scale_spec(f) for f in range(fold)] * 2
                    if quantized else [])
     scale_args = ([k_scale] * fold + [v_scale] * fold) if quantized else []
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nsteps),
+        num_scalar_prefetch=5,
+        grid=(total,),
         in_specs=(
-            [pl.BlockSpec((1, KVH, G, D),
-                          lambda b, i, *_: (b, _I0, _I0, _I0))]
+            [pl.BlockSpec((1, KVH, G, D), row_block)]
             + [page_spec(f) for f in range(fold)]      # k pages
             + [page_spec(f) for f in range(fold)]      # v pages
             + scale_specs                              # k/v scale pages
         ),
-        out_specs=pl.BlockSpec((1, KVH, G, D),
-                               lambda b, i, *_: (b, _I0, _I0, _I0)),
+        out_specs=pl.BlockSpec((1, KVH, G, D), row_block),
         scratch_shapes=[
             pltpu.VMEM((KVH, G, D), jnp.float32),
             pltpu.VMEM((KVH, G, _STATS_LANES), jnp.float32),
@@ -345,16 +437,16 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret_mode(),
         name="paged_attention_decode",
-    )(bt, sl, qg, *([k_cache] * fold), *([v_cache] * fold), *scale_args)
+    )(*prefetch, qg, *([k_cache] * fold), *([v_cache] * fold), *scale_args)
     return out.reshape(B, H, D)
 
 
 def paged_attention_decode_tp(q, k_cache, v_cache, block_tables, seq_lens,
                               mesh, axis="model", sm_scale=None,
-                              fold_tokens=None, k_scale=None, v_scale=None):
+                              k_scale=None, v_scale=None):
     """Tensor-parallel decode attention: query heads and the KV pages'
     head dim sharded over mesh axis `axis` (ISSUE 8).
 
@@ -394,8 +486,7 @@ def paged_attention_decode_tp(q, k_cache, v_cache, block_tables, seq_lens,
     def local(qq, kc, vc, bt, sl, *scales):
         ks, vs = scales if scales else (None, None)
         return paged_attention_decode(
-            qq, kc, vc, bt, sl, sm_scale=sm_scale,
-            fold_tokens=fold_tokens, k_scale=ks, v_scale=vs)
+            qq, kc, vc, bt, sl, sm_scale=sm_scale, k_scale=ks, v_scale=vs)
 
     in_specs = (q_spec, page_spec, page_spec, P(), P())
     args = (q, k_cache, v_cache, block_tables, seq_lens)

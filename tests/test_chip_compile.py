@@ -188,6 +188,22 @@ def test_paged_attention_decode_tp4(for_chip, chip_mesh):
     assert MARKER in text
 
 
+# The serving cell's own call (benchmarks/: B 64 x a 64-page table at page
+# 16, and the engine sends q as f32), and the same on the KVH 8/4 = 2
+# heads of a TP-4 shard.
+@pytest.mark.parametrize("H,KVH", [(32, 8), (8, 2)],
+                         ids=["cell", "kvh2-shard"])
+def test_paged_attention_decode_serving_cell(for_chip, one_chip, H, KVH):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    B, D, pages, page, table = 64, 128, 4096, 16, 64
+    cache = _sds((pages, KVH, page, D), BF16, one_chip)
+    text = _compiled_text(
+        paged_attention_decode, _sds((B, H, D), jnp.float32, one_chip),
+        cache, cache, _sds((B, table), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip))
+    assert MARKER in text
+
+
 def test_quant_matmul(for_chip, one_chip):
     from paddle_tpu.kernels.quant_matmul import quant_matmul
     M, K, N = 32, 4096, 14336

@@ -35,18 +35,23 @@ def test_paged_blockspecs_tpu_legal(B, H, KVH, D, page, S, quantized):
                           kv_dtype="int8" if quantized else None)
     specs, scratch = paged_blockspecs(B, H, KVH, D, page, num_pages,
                                       quantized=quantized)
+    page_spec = ((1, KVH, page, D), (num_pages, KVH, page, D))
+    fold = specs.count(page_spec) // 2
+    assert fold >= 1 and fold * page >= min(128, S)     # a token tile a step
     if quantized:
         # the int8 path streams a scale page per value page: 2*fold
         # extra specs, every one (1, KVH, page) over the page-major
         # fp32 scale array
-        plain, _ = paged_blockspecs(B, H, KVH, D, page, num_pages)
-        assert len(specs) == len(plain) + 2 * ((len(plain) - 2) // 2)
-        assert ((1, KVH, page), (num_pages, KVH, page)) in specs
+        assert len(specs) == 2 + 4 * fold
+        assert specs.count(((1, KVH, page), (num_pages, KVH, page))) \
+            == 2 * fold
+    else:
+        assert len(specs) == 2 + 2 * fold
     for block, array in specs:
         assert mosaic_legal(block, array), (
             f"illegal block {block} for array {array} "
             f"(H={H} KVH={KVH} D={D} page={page} quant={quantized})")
-    # scratch refs: the kernel sub-slices the lane dim (m_ref[h, :, :1]),
+    # scratch refs: the kernel sub-slices the lane dim (m_ref[:, :, :1]),
     # which Mosaic only supports from offset 0 on a 128-lane-aligned
     # buffer; the accumulator's lanes are the head_dim
     for shape in scratch:
@@ -56,6 +61,101 @@ def test_paged_blockspecs_tpu_legal(B, H, KVH, D, page, S, quantized):
     assert all(s[-1] == 128 for s in stats), (
         "running-stat buffers must be exactly 128 lanes (lane-broadcast "
         f"max/sum): {stats}")
+
+
+def _pallas_call_eqn(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for v in eqn.params.values():
+            found = _pallas_call_eqn(v.jaxpr) if hasattr(v, "jaxpr") else None
+            if found is not None:
+                return found
+    return None
+
+
+# the serving cell's call, a KVH/tp shard, a table no fold divides, a
+# page that is already a tile, and the int8 path at both ends
+BUILT = [(64, 32, 8, 128, 16, 64, "float32", None),
+         (64, 8, 2, 128, 16, 64, "float32", None),
+         (8, 32, 8, 128, 16, 9, "bfloat16", None),
+         (32, 32, 8, 128, 16, 160, "bfloat16", None),
+         (8, 32, 8, 128, 128, 16, "bfloat16", None),
+         (8, 32, 8, 128, 128, 16, "bfloat16", "int8"),
+         (8, 32, 32, 128, 16, 128, "bfloat16", None),
+         (4, 16, 2, 64, 8, 64, "bfloat16", "int8")]
+
+
+@pytest.mark.parametrize("B,H,KVH,D,page,P,qdtype,kv_dtype", BUILT)
+def test_paged_blockspecs_are_what_the_call_builds(B, H, KVH, D, page, P,
+                                                   qdtype, kv_dtype):
+    """`paged_blockspecs` enumerates EXACTLY the blocks and scratch of
+    the traced `pallas_call` (they drifted once): same order, same
+    block and array shapes, same scratch; the grid is the flat list of
+    live steps, its one bound known on the device only."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    S = jax.ShapeDtypeStruct
+    num_pages = B * P + 1
+    cache = S((num_pages, KVH, page, D),
+              jnp.int8 if kv_dtype else jnp.bfloat16)
+    args = [S((B, H, D), qdtype), cache, cache, S((B, P), jnp.int32),
+            S((B,), jnp.int32)]
+    fn = paged_attention_decode
+    if kv_dtype:
+        args += [S((num_pages, KVH, page), jnp.float32)] * 2
+
+        def fn(q, k, v, bt, sl, ks, vs):
+            return paged_attention_decode(q, k, v, bt, sl, k_scale=ks,
+                                          v_scale=vs)
+    eqn = _pallas_call_eqn(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert eqn.params["name"] == "paged_attention_decode"
+    gm = eqn.params["grid_mapping"]
+    built = [(tuple(getattr(d, "block_size", d) for d in bm.block_shape),
+              tuple(bm.array_aval.shape)) for bm in gm.block_mappings]
+    specs, scratch = paged_blockspecs(B, H, KVH, D, page, num_pages,
+                                      max_pages=P, quantized=bool(kv_dtype))
+    assert built == specs
+    assert [tuple(a.shape) for a in gm.scratch_avals] == scratch
+    assert len(gm.grid) == 1 and gm.num_dynamic_grid_bounds == 1
+    # the scalar-prefetch arrays end in 128+ valid zero words on a
+    # 128-word boundary: unpadded, a v5e halted on small tables
+    prefetch = eqn.invars[1:1 + gm.num_index_operands]
+    assert gm.num_index_operands == 5
+    assert all(v.aval.ndim == 1 and v.aval.shape[0] % 128 == 0
+               for v in prefetch)
+    assert prefetch[0].aval.shape[0] >= B * P + 128       # the table
+    assert prefetch[2].aval.shape[0] >= B + 128           # the lengths
+
+
+@pytest.mark.parametrize("steps_per_row", [1, 2, 4])
+def test_live_steps_stay_inside_the_table(steps_per_row):
+    """The flat grid's scalar-prefetch arrays: live steps enumerate each
+    row's steps in order, and EVERY entry — those past the bound too,
+    which the pipeline reads ahead — names slots inside the flattened
+    table (the entry past a full last row once named the slot after
+    the table's end; interpret mode clamps such a read and hides it)."""
+    import numpy as np
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.paged_attention import _live_steps
+    fold, page = 4, 16
+    T = fold * page
+    full = steps_per_row * T
+    for lens in ([full] * 3, [0, 1, full], [full, 0, 0], [T, T + 1, 5][:3],
+                 [full + 9, 3, full]):
+        lens = np.minimum(np.asarray(lens, np.int32), full)
+        total, row_of, step_of, first_of = (
+            np.asarray(a) for a in _live_steps(jnp.asarray(lens), T,
+                                               steps_per_row, fold))
+        want = [(b, i) for b, n in enumerate(lens)
+                for i in range(max(1, -(-int(n) // T)))]
+        assert int(total) == len(want)
+        assert list(zip(row_of[:total], step_of[:total])) == want
+        assert len(first_of) == len(lens) * steps_per_row + 1
+        assert (first_of == (row_of * steps_per_row + step_of) * fold).all()
+        assert first_of.min() >= 0
+        assert first_of.max() + fold <= len(lens) * steps_per_row * fold
 
 
 def test_unsupported_paged_shapes_raise():
@@ -89,9 +189,9 @@ def test_paged_decode_still_runs_after_guard():
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
 def test_paged_decode_fold_padding_parity():
-    """The fold rule batches max(128 tokens, 2 pages) per grid step and
-    pads the block table to a fold multiple; max_pages=9 at page=16
-    gives fold=8 -> pad=7, so the jnp.pad branch actually runs (fold
+    """The fold rule gathers max(256 tokens, 2 pages) per grid step and
+    pads the block table to a fold multiple; max_pages=17 at page=16
+    gives fold=16 -> pad=15, so the jnp.pad branch actually runs (fold
     clamps to max_pages, so pps must EXCEED the fold to pad). Must
     still match dense attention exactly, padded slots masked by
     seq_lens."""
@@ -100,7 +200,7 @@ def test_paged_decode_fold_padding_parity():
     import numpy as np
     from paddle_tpu.kernels.paged_attention import paged_attention_decode
 
-    B, H, KVH, D, page, pps = 2, 4, 2, 64, 16, 9
+    B, H, KVH, D, page, pps = 2, 4, 2, 64, 16, 17
     num_pages = B * pps
     rng = np.random.RandomState(0)
     kc = jnp.asarray(rng.randn(num_pages, KVH, page, D), jnp.float32)

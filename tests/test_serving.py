@@ -69,6 +69,93 @@ def test_paged_attention_decode_matches_dense(G):
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
 
+def _assert_matches_dense(out, q, kd, vd, seq_lens):
+    live = seq_lens > 0
+    ref = _dense_decode_ref(q, kd, vd, seq_lens)
+    np.testing.assert_allclose(np.asarray(out)[live], ref[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out)[~live].any()
+
+
+def _tile_edge_case(page, G, kv_dtype, KVH=2):
+    """Ragged rows on every edge of the kernel's token tiling, over a
+    table one page wider than two folds (so the block table is padded
+    and the last grid step holds one live page)."""
+    from paddle_tpu.kernels.paged_attention import _fold_pages, quantize_kv
+    D = 64
+    cache_dtype = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+    fold = _fold_pages(page, 10 ** 6, 2, D, cache_dtype)
+    T = fold * page
+    max_pages = 2 * fold + 1
+    # the empty row is a bucket-padded batch row: its output is zeros;
+    # the last row's length overshoots its table and reads all of it
+    seq_lens = np.array([0, 1, page - 1, page, page + 1, T - 1, T, T + 1,
+                         2 * T, 2 * T + 1, max_pages * page,
+                         max_pages * page + 7], np.int32)
+    B = len(seq_lens)
+    kd, vd, kc, vc, bt = _build_paged(B, KVH, D, page, max_pages, seq_lens)
+    q = rng.randn(B, KVH * G, D).astype(np.float32)
+    if kv_dtype == "int8":
+        kq, ks = quantize_kv(jnp.asarray(kc))
+        vq, vs = quantize_kv(jnp.asarray(vc))
+        kq_d, ks_d = quantize_kv(jnp.asarray(kd))      # per-slot: same values
+        vq_d, vs_d = quantize_kv(jnp.asarray(vd))
+        kd = np.asarray(kq_d, np.float32) * np.asarray(ks_d)[..., None]
+        vd = np.asarray(vq_d, np.float32) * np.asarray(vs_d)[..., None]
+        return q, (kq, vq), dict(k_scale=ks, v_scale=vs), bt, seq_lens, kd, vd
+    kc, vc = jnp.asarray(kc, jnp.bfloat16), jnp.asarray(vc, jnp.bfloat16)
+    kd = np.asarray(jnp.asarray(kd, jnp.bfloat16).astype(jnp.float32))
+    vd = np.asarray(jnp.asarray(vd, jnp.bfloat16).astype(jnp.float32))
+    return q, (kc, vc), {}, bt, seq_lens, kd, vd
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("G", [1, 4, 8])
+@pytest.mark.parametrize("page", [8, 16, 32, 128])
+def test_paged_attention_decode_tile_edges(page, G, kv_dtype):
+    """The joined-tile kernel against dense float32 attention over the
+    values the cache holds: a page is only the unit of the gather, so
+    no page size, group size or storage dtype may change the answer."""
+    q, caches, scales, bt, seq_lens, kd, vd = _tile_edge_case(page, G,
+                                                              kv_dtype)
+    out = paged_attention_decode(jnp.asarray(q), *caches, jnp.asarray(bt),
+                                 jnp.asarray(seq_lens), **scales)
+    _assert_matches_dense(out, q, kd, vd, seq_lens)
+
+
+@pytest.mark.parametrize("tp,kv_dtype", [(2, "bf16"), (4, "bf16"),
+                                         (4, "int8")])
+def test_paged_attention_decode_tp_tile_edges(tp, kv_dtype):
+    """The TP wrapper runs the same kernel on KVH/tp = 2 heads a shard:
+    the same tile edges, through a `shard_map` over `model`."""
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode_tp
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:tp]), ("model",))
+    q, caches, scales, bt, seq_lens, kd, vd = _tile_edge_case(
+        16, 4, kv_dtype, KVH=2 * tp)
+    out = paged_attention_decode_tp(jnp.asarray(q), *caches, jnp.asarray(bt),
+                                    jnp.asarray(seq_lens), mesh, **scales)
+    _assert_matches_dense(out, q, kd, vd, seq_lens)
+
+
+@pytest.mark.parametrize("page", [16, 128])
+def test_paged_attention_decode_rows_stay_independent(page):
+    """A row's steps gather its own table's slots only (its pages, and
+    past them the pad page), never another sequence's: NaN in every
+    page one row owns changes that row's answer and no other's — what
+    the engine's per-row quarantine rests on."""
+    q, (kc, vc), _, bt, seq_lens, _, _ = _tile_edge_case(page, 4, "bf16")
+    args = (jnp.asarray(bt), jnp.asarray(seq_lens))
+    clean = np.asarray(paged_attention_decode(jnp.asarray(q), kc, vc, *args))
+    victim = 7                                           # the T + 1 row
+    owned = bt[victim][:-(-int(seq_lens[victim]) // page)]
+    kc = kc.at[owned].set(jnp.nan)
+    vc = vc.at[owned].set(jnp.nan)
+    out = np.asarray(paged_attention_decode(jnp.asarray(q), kc, vc, *args))
+    others = np.arange(len(seq_lens)) != victim
+    assert np.isnan(out[victim]).all()
+    np.testing.assert_array_equal(out[others], clean[others])
+
+
 def test_paged_cache_write_roundtrip():
     B, KVH, D, page, max_pages = 2, 2, 128, 16, 3
     seq_lens = np.array([page * max_pages, page * max_pages], np.int32)

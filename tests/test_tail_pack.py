@@ -26,6 +26,10 @@ def test_strings_lower_upper_unicode():
 
 
 def test_svd_lowrank_reconstructs_lowrank_matrix():
+    # the projection draws from the global key: unseeded, the result (and
+    # the 1e-3 below, at q = rank with no oversampling) went with whatever
+    # tests the worker had run before this one (seeds 0-11 read 3e-6 to 5e-4)
+    paddle.seed(1)
     A = (rng.randn(32, 4) @ rng.randn(4, 24)).astype(np.float32)
     U, S, V = paddle.linalg.svd_lowrank(paddle.to_tensor(A), q=4)
     rec = (np.asarray(U._data) * np.asarray(S._data)) @ np.asarray(V._data).T
