@@ -1,24 +1,21 @@
-"""Helpers shared by the drivers. `build_model`, the two tolerances,
-`CacheCounter`, `device_bytes` and the TPU-or-exit guard are COPIES of
-`chip_smoke.py`'s (PR 24 proved them on the chip); the benchmark keeps its
-own so that no later PR can change the yardstick by editing the program.
+"""Helpers shared by the drivers, none of them a model's (those are the
+family's, `families/<family>.py`). `CacheCounter`, `device_bytes` and the
+TPU-or-exit guard are COPIES of `chip_smoke.py`'s (PR 24 proved them on
+the chip); the benchmark keeps its own so that no later PR can change the
+yardstick by editing the program.
 """
 from __future__ import annotations
 
 import json
-import math
 import os
 import sys
 import time
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH_DIR)
-
-# keys of a configuration file that LlamaConfig takes as they are
-MODEL_KEYS = ("vocab_size", "hidden_size", "intermediate_size",
-              "num_hidden_layers", "num_attention_heads",
-              "num_key_value_heads", "max_position_embeddings",
-              "rms_norm_eps", "rope_theta", "tie_word_embeddings")
+# what a rehearsal of a toy cell on the CPU gives as its device (the work
+# counts need a kind that the table of peaks holds)
+CPU_AS = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 
 
 def say(msg: str):
@@ -31,27 +28,15 @@ def load_json(path: str) -> dict:
 
 
 def load_cell(name: str, bench_dir: str = BENCH_DIR):
-    """(cell, config) dicts found by name under `bench_dir`."""
+    """(cell, config) dicts found by name under `bench_dir`, which the
+    cell keeps: its metric files are read from there (the tests' data
+    directory for a toy cell)."""
     cell = load_json(os.path.join(bench_dir, "workloads", f"{name}.json"))
     cfg = load_json(os.path.join(bench_dir, "configs",
                                  f"{cell['config']}.json"))
     cell.setdefault("name", name)
+    cell["bench_dir"] = bench_dir
     return cell, cfg
-
-
-def llama_config(cfg: dict):
-    """The program's LlamaConfig from a configuration file: every
-    published key it has a field for, passed explicitly (its defaults for
-    `rms_norm_eps` and `rope_theta` are another model's)."""
-    from paddle_tpu.models.llama import LlamaConfig
-    kw = {k: cfg[k] for k in MODEL_KEYS}
-    if cfg.get("head_dim", kw["hidden_size"] // kw["num_attention_heads"]) \
-            != kw["hidden_size"] // kw["num_attention_heads"]:
-        raise ValueError("LlamaConfig derives head_dim = hidden / heads; "
-                         "this configuration's head_dim differs")
-    if cfg.get("sliding_window") is not None:
-        raise ValueError("the Llama-shaped path has no sliding window")
-    return LlamaConfig(**kw)
 
 
 def place_compile_cache() -> str:
@@ -99,42 +84,6 @@ def require_tpu(chips: int) -> dict:
     return device
 
 
-def build_model(cfg, dtype):
-    """LlamaForCausalLM with parameters CREATED in `dtype` (building in
-    float32 and casting after does not fit at these widths)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import LlamaForCausalLM
-    prev = paddle.get_default_dtype()
-    paddle.set_default_dtype(dtype)
-    try:
-        return LlamaForCausalLM(cfg)
-    finally:
-        paddle.set_default_dtype(prev)
-
-
-def logit_tolerance(dtype, layers: int) -> float:
-    """Relative slack (x max |logit|) between two correct evaluations of
-    ONE logit of a `layers`-deep decoder, one of them in `dtype`: one
-    rounding (eps) per layer-level accumulation, adding as a random walk
-    over depth, doubled. For bfloat16 at 16 layers that is 6.25e-2; a path
-    that computed in int8, or dropped a layer, a mask or the RoPE, moves a
-    logit by a large share of max |logit| and fails. The floor keeps
-    float32 from demanding bit-identity across differently tiled programs."""
-    import jax.numpy as jnp
-    return 2.0 * max(float(jnp.finfo(dtype).eps), 4e-5) * math.sqrt(layers)
-
-
-def loss_tolerance(dtype) -> float:
-    """Relative slack on a LOSS: 1/32 of one rounding step of `dtype`
-    (2.4e-4 for bfloat16), floored at 1e-5. Per-position errors are
-    zero-mean and average over batch x seq positions, so a correct
-    evaluation lands far inside it (PR 24's chip run read 3.6e-7 and
-    1.8e-6 in bf16 at 4096 wide) while a wrong mask or kernel moves a
-    loss by whole percents."""
-    import jax.numpy as jnp
-    return max(float(jnp.finfo(dtype).eps) / 32.0, 1e-5)
-
-
 def device_bytes(arrays) -> dict:
     """{device id: bytes} actually resident, from addressable_shards."""
     out: dict = {}
@@ -172,8 +121,11 @@ class CacheCounter:
 
 
 class Phases:
-    """Splits set-up into named parts on the host clock, from the
-    process's start."""
+    """Splits what a run does outside its window into named parts on the
+    host clock, from the process's start. Set-up is their sum but for
+    `check`: the comparison with the plain reference is paid by every run
+    and is no part of `setup_s`."""
+    NOT_SETUP = ("check",)
 
     def __init__(self, t_start: float):
         self.t_start = self._last = t_start
@@ -189,7 +141,14 @@ class Phases:
         self._last = time.time()
 
     def total(self) -> float:
-        return sum(self.parts.values())
+        return sum(v for k, v in self.parts.items()
+                   if k not in self.NOT_SETUP)
+
+
+def within(checks: dict) -> bool:
+    """`checks` is {name: (number compared, its limit)}: correct where
+    every number is at most its limit (a NaN is not)."""
+    return all(v <= lim for v, lim in checks.values())
 
 
 def percentile(values, q: float) -> float:

@@ -6,6 +6,8 @@
   counters  {"window": deltas over the window, "process": totals at exit}
             of the program's counters, as nested dicts
   trace     reduce_trace.reduce()'s result for the traced slice, or None
+  checks    {name: (number compared, its limit)}: what decided `correct`
+  memory_peak_bytes  read at the window's close, before the reference runs
   cfg, cell the configuration's and the cell's files; device, chips
 
 A metric is a file `metrics/<name>.json`: unit, layer, moves, the driver

@@ -1,5 +1,5 @@
 """Driver `train_loop`: `paddle.jit.to_static(step, state_objects=[model,
-opt])` over LlamaForCausalLM, as a trainer calls it (run_train_steps of
+opt])` over the family's model, as a trainer calls it (run_train_steps of
 chip_smoke.py, re-arranged around a window). A fresh Zipf batch every
 step, labels = ids, the loss fetched every `sync_every`-th step.
 """
@@ -9,9 +9,9 @@ import time
 
 import numpy as np
 
-from . import loadgen, reference
-from .common import (CacheCounter, build_model, device_bytes, llama_config,
-                     loss_tolerance, say)
+from . import loadgen, lookup
+from .common import (CacheCounter, device_bytes, memory_peak_bytes, say,
+                     within)
 from .observe import Spans, delta
 
 
@@ -21,7 +21,8 @@ def setup(cfg: dict, cell: dict, seed: int):
     import jax
     import paddle_tpu as paddle
 
-    lcfg = llama_config(cfg)
+    fam = lookup.family(cfg)
+    pcfg = fam.config(cfg)
     sharding = None
     mesh = cfg.get("mesh")
     if mesh:
@@ -35,7 +36,7 @@ def setup(cfg: dict, cell: dict, seed: int):
         sharding = NamedSharding(fleet.get_hybrid_communicate_group().mesh,
                                  P("data", None))
     paddle.seed(seed % (2 ** 31 - 1))
-    model = build_model(lcfg, cfg["dtype"])
+    model = fam.build_model(pcfg, cfg["dtype"])
     o = cfg["optimizer"]
     opt = getattr(paddle.optimizer, o["name"])(
         o["lr"], parameters=model.parameters(),
@@ -52,8 +53,8 @@ def setup(cfg: dict, cell: dict, seed: int):
     step = paddle.jit.to_static(train_step, state_objects=[model, opt])
     say(f"train: parameter bytes per device "
         f"{device_bytes(p._data for p in model.parameters())}")
-    return {"lcfg": lcfg, "model": model, "opt": opt, "step": step,
-            "sharding": sharding}
+    return {"family": fam, "pcfg": pcfg, "model": model, "opt": opt,
+            "step": step, "sharding": sharding}
 
 
 def counters(cache: CacheCounter) -> dict:
@@ -107,7 +108,7 @@ def run(cfg, cell, *, seed, seconds, cache, phases, tracer=None,
         the weights the step sees. Returns the relative error."""
         ids_np = batches.next()
         w = {k: v._data for k, v in model.state_dict().items()}
-        ref = reference.loss(w, built["lcfg"], ids_np)
+        ref = built["family"].loss(w, built["pcfg"], ids_np)
         del w
         ids = put(ids_np)
         got = float(np.asarray(step(ids, ids)._data))
@@ -122,7 +123,7 @@ def run(cfg, cell, *, seed, seconds, cache, phases, tracer=None,
     if tracer is not None:
         tracer.warm()
     phases.mark("warm_traffic")
-    checks = [checked_step()]
+    steps_checked = [checked_step()]
     phases.mark("check")
 
     # ---------------------------------------------------------- the window
@@ -153,35 +154,38 @@ def run(cfg, cell, *, seed, seconds, cache, phases, tracer=None,
         tracer.stop()
         slice_steps = n - traced_from
     after = counters(cache)
+    peak = memory_peak_bytes()
     phases.skip()
     # between the first and the last sync inside the window
     steps = sync_steps[-1] - sync_steps[0]
     span_s = sync_t[-1] - sync_t[0]
     tokens_per_s = steps * tokens_a_step / span_s
-    checks.append(checked_step())
+    steps_checked.append(checked_step())
     phases.mark("check")
 
-    tol = loss_tolerance(cfg["dtype"])
+    tol = built["family"].loss_tolerance(cfg)
     in_window = delta(after, before)
     finite = [bool(np.isfinite(x)) for x in losses]
-    ok = {"losses_finite": all(finite),
-          "loss_matches_reference": all(e <= tol for _, _, e in checks),
-          "no_eager_fallback": after["to_static"]["eager_fallbacks"] == 0,
-          "no_compile_in_window":
-              in_window["to_static"]["compile_events"] == 0
-              and in_window["jax_cache"]["hits"] == 0
-              and in_window["jax_cache"]["misses"] == 0}
+    checks = {"loss_rel_err.before": (steps_checked[0][2], tol),
+              "loss_rel_err.after": (steps_checked[1][2], tol),
+              "losses_not_finite": (finite.count(False), 0),
+              "eager_fallbacks": (after["to_static"]["eager_fallbacks"], 0),
+              "compiles_in_window": (
+                  in_window["to_static"]["compile_events"]
+                  + in_window["jax_cache"]["hits"]
+                  + in_window["jax_cache"]["misses"], 0)}
     gaps = np.diff(sync_t)
     say(f"train: seconds from sync to sync ({sync_every} steps): min "
         f"{gaps.min():.4f} median {np.median(gaps):.4f} max {gaps.max():.4f}")
     say(f"train: {n} steps in the window, {steps} between its first and "
         f"last sync over {span_s:.3f}s; losses first/last "
         f"{losses[0]:.4f}/{losses[-1]:.4f}; checked steps (loss, float32 "
-        f"reference, rel err) {[(round(g, 5), round(r, 5), f'{e:.2e}') for g, r, e in checks]} "
-        f"tolerance {tol:.2e}; checks {ok}")
+        f"reference, rel err) {[(round(g, 5), round(r, 5), f'{e:.2e}') for g, r, e in steps_checked]} "
+        f"tolerance {tol:.2e}")
     values = {"tokens_per_s": tokens_per_s, "steps": steps,
               "window_s": span_s, "slice_steps": slice_steps}
     e2e = {"train_tokens_per_s_chip": (tokens_per_s / chips, "tokens/s/chip")}
-    obs = {"values": values, "spans": spans.durations,
+    obs = {"values": values, "spans": spans.durations, "checks": checks,
+           "memory_peak_bytes": peak,
            "counters": {"window": in_window, "process": after}}
-    return e2e, obs, all(ok.values()), n, finite[3:].count(False)
+    return e2e, obs, within(checks), n, finite[3:].count(False)

@@ -154,9 +154,15 @@ def main(argv):
     for name in cells:
         cell, cfg = common.load_cell(name)
         t0 = time.time()
-        fn = rehearse_train if cell["driver"] == "train_loop" \
-            else rehearse_serve
-        peak = fn(cell, cfg, topo)
+        # by the configuration's entry point, whatever kind of driver
+        # the cell names: each reaches into that entry's private builders
+        entry = {"train": rehearse_train, "serve": rehearse_serve}
+        if cfg.get("entry") not in entry:
+            raise SystemExit(f"rehearse_sizes.py: configuration "
+                             f"{cell['config']} has entry "
+                             f"{cfg.get('entry')!r}; this tool rehearses "
+                             f"{sorted(entry)}")
+        peak = entry[cfg["entry"]](cell, cfg, topo)
         print(f"[rehearse] {name}: largest program {peak / GIB:.2f} GiB of "
               f"a v5e's 15.75 GiB ({time.time() - t0:.0f}s here)", flush=True)
 

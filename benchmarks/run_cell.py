@@ -21,63 +21,43 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    args = ap.parse_args(argv)
-
-    from benchmarks.harness import common, observe
-    cell, cfg = common.load_cell(args.workload)
-    cache_dir = common.place_compile_cache()
-    device = common.require_tpu(cell["chips"])
-    import paddle_tpu  # noqa: F401 — the system under test
-    from paddle_tpu.kernels.autotune import autotune_enabled
-    if autotune_enabled():
-        print("run_cell.py: kernel autotune must be off (nothing outside "
-              "the checkout may shape a kernel)", file=sys.stderr)
-        return 1
-    phases = common.Phases(T_START)
+def measure(cell, cfg, device, *, seed, seconds, trace, t_start=T_START):
+    """Everything after the look for a chip: finds the cell's driver by
+    name (`drivers/<kind>.py` beside the cell's files), runs it, and
+    returns (the result line as a dict, or None where a traced run holds
+    no device operation; obs)."""
+    from benchmarks.harness import common, lookup, observe
+    phases = common.Phases(t_start)
     phases.mark("import")
     cache = common.CacheCounter()
-    common.say(f"cell {cell['name']} ({cell['driver']}) config "
-               f"{cell['config']} seed {args.seed} seconds {args.seconds} "
-               f"trace {args.trace}; device {device}; compile cache "
-               f"{cache_dir}")
-
     spans = observe.Spans()
     tracer = None
-    if args.trace:
+    if trace:
         tracer = observe.Tracer(os.path.join(
             common.REPO, ".bench_trace", cell["name"]), spans)
-    driver = {"train_loop": "train_loop", "closed_loop": "serve_loop",
-              "open_loop": "serve_loop"}[cell["driver"]]
-    run = __import__(f"benchmarks.harness.{driver}", fromlist=["run"]).run
-    e2e, obs, correct, attempted, failed = run(
-        cfg, cell, seed=args.seed, seconds=args.seconds, cache=cache,
-        phases=phases, tracer=tracer, spans=spans)
+    e2e, obs, correct, attempted, failed = lookup.driver(cell).run(
+        cfg, cell, seed=seed, seconds=seconds, cache=cache, phases=phases,
+        tracer=tracer, spans=spans)
     setup_s = phases.total()
     obs.update(cfg=cfg, cell=cell, device=device, chips=cell["chips"],
-               trace=None)
-    peak = common.memory_peak_bytes()
-    common.say(f"compile cache: {common.cache_state(cache_dir)}")
-    common.say(f"set-up {setup_s:.1f}s = "
-               f"{ {k: round(v, 1) for k, v in phases.parts.items()} }; "
+               trace=None, phases=dict(phases.parts))
+    peak = obs["memory_peak_bytes"]     # read at the window's close
+    common.say(f"set-up {setup_s:.1f}s of "
+               f"{ {k: round(v, 1) for k, v in phases.parts.items()} } "
+               f"(all but {list(phases.NOT_SETUP)}); "
                f"persistent compile cache hits {cache.hits} misses "
                f"{cache.misses}; peak HBM {peak / 2**30:.2f} GiB")
     dev = {"platform": device["platform"], "kind": device["kind"],
            "count": device["count"], "memory_peak_bytes": peak}
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "device": dev}
-    if args.trace:
+    if trace:
         reduced, rows = tracer.reduce()
         common.say(f"trace: lines {rows and rows['lines']}")
         if not reduced or reduced["busy_s"] <= 0:
             print("run_cell.py: the trace holds no device operation",
                   file=sys.stderr)
-            return 1
+            return None, obs
         obs["trace"] = reduced
         common.say(f"trace: slice {reduced['window_s']:.3f}s (from the host "
                    f"span: {reduced['slice_from_host_span']}), busy "
@@ -87,7 +67,7 @@ def main(argv=None) -> int:
         common.say(f"trace: device ops by time (s a chip) "
                    f"{[(k, round(v, 4)) for k, v in top]}")
         dev["busy_s"], dev["window_s"] = reduced["busy_s"], reduced["window_s"]
-        line["metrics"] = observe.read_metrics(obs)
+        line["metrics"] = observe.read_metrics(obs, cell["bench_dir"])
         line["breakdown"] = {"device_ops": reduced["device_ops"],
                              "idle_gaps": reduced["idle_gaps"]}
     else:
@@ -100,7 +80,46 @@ def main(argv=None) -> int:
         line["metrics"] = {k: {"value": float(v), "unit": u}
                            for k, (v, u) in e2e.items() if k in keep}
         line["metrics"]["setup_s"] = {"value": float(setup_s), "unit": "s"}
+    # each number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    line["checks"] = {k: {"value": float(v) if v == v else None,  # no NaN
+                          "limit": float(lim)}
+                      for k, (v, lim) in obs["checks"].items()}
+    return line, obs
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+
+    from benchmarks.harness import common
+    cell, cfg = common.load_cell(args.workload)
+    cache_dir = common.place_compile_cache()
+    device = common.require_tpu(cell["chips"])
+    import paddle_tpu  # noqa: F401 — the system under test
+    from paddle_tpu.kernels.autotune import autotune_enabled
+    if autotune_enabled():
+        print("run_cell.py: kernel autotune must be off (nothing outside "
+              "the checkout may shape a kernel)", file=sys.stderr)
+        return 1
+    common.say(f"cell {cell['name']} ({cell['driver']}) config "
+               f"{cell['config']} seed {args.seed} seconds {args.seconds} "
+               f"trace {args.trace}; device {device}; compile cache "
+               f"{cache_dir}")
+    line, _ = measure(cell, cfg, device, seed=args.seed,
+                      seconds=args.seconds, trace=args.trace)
+    common.say(f"compile cache: {common.cache_state(cache_dir)}")
+    if line is None:
+        return 1
     print(json.dumps(line), flush=True)
+    for name, c in line["checks"].items():
+        print(f"check {name} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

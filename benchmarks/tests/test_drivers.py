@@ -16,28 +16,33 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 REPO = common.REPO
 
 
-def run_cell(name, seconds=1.5, seed=3000000019):
-    """What run_cell.py does after its guard, on the toy files."""
-    cell, cfg = common.load_cell(name, DATA)
-    cache, spans = common.CacheCounter(), observe.Spans()
-    phases = common.Phases(time.time())
-    mod = "train_loop" if cell["driver"] == "train_loop" else "serve_loop"
-    run = __import__(f"benchmarks.harness.{mod}", fromlist=["run"]).run
-    e2e, obs, correct, attempted, failed = run(
-        cfg, cell, seed=seed, seconds=seconds, cache=cache, phases=phases,
-        spans=spans)
-    obs.update(cfg=cfg, cell=cell, device={"kind": "TPU v5 lite"},
-               chips=cell["chips"], trace=None)
-    return e2e, obs, correct, attempted, failed, phases
+def run_cell(name, seconds=1.5, seed=3000000019, data=DATA):
+    """What run_cell.py does after its look for a chip, on the toy files:
+    the command's own `measure`, driver found by name."""
+    from benchmarks import run_cell as command
+    cell, cfg = common.load_cell(name, data)
+    t0 = time.time()
+    line, obs = command.measure(cell, cfg, common.CPU_AS, seed=seed,
+                                seconds=seconds, trace=0, t_start=t0)
+    assert line["correct"] == all(c["value"] <= c["limit"]
+                                  for c in line["checks"].values())
+    assert list(line)[-1] == "checks" and len(line["checks"]) >= 5
+    e2e = {k: (m["value"], m["unit"]) for k, m in line["metrics"].items()}
+    return e2e, obs, line["correct"], line["attempted"], line["failed"], \
+        line["metrics"]["setup_s"]["value"]
 
 
 @pytest.mark.parametrize("name", ["toy-train", "toy-train4"])
 def test_train_loop(name):
-    e2e, obs, correct, attempted, failed, phases = run_cell(name)
+    e2e, obs, correct, attempted, failed, setup_s = run_cell(name)
     assert correct and failed == 0 and attempted > 0
     assert e2e["train_tokens_per_s_chip"][0] > 0
-    assert set(phases.parts) >= {"model_build", "program_build",
-                                 "warm_traffic", "check"}
+    # set-up is split into its parts, and the reference's seconds are none
+    parts = obs["phases"]
+    assert set(parts) >= {"import", "model_build", "program_build",
+                          "warm_traffic", "check"}
+    assert parts["check"] > 0 and setup_s == pytest.approx(
+        sum(v for k, v in parts.items() if k != "check"))
     got = observe.read_metrics(obs)          # the real metric files
     assert {"train_enqueue_ms", "train_compile_s", "train_mfu"} <= set(got)
     # nothing compiled inside the window
@@ -62,6 +67,28 @@ def test_serve_loop(name):
         # admitted in the window ~ due in the window (a late admission at
         # either edge moves one or two across it)
         assert abs(toy["toy_requests_added"]["value"] - attempted) <= 5
+
+
+def test_a_token_altered_where_it_is_produced_comes_out_not_correct(
+        monkeypatch):
+    """The rest of a run driven with the timed path broken underneath: the
+    engine alters the third token of every request as it returns it, and
+    the comparison with the plain reference says so."""
+    from paddle_tpu.serving import ServingEngine
+    real, seen = ServingEngine.step, {}
+
+    def step(self):
+        out = []
+        for rid, tok in real(self):
+            seen[rid] = seen.get(rid, 0) + 1
+            out.append((rid, (tok + 1) % 512 if seen[rid] == 3 else tok))
+        return out
+
+    monkeypatch.setattr(ServingEngine, "step", step)
+    _, obs, correct, attempted, _, _ = run_cell("toy-closed")
+    assert attempted > 0 and not correct
+    wrong = {k for k, (v, lim) in obs["checks"].items() if not v <= lim}
+    assert wrong == {"logit_gap_mean", "logit_gap_widest"}
 
 
 def test_trace_metrics_are_left_out_without_a_trace():

@@ -181,8 +181,9 @@ def test_the_fourteen_metrics_of_issue_27():
         train = m["moves"] == "train_tokens_per_s_chip"
         assert f["drivers"] == (["train_loop"] if train
                                 else ["closed_loop", "open_loop"])
-        assert m["workloads"] == ["train-pretrain-2k" if train
-                                  else "serve-offline-decode"]
+        assert m["workloads"] == (
+            ["train-pretrain-2k"] if train
+            else ["serve-offline-decode", "serve-chat-steady"])
     for name, kernel in KERNEL_SHARES.items():
         f = files[name]
         assert (f["reader"], f["unit"], f["better"], f["source"]) == (
