@@ -829,13 +829,16 @@ def functional_call(layer, params_and_buffers, *args, method=None, **kwargs):
     """Run `layer.forward` with parameters temporarily replaced by the given
     dict of arrays (jit-friendly module application). `method` names an
     alternate entry point on the layer (e.g. the serving engine drives
-    `forward_paged_decode` through the same state swap)."""
+    the model's paged entry through the same state swap); a callable
+    `method` is called as `method(layer, *args, **kwargs)` under it."""
     sd = layer.state_dict()
     saved = {k: t._data for k, t in sd.items()}
     try:
         for k, v in params_and_buffers.items():
             if k in sd:
                 sd[k]._data = v._data if isinstance(v, Tensor) else v
+        if callable(method):
+            return method(layer, *args, **kwargs)
         if method is not None:
             return getattr(layer, method)(*args, **kwargs)
         return layer(*args, **kwargs)

@@ -623,7 +623,16 @@ def _pick_block(n, target):
     return max(b, 128)
 
 
-def _pick_block_q(sq, target=1024):
+def _block_cap(d):
+    """The largest tile side for a head width `d`: 1,024 up to 128 (the
+    sweep below); 512 above it, where the 1,024 x 1,024 float32 score tile
+    does not fit in a v5e's VMEM beside the wider q/k/v tiles and a whole
+    program's other fusions (PR 31, d 192: the whole-program rehearsal
+    refuses it, a compile of the kernel alone does not)."""
+    return 1024 if d <= 128 else 512
+
+
+def _pick_block_q(sq, d=128):
     """Default (1024, 1024): the on-chip block sweeps (v5e; S∈{2048,
     8192}, D∈{64, 128}, causal; fwd and fwd+bwd; device-side timing)
     found it fastest at every shape tried — 1.5-1.9× over the original
@@ -631,28 +640,39 @@ def _pick_block_q(sq, target=1024):
     online-softmax bookkeeping and keep the MXU fed; VMEM stays under
     budget (k+v tiles at 1024×128 bf16 = 512 KB, scores 1024×1024 fp32
     = 4 MB). (2048, 2048) fails to compile (VMEM); (1024, 2048)
-    regresses fwd badly — don't chase full-axis K."""
-    return _pick_block(sq, target)
+    regresses fwd badly — don't chase full-axis K. A head width `d` over
+    128 caps both at 512 (`_block_cap`)."""
+    return _pick_block(sq, _block_cap(d))
 
 
-def _pick_block_k(sk, target=1024):
-    return _pick_block(sk, target)
+def _pick_block_k(sk, d=128):
+    return _pick_block(sk, _block_cap(d))
+
+
+def unsupported_reason(q_shape, k_shape, dtype):
+    """Why the kernel refuses these (B, S, H, D) shapes, or None where it
+    takes them: the one statement of its tiling rule. K/V stream through
+    VMEM in blocks, so sequence length is not VMEM-bound; only tiling
+    legality is checked."""
+    B, Sq, H, D = q_shape
+    Sk = k_shape[1]
+    if D > 256 or D % 8 != 0:
+        return f"head_dim {D} unsupported"
+    if Sq % 8 != 0 or Sk % 8 != 0:
+        return "seq len must be multiple of 8"
+    if Sq % 128 != 0 and Sq > 1024:
+        return "long Sq must be a multiple of 128"
+    if Sk % 128 != 0 and Sk > 1024:
+        return "long Sk must be a multiple of 128"
+    return None
 
 
 def check_supported(q_shape, k_shape, dtype):
     """Raises ValueError for shapes the kernel doesn't support (caller falls
-    back to the XLA composition). K/V stream through VMEM in blocks, so
-    sequence length is not VMEM-bound; only tiling legality is checked."""
-    B, Sq, H, D = q_shape
-    Sk = k_shape[1]
-    if D > 256 or D % 8 != 0:
-        raise ValueError(f"head_dim {D} unsupported")
-    if Sq % 8 != 0 or Sk % 8 != 0:
-        raise ValueError("seq len must be multiple of 8")
-    if Sq % 128 != 0 and Sq > 1024:
-        raise ValueError("long Sq must be a multiple of 128")
-    if Sk % 128 != 0 and Sk > 1024:
-        raise ValueError("long Sk must be a multiple of 128")
+    back to the XLA composition)."""
+    why = unsupported_reason(q_shape, k_shape, dtype)
+    if why is not None:
+        raise ValueError(why)
 
 
 def _to_bhsd(x):
@@ -671,8 +691,8 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None):
     check_supported(tuple(q.shape), tuple(k.shape), q.dtype)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    block_q = _pick_block_q(Sq)
-    block_k = _pick_block_k(Sk)
+    block_q = _pick_block_q(Sq, D)
+    block_k = _pick_block_k(Sk, D)
     qf, kf, vf = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
     from .autotune import autotune_enabled, lookup
     sig = (B * H, Sq, Sk, D, str(q.dtype), bool(causal))
@@ -738,8 +758,8 @@ def flash_attention_varlen_bshd(q, k, v, q_segment_ids, kv_segment_ids,
     check_supported(tuple(q.shape), tuple(k.shape), q.dtype)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    block_q = _pick_block_q(Sq)
-    block_k = _pick_block_k(Sk)
+    block_q = _pick_block_q(Sq, D)
+    block_k = _pick_block_k(Sk, D)
     ids_q = q_segment_ids.astype(jnp.int32).reshape(B, Sq)
     ids_k = kv_segment_ids.astype(jnp.int32).reshape(B, Sk)
     if causal:
@@ -784,8 +804,8 @@ def flashmask_attention_bshd(q, k, v, startend_row_indices, causal=True,
         raise ValueError("non-causal flashmask needs 2 or 4 bound columns")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    block_q = _pick_block_q(Sq)
-    block_k = _pick_block_k(Sk)
+    block_q = _pick_block_q(Sq, D)
+    block_k = _pick_block_k(Sk, D)
     # (B, Hm, Sk, C) -> (B*Hm, C, Sk)
     fm = jnp.swapaxes(startend_row_indices.astype(jnp.int32), 2, 3)
     fm = fm.reshape(B * Hm, C, Sk)

@@ -200,8 +200,8 @@ def ring_flash_attention(q, k, v, axis_name="sep", causal=True, sm_scale=None):
     check_supported((B, S, H, D), (B, S, H, D), q.dtype)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    block_q = _pick_block_q(S)
-    block_k = _pick_block_k(S)
+    block_q = _pick_block_q(S, D)
+    block_k = _pick_block_k(S, D)
 
     def to_flat(x):
         return jnp.swapaxes(x, 1, 2).reshape(x.shape[0] * x.shape[2],

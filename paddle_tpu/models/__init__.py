@@ -13,3 +13,17 @@ from .unet import UNetConfig, UNet2DModel, unet_tiny  # noqa: F401
 from .generation import jit_generate  # noqa: F401
 from .qwen2_moe import (Qwen2MoeConfig, Qwen2MoeForCausalLM,  # noqa: F401
                         qwen2_moe_tiny, qwen2_moe_a14b)
+
+
+# Kimi-K2 (models/kimi_k2.py) is imported when it is asked for, not with
+# the package: `import paddle_tpu` is part of every program's set-up.
+_LAZY = {"KimiK2Config": "kimi_k2", "KimiK2ForCausalLM": "kimi_k2",
+         "kimi_k2_tiny": "kimi_k2"}
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        import importlib
+        return getattr(importlib.import_module(f".{_LAZY[name]}", __name__),
+                       name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

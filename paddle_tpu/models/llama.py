@@ -760,88 +760,77 @@ class LlamaForCausalLM(nn.Layer):
         logits = _head_and_loss(h, None, self.lm_head, tied)
         return logits, caches
 
+    # ------------------------------------------- the engine's contract
+    # (models/paged.py): the cache entry, the one paged entry over a
+    # span of query positions, and no counters. The three methods above
+    # are this family's implementation of the spans; the engine names
+    # none of them.
+    paged_counters = ()
+
+    def paged_cache_spec(self, page_size, dtype, kv_dtype=None, tp=1):
+        """One layer's cache entry: K pages and V pages of
+        `(pages, KVH, page, D)` in the served type, or int8 with their
+        fp32 per-slot scale pages `(pages, KVH, page)`; head-sharded over
+        'model' under tensor parallelism (page IDS stay global). Raises
+        at construction, not at the first decode launch, where the Pallas
+        kernel's static constraints refuse the PER-SHARD geometry."""
+        from ..kernels.paged_attention import (KV_SCALE_DTYPE,
+                                               check_supported_paged,
+                                               paged_page_bytes)
+        cfg, dtype = self.cfg, jnp.dtype(dtype)
+        kvh, heads = cfg.num_key_value_heads, cfg.num_attention_heads
+        hd = cfg.hidden_size // heads
+        if tp > 1 and (kvh % tp or heads % tp):
+            which, n = (("num_key_value_heads", kvh) if kvh % tp
+                        else ("num_attention_heads", heads))
+            raise ValueError(f"{which} {n} not divisible by model-axis "
+                             f"degree {tp}")
+        check_supported_paged((1, heads // tp, hd),
+                              (1, kvh // tp, page_size, hd),
+                              dtype, kv_dtype=kv_dtype)
+        name = kv_dtype if kv_dtype is not None else str(dtype)
+        shape = (kvh, page_size, hd)
+        kv_spec = P(None, "model", None, None) if tp > 1 else None
+        cache_dtype = jnp.int8 if kv_dtype == "int8" else dtype
+        entries = [(shape, cache_dtype, kv_spec)] * 2
+        if kv_dtype == "int8":
+            sc_spec = P(None, "model", None) if tp > 1 else None
+            entries += [(shape[:2], KV_SCALE_DTYPE, sc_spec)] * 2
+        from .paged import PagedCacheSpec
+        return PagedCacheSpec(
+            tuple(entries), paged_page_bytes(kvh, page_size, hd, name),
+            paged_page_bytes(kvh // tp, page_size, hd, name))
+
+    def paged_forward(self, input_ids, paged_caches, block_tables, span):
+        """The one paged entry (models/paged.py `PagedSpan`)."""
+        if span.kind == "decode":
+            out = self.forward_paged_decode(input_ids, paged_caches,
+                                            block_tables, span.start)
+        elif span.kind == "prefill":
+            out = self.forward_paged_prefill(input_ids, paged_caches,
+                                             block_tables, span.start,
+                                             span.live)
+        elif span.kind == "verify":
+            out = self.forward_paged_verify(input_ids, paged_caches,
+                                            block_tables, span.start,
+                                            span.live)
+        else:
+            raise ValueError(f"unknown span kind {span.kind!r}")
+        return out + ((),)
+
     def forward_paged_decode_multi(self, input_ids, paged_caches,
                                    block_tables, seq_lens, step_caps,
                                    eos_ids, key, *, k_steps,
                                    temperature=0.0, top_k=0, top_p=1.0):
-        """K decode iterations in ONE trace (multi-step device-side
-        decode, ISSUE 13): a `lax.scan` over the single-token decode
-        body with IN-GRAPH sampling, so one compiled launch emits up to
-        `k_steps` tokens per row instead of paying the host round trip
-        per token.
-
-        input_ids (B,) int32 — each row's last emitted token; seq_lens
-        (B,) counts through that token (the `forward_paged_decode`
-        convention); step_caps (B,) int32 — tokens row b may emit this
-        launch (0 marks a padded batch row; the engine caps by
-        remaining max_new_tokens); eos_ids (B,) int32 per-row EOS
-        (-1 = none); key — ONE pre-drawn PRNG key, per-step keys are
-        `fold_in`(key, step) so StepSupervisor retries replay the
-        identical launch bit-for-bit.
-
-        Per-row freeze masks: a row stops emitting once it hits its
-        cap, its EOS, or a non-finite logits row (the per-launch NaN
-        quarantine signal). Frozen rows stay in the batch at frozen
-        (ids, seq_len) — each remaining step rewrites the SAME token's
-        K/V at the SAME position, the idempotent-rewrite contract the
-        span writes already rely on — and their emitted-token slots are
-        masked to the -1 sentinel. The loop carry threads the paged
-        cache state through every step; the trip count is clamped to
-        the tpu-lint A4 wedge cap (a 4096-iteration device-side loop
-        once left the chip UNAVAILABLE for minutes; `k_steps` is
-        engine-validated far below it, so the clamp is lint-provable,
-        never load-bearing).
-
-        Returns (tokens (B, K) int32 with -1 past each row's finish,
-        n_emit (B,) int32, ok (B,) bool — False iff a LIVE step of that
-        row produced non-finite logits — and the updated caches)."""
-        from .generation import _sample_arr
-        ids0 = (input_ids._data if isinstance(input_ids, Tensor)
-                else jnp.asarray(input_ids)).astype(jnp.int32)
-        bt = block_tables if isinstance(block_tables, Tensor) \
-            else Tensor(jnp.asarray(block_tables))
-        sl0 = (seq_lens._data if isinstance(seq_lens, Tensor)
-               else jnp.asarray(seq_lens)).astype(jnp.int32)
-        caps = (step_caps._data if isinstance(step_caps, Tensor)
-                else jnp.asarray(step_caps)).astype(jnp.int32)
-        eos = (eos_ids._data if isinstance(eos_ids, Tensor)
-               else jnp.asarray(eos_ids)).astype(jnp.int32)
-        key_a = key._data if isinstance(key, Tensor) else key
-        b = ids0.shape[0]
-        caches0 = [tuple(t._data for t in kv) for kv in paged_caches]
-
-        def body(carry, j):
-            ids, sl, active, n_emit, ok, caches = carry
-            caches_t = [tuple(Tensor(a) for a in kv) for kv in caches]
-            logits, new_caches = self.forward_paged_decode(
-                Tensor(ids[:, None]), caches_t, bt, Tensor(sl))
-            rows = logits._data[:, 0, :]
-            fin = jnp.all(jnp.isfinite(rows), axis=-1)
-            tok = _sample_arr(rows, jax.random.fold_in(key_a, j),
-                              temperature, top_k, top_p)
-            emit = jnp.logical_and(active, fin)
-            # non-finite on a LIVE step poisons the row (frozen rows'
-            # logits are discarded — they cannot quarantine anyone)
-            ok = jnp.logical_and(ok, jnp.logical_or(fin, ~active))
-            tok_out = jnp.where(emit, tok, jnp.int32(-1))
-            n_emit = n_emit + emit.astype(jnp.int32)
-            hit_eos = emit & (eos >= 0) & (tok == eos)
-            active = emit & ~hit_eos & (n_emit < caps)
-            ids = jnp.where(emit, tok, ids)
-            sl = sl + emit.astype(jnp.int32)
-            caches = [tuple(t._data for t in kv) for kv in new_caches]
-            return (ids, sl, active, n_emit, ok, caches), tok_out
-
-        carry0 = (ids0, sl0, caps > 0, jnp.zeros((b,), jnp.int32),
-                  jnp.ones((b,), bool), caches0)
-        # trip count clamped to the A4 wedge cap inline, so tpu-lint can
-        # prove the bound statically (the engine validates k_steps far
-        # below it — the min() is never load-bearing at runtime)
-        steps = jnp.arange(min(int(k_steps), 512), dtype=jnp.int32)
-        (_, _, _, n_emit, ok, caches), toks = jax.lax.scan(
-            body, carry0, steps)
-        new_caches = [tuple(Tensor(a) for a in kv) for kv in caches]
-        return Tensor(toks.T), Tensor(n_emit), Tensor(ok), new_caches
+        """K decode iterations in ONE trace: `models/paged.py`
+        `decode_multi`, the scan every family shares, under the name
+        `bench_ops.py` calls. Returns (tokens (B, K), n_emit (B,),
+        ok (B,), caches)."""
+        from .paged import decode_multi
+        return decode_multi(self, input_ids, paged_caches, block_tables,
+                            seq_lens, step_caps, eos_ids, key,
+                            k_steps=k_steps, temperature=temperature,
+                            top_k=top_k, top_p=top_p)[:4]
 
     # -------------------------------------------------------- generation
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,

@@ -5,11 +5,18 @@ scheduling, block-based KV management and the radix prefix cache run on
 the host (scheduler.py / kv_cache.py / radix_cache.py), while all device
 work funnels through a SMALL, FIXED set of compiled programs — one per
 shape bucket — so continuous batching never triggers unbounded
-recompilation:
+recompilation. The programs are written against the MODEL-SIDE CONTRACT
+of `models/paged.py` and name no other method of a model: what one
+layer's cache entry is (`paged_cache_spec`: Llama's K and V pages, a
+latent-attention model's one array a layer), ONE paged entry over a span
+of query positions (`paged_forward`: 1 a row to decode, 1 + K to verify,
+S of one sequence to prefill), and the counters a model adds to a launch's
+outputs (`paged_counters`). What the bullets below say of Llama's kernels
+is that family's implementation of the spans:
 
   * prefill CHUNK program, keyed by (chunk-length bucket, block-table
-    bucket): processes one span of ONE padded prompt through
-    `model.forward_paged_prefill` — rope at absolute positions,
+    bucket): processes one span of ONE padded prompt through a
+    "prefill" span — rope at absolute positions,
     `paged_cache_write_range` at the chunk's offset, attention over the
     gathered paged prefix — and samples a token from the chunk's last
     live position (used only when the chunk completes the prompt).
@@ -18,13 +25,13 @@ recompilation:
     tokens, so cache on/off cannot change program shapes (the
     determinism contract, SERVING.md);
   * decode program, keyed by (batch bucket, block-table-width bucket):
-    one batched step through `model.forward_paged_decode` — per-row rope
+    one batched step through a "decode" span — per-row rope
     positions, `paged_cache_write` of the current token, Pallas
     `paged_attention_decode` over the block tables — plus sampling;
   * VERIFY program (speculative decoding, ISSUE 5), keyed by
     ("verify", batch bucket, draft-length bucket, block-table bucket):
     when a `Proposer` is configured, the decode launch is replaced by
-    `model.forward_paged_verify` — each row scores its last emitted
+    a "verify" span — each row scores its last emitted
     token plus up to K drafted tokens in ONE launch, acceptance is
     resolved in-graph (greedy longest-prefix match, or exact one-hot
     rejection sampling for temperature > 0), and rejected drafts' KV
@@ -35,7 +42,7 @@ recompilation:
     ("multi_decode", batch bucket, steps bucket, block-table bucket):
     with `decode_steps=K` (no proposer), the decode launch runs K
     iterations of the decode body inside ONE compiled `lax.scan`
-    (`model.forward_paged_decode_multi`) — in-graph sampling on
+    (`models/paged.py` `decode_multi`) — in-graph sampling on
     per-step keys folded from one pre-drawn key, per-step paged cache
     writes through the loop carry, and per-row EOS/step-cap/finiteness
     masks that freeze completed rows — so each emitted token stops
@@ -81,6 +88,7 @@ import numpy as np
 from ..core.autograd import no_grad
 from ..core.tensor import Tensor
 from ..jit.api import functional_call
+from ..models.paged import PAGED_ENTRY, PagedSpan, decode_multi
 from ..models.generation import _filter_logits, _sample_arr
 from .. import profiler
 from ..utils import faults
@@ -294,10 +302,11 @@ class _HostSpillBridge:
 class ServingEngine:
     """Continuous-batching engine over a causal LM with paged-KV decode.
 
-    model: a LlamaForCausalLM-protocol model — `forward_paged_prefill`
-    for (chunked) prompt processing and `forward_paged_decode` for the
+    model: a model of the paged contract (`models/paged.py`:
+    `paged_cache_spec`, `paged_forward`, `paged_counters`) — a "prefill"
+    span for (chunked) prompt processing and a "decode" span for the
     batched decode step, both over the engine-owned paged caches
-    (plus `forward_paged_verify` when speculative decoding is on).
+    (plus a "verify" span when speculative decoding is on).
     enable_prefix_cache turns the radix tree on (default); off, the
     engine behaves like PR 1 plus chunked prefill.
 
@@ -439,15 +448,6 @@ class ServingEngine:
         self.mesh = mesh
         self.tp = (int(dict(mesh.shape).get("model", 1))
                    if mesh is not None else 1)
-        if self.tp > 1:
-            if cfg.num_key_value_heads % self.tp:
-                raise ValueError(
-                    f"num_key_value_heads {cfg.num_key_value_heads} not "
-                    f"divisible by model-axis degree {self.tp}")
-            if cfg.num_attention_heads % self.tp:
-                raise ValueError(
-                    f"num_attention_heads {cfg.num_attention_heads} not "
-                    f"divisible by model-axis degree {self.tp}")
         # the central capability table (serving/errors.py, ROADMAP item
         # 4): every pairwise feature conflict is ONE check against ONE
         # table — the scattered per-feature raises this replaces could
@@ -481,27 +481,23 @@ class ServingEngine:
         self.model = model
         self.cfg = cfg
         self.num_layers = cfg.num_hidden_layers
-        self.num_kv = cfg.num_key_value_heads
-        self.head_dim = cfg.hidden_size // cfg.num_attention_heads
         self.page_size = int(page_size)
-        from ..kernels.paged_attention import paged_page_bytes
         wdtype = next(t._data.dtype for t in model.state_dict().values()
                       if jnp.issubdtype(t._data.dtype, jnp.floating))
-        # bytes one page costs in THIS engine (int8 pages + scales, or
-        # the model dtype's full-width pages) — the capacity gauge and
-        # the kv_pool_bytes sizing below both hang off it. Under TP a
-        # page's contents are head-sharded, so one chip pays only the
-        # per-SHARD bytes (KVH/tp heads) — both numbers come from the
-        # same paged_page_bytes source (linear in KVH, so
-        # shard * tp == global exactly)
+        # what ONE layer's cache entry is comes from the model
+        # (models/paged.py): the arrays a page id names, and the bytes
+        # one page costs in THIS engine (int8 pages + scales, or the
+        # model dtype's full-width pages) — the capacity gauge and the
+        # kv_pool_bytes sizing below both hang off it. Under TP a page's
+        # contents are head-sharded, so one chip pays only the per-SHARD
+        # bytes. The model raises here, at construction, where its
+        # kernels' static constraints refuse the (per-shard) geometry.
         self._kv_dtype_name = (kv_dtype if kv_dtype is not None
                                else str(wdtype))
-        self.kv_page_bytes = paged_page_bytes(
-            cfg.num_key_value_heads, self.page_size, self.head_dim,
-            self._kv_dtype_name)
-        self.kv_page_bytes_shard = paged_page_bytes(
-            cfg.num_key_value_heads // self.tp, self.page_size,
-            self.head_dim, self._kv_dtype_name)
+        spec = model.paged_cache_spec(self.page_size, wdtype,
+                                      kv_dtype=kv_dtype, tp=self.tp)
+        self.kv_page_bytes = spec.page_bytes
+        self.kv_page_bytes_shard = spec.page_bytes_shard
         if kv_pool_bytes is not None:
             # size the pool from a PER-CHIP HBM byte budget: the page
             # count is what kv_dtype="int8" roughly doubles and TP
@@ -532,19 +528,8 @@ class ServingEngine:
             self._state[k] = self._place(t._data,
                                          getattr(t, "_spec", None))
 
-        # fail at construction, not at the first decode launch: the
-        # Pallas kernel's static constraints are model geometry — under
-        # TP the kernel sees the PER-SHARD geometry (H/tp query heads
-        # over KVH/tp kv heads), so that is what must be legal
-        from ..kernels.paged_attention import check_supported_paged
         dtype = next(a.dtype for a in self._state.values()
                      if jnp.issubdtype(a.dtype, jnp.floating))
-        self._cache_dtype = jnp.int8 if kv_dtype == "int8" else dtype
-        check_supported_paged(
-            (1, cfg.num_attention_heads // self.tp, self.head_dim),
-            (self.num_pages, self.num_kv // self.tp, self.page_size,
-             self.head_dim),
-            dtype, kv_dtype=kv_dtype)
 
         # longest sequence a request may ever reach (rope table and page
         # supply both bound it)
@@ -689,31 +674,28 @@ class ServingEngine:
         self._step_no = 0
         self._last_launch_s: Optional[float] = None
 
-        from jax.sharding import PartitionSpec as P
-        shape = (self.num_pages, self.num_kv, self.page_size, self.head_dim)
-        # page contents head-sharded over 'model' (page IDS stay
-        # global): one chip holds KVH/tp heads of every page
-        kv_spec = P(None, "model", None, None) if self.tp > 1 else None
-        sc_spec = P(None, "model", None) if self.tp > 1 else None
-        self._k_caches = [self._place(jnp.zeros(shape, self._cache_dtype),
-                                      kv_spec)
-                          for _ in range(self.num_layers)]
-        self._v_caches = [self._place(jnp.zeros(shape, self._cache_dtype),
-                                      kv_spec)
-                          for _ in range(self.num_layers)]
-        if self.kv_dtype == "int8":
-            from ..kernels.paged_attention import KV_SCALE_DTYPE
-            self._k_scales = [self._place(
-                jnp.zeros(shape[:3], KV_SCALE_DTYPE), sc_spec)
-                for _ in range(self.num_layers)]
-            self._v_scales = [self._place(
-                jnp.zeros(shape[:3], KV_SCALE_DTYPE), sc_spec)
-                for _ in range(self.num_layers)]
-        else:
-            # empty pytrees: the compiled programs take the scale lists
-            # unconditionally so both kv_dtypes share one program shape
-            self._k_scales = []
-            self._v_scales = []
+        # the pool: one list over the layers for each array of the
+        # model's cache entry (Llama: K pages, V pages and, for int8,
+        # their scale pages, head-sharded over 'model' under TP while page
+        # IDS stay global; a latent-attention model: ONE array a layer).
+        # The compiled programs take four cache lists unconditionally, so
+        # every entry shares one program shape: the lists an entry does
+        # not fill are empty pytrees.
+        pools = [[self._place(jnp.zeros((self.num_pages,) + tuple(page), dt),
+                              pspec)
+                  for _ in range(self.num_layers)]
+                 for page, dt, pspec in spec.entries]
+        pools += [[] for _ in range(4 - len(pools))]
+        self._k_caches, self._v_caches, self._k_scales, self._v_scales = \
+            pools
+        # the model's own counters (models/paged.py `paged_counters`): a
+        # small int32 vector each program returns beside the step's
+        # tokens, added to the metrics counters of those names where the
+        # tokens are fetched. A dense model names none and its programs
+        # return an empty pytree there.
+        self._model_counters = tuple(model.paged_counters)
+        for name in self._model_counters:
+            self.metrics.counters.setdefault(name, 0)
         # bytes-moved accounting (ServingMetrics): one token's K+V
         # across every layer, scales included — GLOBAL bytes (the sum
         # over shards); per-chip traffic is this / tp
@@ -832,7 +814,7 @@ class ServingEngine:
         (donation off) and for failures raised BEFORE dispatch (fault
         injection, connect errors) the buffers stay alive and
         retries proceed."""
-        probe = (self._k_caches[0], self._v_caches[0])
+        probe = [c[0] for c in self._cache_lists() if c]
         return not any(getattr(a, "is_deleted", lambda: False)()
                        for a in probe)
 
@@ -1153,30 +1135,42 @@ class ServingEngine:
     # --------------------------------------------- paged-cache plumbing
     @staticmethod
     def _paged_views(kcs, vcs, kss, vss):
-        """Per-layer cache tuples for the model's forward_paged_* —
-        (k, v) for full-width KV, (k, v, k_scale, v_scale) for int8
-        (the model branches on tuple arity, ISSUE 6)."""
-        if kss:
-            return [(Tensor(kcs[l]), Tensor(vcs[l]),
-                     Tensor(kss[l]), Tensor(vss[l]))
-                    for l in range(len(kcs))]
-        return [(Tensor(kcs[l]), Tensor(vcs[l]))
+        """Per-layer cache tuples for the model's paged entry, one
+        Tensor for each array of its cache entry: (k, v) for Llama's
+        full-width KV, (k, v, k_scale, v_scale) for int8 (the model
+        branches on tuple arity, ISSUE 6), (pool,) for a latent cache."""
+        lists = [c for c in (kcs, vcs, kss, vss) if c]
+        return [tuple(Tensor(c[l]) for c in lists)
                 for l in range(len(kcs))]
 
     @staticmethod
     def _split_views(caches):
-        """Inverse of _paged_views: four flat array lists (scale lists
-        empty for full-width KV) — the uniform program return shape."""
-        kcs = [c[0]._data for c in caches]
-        vcs = [c[1]._data for c in caches]
-        if caches and len(caches[0]) == 4:
-            return (kcs, vcs, [c[2]._data for c in caches],
-                    [c[3]._data for c in caches])
-        return kcs, vcs, [], []
+        """Inverse of _paged_views: four flat array lists (those the
+        entry does not fill empty) — the uniform program return shape."""
+        n = len(caches[0]) if caches else 0
+        return tuple([c[i]._data for c in caches] if i < n else []
+                     for i in range(4))
+
+    def _cache_lists(self):
+        return (self._k_caches, self._v_caches, self._k_scales,
+                self._v_scales)
 
     def _store_caches(self, kcs, vcs, kss, vss):
         self._k_caches, self._v_caches = kcs, vcs
         self._k_scales, self._v_scales = kss, vss
+
+    def _count_model(self, counts):
+        """Add a launch's model counters (fetched with its tokens) to
+        the metrics counters of their names."""
+        if self._model_counters:
+            got = {name: int(n) for name, n in
+                   zip(self._model_counters, np.asarray(counts))}
+            for name, n in got.items():
+                self.metrics.counters[name] += n
+            # in a trace, THIS launch's counts on the profiler's clock
+            # beside its device ops: a reader sums a slice's own
+            with profiler.RecordEvent("serving.model_counters", **got):
+                pass
 
     # ----------------------------------------------------- prefill chunks
     def _build_chunk(self, S: int, P: int):
@@ -1193,17 +1187,17 @@ class ServingEngine:
             st = {k: Tensor(v) for k, v in state.items()}
             paged = views(kcs, vcs, kss, vss)
             with lora_open(largs):
-                logits, caches = functional_call(
+                logits, caches, counts = functional_call(
                     model, st, Tensor(ids), paged, Tensor(bt),
-                    Tensor(cache_len), Tensor(live),
-                    method="forward_paged_prefill")
+                    PagedSpan("prefill", Tensor(cache_len), Tensor(live)),
+                    method=PAGED_ENTRY)
             last = logits._data[0, 0]   # head ran at the chunk end only
             # in-graph NaN detection (the jit counterpart of the eager
             # dispatch NaN hook): NaN/Inf anywhere in the network flows
             # into the chunk-end logits, so one reduction covers the step
             ok = jnp.all(jnp.isfinite(last))
             tok = _sample_arr(last[None], key, temperature, top_k, top_p)[0]
-            return (tok, ok) + split(caches)
+            return (tok, ok, counts) + split(caches)
 
         # tpu-lint: cache-key-ok (donation is backend-constant per process)
         return jax.jit(program, donate_argnums=self._donate)
@@ -1247,8 +1241,8 @@ class ServingEngine:
                     *largs)
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
-        tok, ok, *caches = self.supervisor.run(launch,
-                                               label="prefill_chunk")
+        tok, ok, counts, *caches = self.supervisor.run(
+            launch, label="prefill_chunk")
         with profiler.RecordEvent("serving.bookkeeping"):
             self._tr_launch((req.request_id,), "prefill_chunk", t_tr,
                             start=chunk.start, length=chunk.length,
@@ -1268,6 +1262,7 @@ class ServingEngine:
             ok = bool(ok)              # host fetch = the honest sync
             if ok and chunk.is_last:
                 tok = int(tok)
+            self._count_model(counts)
         return tok, ok
 
     # ----------------------------------------------------------- decode
@@ -1283,16 +1278,16 @@ class ServingEngine:
             st = {k: Tensor(v) for k, v in state.items()}
             paged = views(kcs, vcs, kss, vss)
             with lora_open(largs):
-                logits, caches = functional_call(
-                    model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
-                    method="forward_paged_decode")
+                logits, caches, counts = functional_call(
+                    model, st, Tensor(ids), paged, Tensor(bt),
+                    PagedSpan("decode", Tensor(sl)), method=PAGED_ENTRY)
             rows = logits._data[:, 0, :]
             # per-row finiteness: rows are independent (SERVING.md), so a
             # poisoned request flags ONLY its own row — the quarantine
             # granularity ("fail one request, not the engine")
             ok = jnp.all(jnp.isfinite(rows), axis=-1)
             toks = _sample_arr(rows, key, temperature, top_k, top_p)
-            return (toks, ok) + split(caches)
+            return (toks, ok, counts) + split(caches)
 
         # tpu-lint: cache-key-ok (donation is backend-constant per process)
         return jax.jit(program, donate_argnums=self._donate)
@@ -1336,10 +1331,11 @@ class ServingEngine:
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
-        toks, oks, *caches = self.supervisor.run(launch,
-                                                 label="decode_step")
+        toks, oks, counts, *caches = self.supervisor.run(
+            launch, label="decode_step")
         with profiler.RecordEvent("serving.fetch"):
             toks = np.asarray(toks)    # host fetch = the honest sync
+            self._count_model(counts)
             # the TPOT sample and the request's launch span end here,
             # with the tokens on the host
             self._last_launch_s = _perf_counter() - t0
@@ -1403,12 +1399,13 @@ class ServingEngine:
             # stacks become loop constants, so the paged gather runs
             # once per LAUNCH, not once per decode step
             with lora_open(largs):
-                toks, n_emit, ok, caches = functional_call(
+                toks, n_emit, ok, caches, counts = functional_call(
                     model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
                     Tensor(caps), Tensor(eos), key,
-                    method="forward_paged_decode_multi", k_steps=K,
+                    method=decode_multi, k_steps=K,
                     temperature=temperature, top_k=top_k, top_p=top_p)
-            return (toks._data, n_emit._data, ok._data) + split(caches)
+            return (toks._data, n_emit._data, ok._data, counts) \
+                + split(caches)
 
         # tpu-lint: cache-key-ok (donation is backend-constant per process)
         return jax.jit(program, donate_argnums=self._donate)
@@ -1435,7 +1432,7 @@ class ServingEngine:
             for i, (r, c) in enumerate(zip(reqs, caps)):
                 ids[i] = r.output_ids[-1]
                 # seq_lens counts through the FIRST input token (the
-                # forward_paged convention); the extension slots grew
+                # decode span's convention); the extension slots grew
                 # num_tokens past it, so subtract them back out
                 sl[i] = r.seq.num_tokens - (c - 1)
                 cp[i] = c
@@ -1467,12 +1464,13 @@ class ServingEngine:
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
-        toks, n_emit, oks, *caches = self.supervisor.run(
+        toks, n_emit, oks, counts, *caches = self.supervisor.run(
             launch, label="multi_decode_step")
         # the host fetch is the sync: convert
         # BEFORE stamping the launch time so TPOT covers device work
         with profiler.RecordEvent("serving.fetch"):
             toks = np.asarray(toks)
+            self._count_model(counts)
             n_emit = np.asarray(n_emit).astype(int)
             oks = np.asarray(oks)[:len(reqs)].copy()
         dt = _perf_counter() - t0
@@ -1617,9 +1615,10 @@ class ServingEngine:
         def program(state, kcs, vcs, kss, vss, ids, bt, sl, dl, key):
             st = {k: Tensor(v) for k, v in state.items()}
             paged = views(kcs, vcs, kss, vss)
-            logits, caches = functional_call(
-                model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
-                Tensor(dl), method="forward_paged_verify")
+            logits, caches, counts = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt),
+                PagedSpan("verify", Tensor(sl), Tensor(dl)),
+                method=PAGED_ENTRY)
             lg = logits._data                            # (B, S, V)
             jpos = jnp.arange(S, dtype=jnp.int32)[None, :]
             live_q = jpos <= dl[:, None]                 # (B, S)
@@ -1664,7 +1663,7 @@ class ServingEngine:
                 sampled = jax.random.categorical(
                     k_r, jnp.log(res + 1e-30), axis=-1).astype(jnp.int32)
                 toks = jnp.where(jpos < n_acc[:, None], idsn, sampled)
-            return (toks, n_acc, ok) + split(caches)
+            return (toks, n_acc, ok, counts) + split(caches)
 
         # tpu-lint: cache-key-ok (donation is backend-constant per process)
         return jax.jit(program, donate_argnums=self._donate)
@@ -1733,7 +1732,7 @@ class ServingEngine:
                 ids[i, 1:1 + len(d)] = d
                 dl[i] = len(d)
                 # seq_lens counts through the FIRST input token (the
-                # forward_paged convention); the drafts extended
+                # decode span's convention); the drafts extended
                 # num_tokens past it, so subtract them back out
                 sl[i] = r.seq.num_tokens - len(d)
             key = self._next_key()  # drawn once: retries re-run identically
@@ -1757,7 +1756,7 @@ class ServingEngine:
                     jnp.asarray(dl), key)
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
-        toks, n_acc, oks, *caches = self.supervisor.run(
+        toks, n_acc, oks, counts, *caches = self.supervisor.run(
             launch, label="verify_step")
         with profiler.RecordEvent("serving.bookkeeping"):
             if self.tracer is not None:
@@ -1778,6 +1777,7 @@ class ServingEngine:
             oks = np.asarray(oks)[:len(reqs)].copy()
             toks = np.asarray(toks)
             n_acc = np.asarray(n_acc).astype(int)
+            self._count_model(counts)
         poison = faults.fire(FAULT_NAN)
         if poison is not None:
             for i in self._poison_rows(poison, reqs):
@@ -1890,18 +1890,19 @@ class ServingEngine:
         and copy WITH it — a fork that only copied values would
         dequantize the new page with the old (soon divergent) scales."""
         for src, dst in copies:
-            for l in range(self.num_layers):
-                self._k_caches[l] = self._k_caches[l].at[dst].set(
-                    self._k_caches[l][src])
-                self._v_caches[l] = self._v_caches[l].at[dst].set(
-                    self._v_caches[l][src])
-            for l in range(len(self._k_scales)):
-                self._k_scales[l] = self._k_scales[l].at[dst].set(
-                    self._k_scales[l][src])
-                self._v_scales[l] = self._v_scales[l].at[dst].set(
-                    self._v_scales[l][src])
+            for pool in self._cache_lists():
+                for l in range(len(pool)):
+                    pool[l] = pool[l].at[dst].set(pool[l][src])
 
     # ------------------------------------- tiered KV page I/O (ISSUE 17)
+    def _payload_order(self):
+        """(pool, layer) of each array of a page's payload, in the
+        codec's order: the value arrays layer by layer (k row, v row),
+        then the int8 scale rows the same way."""
+        k, v, ks, vs = self._cache_lists()
+        return [(pool, l) for a, b in ((k, v), (ks, vs))
+                for l in range(len(a)) for pool in (a, b) if pool]
+
     def _gather_page_payload(self, pid: int) -> bytes:
         """One device page's bytes as an encoded payload: k row, v row
         per layer, then the int8 scale rows when the cache is
@@ -1909,14 +1910,8 @@ class ServingEngine:
         synchronizes). The byte round trip is
         exact — np.asarray and .at[].set move raw rows, so a promoted
         page is bit-identical to the page that was demoted."""
-        arrays = []
-        for l in range(self.num_layers):
-            arrays.append(np.asarray(self._k_caches[l][pid]))
-            arrays.append(np.asarray(self._v_caches[l][pid]))
-        for l in range(len(self._k_scales)):
-            arrays.append(np.asarray(self._k_scales[l][pid]))
-            arrays.append(np.asarray(self._v_scales[l][pid]))
-        return encode_page_payload(arrays)
+        return encode_page_payload(
+            [np.asarray(pool[l][pid]) for pool, l in self._payload_order()])
 
     def _scatter_page_payload(self, pid: int, arrays) -> None:
         """Inverse of `_gather_page_payload` onto device page `pid`:
@@ -1926,22 +1921,13 @@ class ServingEngine:
         reads the page. Raises HostPageCorrupt on an array-count
         mismatch (a decoded payload from a different engine geometry
         must never partially land)."""
-        expect = 2 * (self.num_layers + len(self._k_scales))
-        if len(arrays) != expect:
+        order = self._payload_order()
+        if len(arrays) != len(order):
             raise HostPageCorrupt(
                 f"page payload has {len(arrays)} arrays; this engine "
-                f"needs {expect}")
-        it = iter(arrays)
-        for l in range(self.num_layers):
-            self._k_caches[l] = self._k_caches[l].at[pid].set(
-                jnp.asarray(next(it)))
-            self._v_caches[l] = self._v_caches[l].at[pid].set(
-                jnp.asarray(next(it)))
-        for l in range(len(self._k_scales)):
-            self._k_scales[l] = self._k_scales[l].at[pid].set(
-                jnp.asarray(next(it)))
-            self._v_scales[l] = self._v_scales[l].at[pid].set(
-                jnp.asarray(next(it)))
+                f"needs {len(order)}")
+        for (pool, l), a in zip(order, arrays):
+            pool[l] = pool[l].at[pid].set(jnp.asarray(a))
 
     def _spill_gauges(self) -> dict:
         """update_gauges kwargs for the radix eviction rungs and the

@@ -312,7 +312,42 @@ def _quant(one_chip):
                           _sds((N,), BF16, one_chip))
 
 
-KERNEL_NAMES = [("flash_attention_fwd", _flash_fwd),
+def _mla_decode(one_chip):
+    """The latent decode kernel at the serving cell's call: B 64, 64 heads
+    over one 640-lane entry (512 + 64 in whole lane tiles), a 320-page
+    table at page 16."""
+    from paddle_tpu.kernels.mla_attention import mla_paged_decode
+    B, H, W, pages, page, table = 64, 64, 640, 16384, 16, 320
+    return (lambda q, c, bt, sl: mla_paged_decode(q, c, bt, sl, rank=512,
+                                                  sm_scale=0.1),
+            (_sds((B, H, W), BF16, one_chip),
+             _sds((pages, page, W), BF16, one_chip),
+             _sds((B, table), jnp.int32, one_chip),
+             _sds((B,), jnp.int32, one_chip)))
+
+
+def _held_experts(tokens):
+    """12 held experts at the published widths over `tokens` x 8 pairs: a
+    decode step's 64 tokens (16-row tiles) and a chunk's 2,048 (128)."""
+    def case(one_chip):
+        from paddle_tpu.models.kimi_k2 import held_experts
+        H, I, E, k = 7168, 2048, 12, 8
+        return (lambda x, live, idx, w, eg, eu, ed: held_experts(
+            x, live, idx, w, eg, eu, ed, offset=0),
+            (_sds((tokens, H), BF16, one_chip),
+             _sds((tokens,), jnp.bool_, one_chip),
+             _sds((tokens, k), jnp.int32, one_chip),
+             _sds((tokens, k), jnp.float32, one_chip),
+             _sds((E, H, I), BF16, one_chip), _sds((E, H, I), BF16, one_chip),
+             _sds((E, I, H), BF16, one_chip)))
+    return case
+
+
+KERNEL_NAMES = [("mla_paged_decode", _mla_decode),
+                ("moe_grouped_matmul_gate_up", _held_experts(64)),
+                ("moe_grouped_matmul_down", _held_experts(64)),
+                ("moe_grouped_matmul_gate_up", _held_experts(2048)),
+                ("flash_attention_fwd", _flash_fwd),
                 ("flash_attention_bwd_dq", _flash_bwd),
                 ("flash_attention_bwd_dkv", _flash_bwd),
                 ("paged_attention_decode", _paged16),
@@ -327,7 +362,8 @@ def _custom_call_names(text):
 
 
 @pytest.mark.parametrize("name,case", KERNEL_NAMES,
-                         ids=[n for n, _ in KERNEL_NAMES])
+                         ids=[f"{i}-{n}" for i, (n, _) in
+                              enumerate(KERNEL_NAMES)])
 def test_kernel_name_in_compiled_text(for_chip, one_chip, name, case):
     """The `name=` of each `pallas_call` is the compiled instruction's
     name (through jit, jvp and transpose as a substring): what a device
