@@ -22,6 +22,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 from ..core.tensor import Tensor
@@ -113,7 +114,12 @@ def apply_rotary(x, cos, sin):
     than stride-2 lane slices (`x[..., 0::2]`): on TPU the minor dim is
     the 128-lane axis, and strided lane gathers ran at 320 GB/s vs
     788 GB/s (near HBM roofline) for the reshape form — measured on a
-    v5e at (4, 2048, 12, 128); the math is bit-identical."""
+    v5e at (4, 2048, 12, 128), the TRAIN path's shapes: thousands of
+    activation rows; the math is bit-identical. On the serving engine's
+    paged launches (64-512 rows out of a 4096-wide projection) the
+    compiler moved this pair view onto the projection's WEIGHT, three
+    passes over q_proj and k_proj a launch (PR 32), so the paged spans
+    call `apply_rotary_paged`; this one stays the train path's."""
     xr = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
     x1 = xr[..., 0]
     x2 = xr[..., 1]
@@ -123,6 +129,25 @@ def apply_rotary(x, cos, sin):
     o2 = x2 * c + x1 * s
     out = jnp.stack([o1, o2], axis=-1)
     return out.reshape(x.shape)
+
+
+def apply_rotary_paged(x, cos, sin):
+    """The paged spans' rotation: x (B, S, H, D), cos/sin broadcastable
+    to (B, S, 1, D/2) (decode: each row's position; a chunk: each
+    token's; verify: each row's span). The same interleaved (2i, 2i+1)
+    rotation as `apply_rotary`, the same products and sums in the same
+    dtype, so bit-identical to it: out[2i] = x[2i] c_i + x[2i+1] (-s_i),
+    out[2i+1] = x[2i+1] c_i + x[2i] s_i. A lane's partner is reached by
+    ROLLING the minor axis, never by viewing D as (D/2, 2): on a paged
+    launch x is 64-512 rows out of a 4096-wide projection, and the
+    compiler moved that view onto the projection's WEIGHT (three passes
+    over q_proj and k_proj every launch, PR 32)."""
+    even = np.arange(x.shape[-1]) % 2 == 0     # a constant of the program
+    c = jnp.repeat(cos, 2, axis=-1)
+    s = jnp.repeat(sin, 2, axis=-1)
+    partner = jnp.where(even, jnp.roll(x, -1, axis=-1),
+                        jnp.roll(x, 1, axis=-1))
+    return x * c + partner * jnp.where(even, -s, s)
 
 
 def _lora(name, x, y):
@@ -240,6 +265,30 @@ class LlamaAttention(nn.Layer):
                           lambda a: _constraint(a, spec), vd)
         return kd, vd
 
+    def _paged_qk(self, q2, k2, cos, sin, expand):
+        """The q and k projections' 2-D outputs (B, S, heads * D) of one
+        paged span -> roped (B, S, heads, D). `expand` are the axes the
+        gathered rope rows lack of (B, S, 1, D/2): (1, 2) for decode's
+        (B, D/2), (0, 2) for a chunk's (S, D/2), (2,) for verify's
+        (B, S, D/2).
+
+        The barrier keeps the heads view OFF the dot: without it the
+        compiler gives the dot a heads-major output and transposes the
+        WEIGHT for it every launch (33.6 + 8.4 MB a layer against 0.5 MB
+        of activations at 64 rows); with it any relayout falls on the
+        activation (PR 32)."""
+        b, s = q2.shape[:2]
+
+        def rope(x, c, sn):
+            return apply_rotary_paged(x, jnp.expand_dims(c, expand),
+                                      jnp.expand_dims(sn, expand))
+
+        def heads(y, n):
+            y = apply_op("proj_out", jax.lax.optimization_barrier, y)
+            y = M.reshape(y, [b, s, n, self.head_dim])
+            return apply_op("rope_paged", rope, y, cos, sin)
+        return heads(q2, self.n_heads), heads(k2, self.n_kv)
+
     def forward_paged(self, x, cos_b, sin_b, kv, block_tables, seq_lens):
         """One decode step over the PAGED KV cache (serving engine path).
 
@@ -256,14 +305,11 @@ class LlamaAttention(nn.Layer):
         from ..kernels.paged_attention import (paged_attention_decode,
                                                paged_cache_write)
         b, s, _ = x.shape
-        q = M.reshape(_lora("q_proj", x, self.q_proj(x)),
-                      [b, s, self.n_heads, self.head_dim])
-        k = M.reshape(_lora("k_proj", x, self.k_proj(x)),
-                      [b, s, self.n_kv, self.head_dim])
+        q, k = self._paged_qk(_lora("q_proj", x, self.q_proj(x)),
+                              _lora("k_proj", x, self.k_proj(x)),
+                              cos_b, sin_b, (1, 2))
         v = M.reshape(_lora("v_proj", x, self.v_proj(x)),
                       [b, s, self.n_kv, self.head_dim])
-        q = apply_op("rope_pos", apply_rotary_positions, q, cos_b, sin_b)
-        k = apply_op("rope_pos", apply_rotary_positions, k, cos_b, sin_b)
 
         def _write(*arrs):
             kc, vc, ks, vs, (kn, vn, bt, sl) = _split_kv_args(arrs, 4)
@@ -318,14 +364,11 @@ class LlamaAttention(nn.Layer):
         """
         from ..kernels.paged_attention import paged_cache_write_range
         b, s, _ = x.shape
-        q = M.reshape(_lora("q_proj", x, self.q_proj(x)),
-                      [b, s, self.n_heads, self.head_dim])
-        k = M.reshape(_lora("k_proj", x, self.k_proj(x)),
-                      [b, s, self.n_kv, self.head_dim])
+        q, k = self._paged_qk(_lora("q_proj", x, self.q_proj(x)),
+                              _lora("k_proj", x, self.k_proj(x)),
+                              cos_c, sin_c, (0, 2))
         v = M.reshape(_lora("v_proj", x, self.v_proj(x)),
                       [b, s, self.n_kv, self.head_dim])
-        q = apply_op("rope", apply_rotary, q, cos_c, sin_c)
-        k = apply_op("rope", apply_rotary, k, cos_c, sin_c)
 
         def _write(*arrs):
             kc, vc, ks, vs, (kn, vn, bt, ln, st) = _split_kv_args(arrs, 5)
@@ -379,11 +422,9 @@ class LlamaAttention(nn.Layer):
         """
         from ..kernels.paged_attention import paged_cache_write_span
         b, s, _ = x.shape
-        q = M.reshape(self.q_proj(x), [b, s, self.n_heads, self.head_dim])
-        k = M.reshape(self.k_proj(x), [b, s, self.n_kv, self.head_dim])
+        q, k = self._paged_qk(self.q_proj(x), self.k_proj(x), cos_bs, sin_bs,
+                              (2,))
         v = M.reshape(self.v_proj(x), [b, s, self.n_kv, self.head_dim])
-        q = apply_op("rope_span", apply_rotary_spans, q, cos_bs, sin_bs)
-        k = apply_op("rope_span", apply_rotary_spans, k, cos_bs, sin_bs)
 
         def _write(*arrs):
             kc, vc, ks, vs, (kn, vn, bt, sl, dl) = _split_kv_args(arrs, 5)
@@ -418,38 +459,6 @@ class LlamaAttention(nn.Layer):
         out = F.scaled_dot_product_attention(q, kd, vd, attn_mask=mask)
         out = M.reshape(out, [b, s, self.n_heads * self.head_dim])
         return self.o_proj(out), kv
-
-
-def apply_rotary_spans(x, cos_bs, sin_bs):
-    """Rotary at PER-ROW PER-OFFSET positions: x (B, S, H, D),
-    cos_bs/sin_bs (B, S, D/2) gathered at each row's own span of
-    absolute positions (the speculative-decode verify step scores
-    1 + K tokens per sequence, each sequence at a different offset).
-    Same pair-view convention as `apply_rotary`."""
-    xr = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    x1 = xr[..., 0]
-    x2 = xr[..., 1]
-    c = cos_bs[:, :, None, :]
-    s = sin_bs[:, :, None, :]
-    o1 = x1 * c - x2 * s
-    o2 = x2 * c + x1 * s
-    out = jnp.stack([o1, o2], axis=-1)
-    return out.reshape(x.shape)
-
-
-def apply_rotary_positions(x, cos_b, sin_b):
-    """Rotary at PER-ROW positions: x (B, 1, H, D), cos_b/sin_b (B, D/2)
-    gathered at each row's own position (serving decode batches sequences
-    of different lengths). Same pair-view convention as `apply_rotary`."""
-    xr = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    x1 = xr[..., 0]
-    x2 = xr[..., 1]
-    c = cos_b[:, None, None, :]
-    s = sin_b[:, None, None, :]
-    o1 = x1 * c - x2 * s
-    o2 = x2 * c + x1 * s
-    out = jnp.stack([o1, o2], axis=-1)
-    return out.reshape(x.shape)
 
 
 class LlamaMLP(nn.Layer):

@@ -376,3 +376,132 @@ def test_kernel_name_in_compiled_text(for_chip, one_chip, name, case):
     calls = _custom_call_names(_compiled_text(program, *args))
     assert any(name in c for c in calls), calls
     assert not any(c.startswith("program") for c in calls), calls
+
+
+# The engine's paged programs at Mistral-7B-v0.3's widths (the serving
+# cells' configuration, ONE layer): no projection weight is re-laid-out
+# inside a launch (PR 32). At the parent each q_proj and k_proj went
+# through a transposing copy, a physical reshape to RoPE's pair view and
+# a third copy before the dot read it: 12 weight-sized instructions a
+# program at two layers, 4.0 GB of traffic a launch at sixteen.
+WEIGHT_ELEMENTS = 4096 * 1024            # k_proj, the smallest projection
+RELAYOUTS = ("copy", "reshape", "transpose")
+# kinds that hand an array on as it is stored: views, and the compiler's
+# asynchronous prefetches into fast memory
+PASS_ON = ("bitcast", "get-tuple-element", "copy-start", "copy-done",
+           "slice-start", "slice-done")
+
+
+@pytest.fixture(scope="module")
+def mistral_engine():
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import ServingEngine
+    prev = paddle.get_default_dtype()
+    paddle.set_default_dtype("bfloat16")
+    try:
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+            num_hidden_layers=1, num_attention_heads=32,
+            num_key_value_heads=8, max_position_embeddings=32768,
+            rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16"))
+    finally:
+        paddle.set_default_dtype(prev)
+    eng = ServingEngine(model, num_pages=256, page_size=16,
+                        max_batch_size=64, temperature=0.0)
+    eng._donate = (1, 2, 3, 4)           # as on the chip: caches donated
+    yield eng
+    eng.shutdown()
+
+
+def _hlo_computations(text):
+    """{computation: [(name, elements, kind, rest of the line)]} of a
+    compiled module's text; `elements` is None for a tuple result."""
+    import re
+    head = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+    instr = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][\w\-]*)\((.*)$")
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = head.match(line)
+        if m:
+            cur = comps.setdefault("ENTRY" if line.startswith("ENTRY")
+                                   else m.group(1), [])
+            continue
+        m = instr.match(line) if cur is not None else None
+        if m:
+            name, typ, kind, rest = m.groups()
+            dims = re.match(r"\w+\[([\d,]*)\]", typ)
+            n = None if dims is None else int(np.prod(
+                [int(d) for d in dims.group(1).split(",") if d] or [1]))
+            cur.append((name, n, kind, rest))
+    return comps
+
+
+def _weight_relayouts(text):
+    return [f"{kind} {name}" for body in _hlo_computations(text).values()
+            for name, n, kind, _ in body
+            if kind in RELAYOUTS and n is not None and n >= WEIGHT_ELEMENTS]
+
+
+def _stray_weight_readers(text):
+    """Readers of a `*_proj_weight` parameter, followed through PASS_ON,
+    that are not a fusion holding the matmul."""
+    import re
+    comps = _hlo_computations(text)
+    entry = comps["ENTRY"]
+    reads = lambda rest, name: re.search(
+        r"[(, ]%" + re.escape(name) + r"[,)]", "(" + rest) is not None
+    stray, seen = [], 0
+    todo = [name for name, _, kind, _ in entry
+            if kind == "parameter" and "_proj_weight" in name]
+    assert todo, "no projection weight among the program's parameters"
+    while todo:
+        src = todo.pop()
+        for name, _, kind, rest in entry:
+            if kind == "parameter" or not reads(rest, src):
+                continue
+            seen += 1
+            if kind in PASS_ON:
+                todo.append(name)
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            if kind != "fusion" or not any(
+                    k in ("convolution", "dot")
+                    for _, _, k, _ in comps[called.group(1)]):
+                stray.append(f"{kind} {name} <- {src}")
+    assert seen, "no reader of a projection weight was found"
+    return stray
+
+
+def _paged_program(eng, kind):
+    i32 = jnp.int32
+    if kind == "decode":                 # serve-offline-decode's bucket
+        B, P = 64, 64
+        return eng._build_decode(B, P), [((B, 1), i32), ((B, P), i32),
+                                         ((B,), i32)]
+    if kind == "chunk":
+        S, P = 512, 64
+        return eng._build_chunk(S, P), [((1, S), i32), ((), i32), ((), i32),
+                                        ((P,), i32)]
+    B, K, P = 1, 1, 16                   # verify at its smallest bucket
+    return eng._build_verify(B, K, P), [((B, K + 1), i32), ((B, P), i32),
+                                        ((B,), i32), ((B,), i32)]
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "verify"])
+def test_paged_programs_leave_the_projection_weights_where_they_are(
+        for_chip, one_chip, mistral_engine, kind):
+    import paddle_tpu as paddle
+    eng = mistral_engine
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    program, shapes = _paged_program(eng, kind)
+    with paddle.no_grad():               # as the engine launches it
+        text = program.lower(
+            placed(eng._state), *placed(tuple(eng._cache_lists())),
+            *[_sds(s, dt, one_chip) for s, dt in shapes],
+            placed(eng._null_key)).compile().as_text()
+    if kind == "decode":
+        assert "paged_attention_decode" in text
+    assert _weight_relayouts(text) == []
+    assert _stray_weight_readers(text) == []
