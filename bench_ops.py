@@ -422,8 +422,8 @@ def bench_paged_decode_tp(dev, quick):
 
 def bench_multi_decode(dev, quick):
     """Multi-step device-side decode (ISSUE 13): K decode iterations of
-    a small Llama inside ONE compiled launch (`forward_paged_decode_multi`
-    — in-graph sampling, per-step paged cache writes through the scan
+    a small Llama inside ONE compiled launch (`models/paged.py`
+    `decode_multi` — in-graph sampling, per-step paged cache writes through the scan
     carry) vs K single-step launches. Rows per K in {1, 4, 8, 16}:
     wall ms, BYTES-TRUE KV GB/s (each step reads the then-current
     prefix and writes one token — paged_page_bytes is the accounting
@@ -441,6 +441,7 @@ def bench_multi_decode(dev, quick):
     from paddle_tpu.kernels.paged_attention import (alloc_paged_cache,
                                                     paged_page_bytes)
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.paged import decode_multi
 
     if dev == "cpu":
         B, S, page = 2, 48, 8
@@ -503,10 +504,10 @@ def bench_multi_decode(dev, quick):
                         for a in flat[i * arity:(i + 1) * arity])
                   for i in range(cfg.num_hidden_layers)]
             with no_grad():
-                toks, n_emit, ok, _ = functional_call(
+                toks, n_emit, ok, _, _ = functional_call(
                     model, st, Tensor(ids_a), pc, Tensor(bt),
                     Tensor(sl_a), Tensor(caps), Tensor(eos), key_a,
-                    method="forward_paged_decode_multi", k_steps=K)
+                    method=decode_multi, k_steps=K)
             return toks._data, n_emit._data, ok._data
 
         return jax.jit(prog)

@@ -462,7 +462,7 @@ def serve_phase(cfg, *, prompt_lens, shared_prefix, max_new_tokens,
     say(f"serve: {KERNEL_MARKER} per decode program {decode_calls}; chunk "
         f"program {chunk_key[:3]} has {chunk_calls} — prefill chunks attend "
         f"through the masked XLA composition over gathered pages "
-        f"(models/llama.py forward_paged_prefill), a lead for later")
+        f"(models/llama.py LlamaAttention.paged, a prefill span), a lead for later")
 
     # dense-cache reference for the first request
     ref_ids = model.generate(

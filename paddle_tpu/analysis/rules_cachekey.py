@@ -11,9 +11,11 @@ share a program whose closed-over constants differ.
 The rule runs per class: every `self._get_program(key, builder)` /
 `self.programs.get(key, builder)` call is paired with its builder
 FunctionDef (direct `self._build_x` reference or
-`lambda: self._build_x(...)`), and every `self.<attr>` READ inside the
-builder must ride the key. "Rides the key" is transitive through
-plain `self.X = <expr>` assignments anywhere in the class — the
+`lambda: self._build_x(...)`; where the call sits in a method that takes
+the builder as a PARAMETER — the engine's one launch path — every
+builder the class hands that method), and every `self.<attr>` READ
+inside the builder must ride the key. "Rides the key" is transitive
+through plain `self.X = <expr>` assignments anywhere in the class — the
 engine's `self._qkey` aggregate keys `kv_dtype`/`wq`/`tp`/`lora`
 without naming them at the call site. Methods/properties defined in
 the class body are exempt (they are code, not config), and
@@ -90,6 +92,27 @@ def _resolve_builder(expr, class_defs):
     return None
 
 
+def _builders_of(expr, fn, cls, class_defs):
+    """The builder FunctionDefs a cache-get call inside method `fn` can
+    invoke: the one `expr` names, or, where `expr` is a parameter of
+    `fn`, what every `self.<fn>(...)` call in the class passes for it."""
+    params = [a.arg for a in fn.args.args]
+    if not (isinstance(expr, ast.Name) and expr.id in params):
+        found = _resolve_builder(expr, class_defs)
+        return [] if found is None else [found]
+    pos = params.index(expr.id) - 1                          # less self
+    out = []
+    for n in ast.walk(cls):
+        if isinstance(n, ast.Call) \
+                and astutil.dotted_name(n.func) == f"self.{fn.name}":
+            passed = n.args[pos] if pos < len(n.args) else next(
+                (k.value for k in n.keywords if k.arg == expr.id), None)
+            found = _resolve_builder(passed, class_defs)
+            if found is not None:
+                out.append(found)
+    return out
+
+
 def _cache_get_calls(cls):
     """(call, key_expr, builder_expr) for every program-cache get in
     the class: `self._get_program(key, builder)` or
@@ -119,10 +142,15 @@ def check_cache_key(ctx):
                                         ast.AsyncFunctionDef))}
         deps = None
         flagged = set()
-        for call, key_expr, builder_expr in _cache_get_calls(cls):
-            builder = _resolve_builder(builder_expr, class_defs)
-            if builder is None:
-                continue    # forwarding shims (_get_program itself)
+        pairs = [(key_expr, builder)
+                 for fn in class_defs.values()
+                 for _, key_expr, builder_expr in _cache_get_calls(fn)
+                 # a key made elsewhere marks a forwarding shim
+                 # (_get_program itself): checked where the key is made
+                 if not isinstance(key_expr, ast.Name)
+                 for builder in _builders_of(builder_expr, fn, cls,
+                                             class_defs)]
+        for key_expr, builder in pairs:
             if deps is None:
                 deps = _attr_dependencies(cls)
             keyed = _expand_keyed(_self_attrs(key_expr), deps)

@@ -289,176 +289,125 @@ class LlamaAttention(nn.Layer):
             return apply_op("rope_paged", rope, y, cos, sin)
         return heads(q2, self.n_heads), heads(k2, self.n_kv)
 
-    def forward_paged(self, x, cos_b, sin_b, kv, block_tables, seq_lens):
-        """One decode step over the PAGED KV cache (serving engine path).
+    def paged(self, x, cos, sin, kv, block_tables, span):
+        """One span of query positions over the PAGED KV cache (the
+        serving engine's path). `models/paged.py` `PagedSpan` says, for
+        each kind, which positions the tokens sit at, which of them are
+        live and what the block table's shape is.
 
-        x (B, 1, hidden); cos_b/sin_b (B, D/2) at each row's position;
-        kv = (k_cache, v_cache) with caches (num_pages, KVH, page, D) —
-        or the QUANTIZED 4-tuple (k, v, k_scale, v_scale) with int8
-        value pages and (num_pages, KVH, page) fp32 per-slot scales
-        (ISSUE 6); block_tables (B, max_pages); seq_lens (B,) INCLUDING
-        the token being decoded. Writes the current token's K/V at
-        position seq_lens-1 (quantize-on-write for int8), then attends
-        through kernels.paged_attention_decode (dequantize-in-kernel).
-        Returns (out, kv) with the updated cache tuple.
+        x (B, S, hidden); cos/sin the rope rows already gathered at the
+        span's absolute positions — (B, D/2) decode, (S, D/2) prefill,
+        (B, S, D/2) verify; kv = (k_cache, v_cache) with caches
+        (num_pages, KVH, page, D), or the QUANTIZED 4-tuple (k, v,
+        k_scale, v_scale) with int8 value pages and (num_pages, KVH,
+        page) fp32 per-slot scales (ISSUE 6). Returns (out, kv) with the
+        updated cache tuple, same arity.
+
+        The span's roped K/V are WRITTEN first (quantize-on-write for
+        int8): decode's one token at position start - 1
+        (`paged_cache_write`); a chunk's first `live` tokens from
+        position `start` (`paged_cache_write_range`); a verify row's
+        1 + live tokens from position start - 1
+        (`paged_cache_write_span`). Rewriting a position already written
+        is idempotent — quantize-on-write is deterministic — so
+        supervisor retries, a frozen row's scan steps and
+        rollback-rewrites stay bit-identical.
+
+        Then it ATTENDS. Decode: `kernels.paged_attention_decode` over
+        the block tables (dequantize-in-kernel). Prefill and verify:
+        over the GATHERED dense view of each row's pages (dequantized
+        during the gather on the int8 path) — the cached prefix plus the
+        span itself — under the position mask kpos <= qpos, with qpos =
+        start + i for a chunk and (start - 1) + j for a verify row.
+        Prefill is compute-bound, so one XLA gather per layer is the
+        right capability-axis cost; a fused chunk-attention Pallas
+        kernel is a perf follow-up (ROADMAP Speed 4).
         """
         from ..kernels.paged_attention import (paged_attention_decode,
-                                               paged_cache_write)
+                                               paged_cache_write,
+                                               paged_cache_write_range,
+                                               paged_cache_write_span)
         b, s, _ = x.shape
+        kind = span.kind
+        # the axes the gathered rope rows lack of (B, S, 1, D/2)
+        expand = {"decode": (1, 2), "prefill": (0, 2), "verify": (2,)}[kind]
         q, k = self._paged_qk(_lora("q_proj", x, self.q_proj(x)),
                               _lora("k_proj", x, self.k_proj(x)),
-                              cos_b, sin_b, (1, 2))
+                              cos, sin, expand)
         v = M.reshape(_lora("v_proj", x, self.v_proj(x)),
                       [b, s, self.n_kv, self.head_dim])
 
-        def _write(*arrs):
-            kc, vc, ks, vs, (kn, vn, bt, sl) = _split_kv_args(arrs, 4)
-            return paged_cache_write(kc, vc, kn[:, 0], vn[:, 0], bt,
-                                     sl.astype(jnp.int32) - 1,
-                                     k_scale=ks, v_scale=vs)
-
-        kv = apply_op("paged_cache_write", _write, *kv, k, v,
-                      block_tables, seq_lens)
-
-        def _attend(qq, *arrs):
-            kc, vc, ks, vs, (bt, sl) = _split_kv_args(arrs, 2)
-            mesh = current_mesh()
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                # TP serving (ISSUE 8): heads/KV pages sharded over
-                # 'model' — each shard attends its own head slice
-                from ..kernels.paged_attention import \
-                    paged_attention_decode_tp
-                return paged_attention_decode_tp(
-                    qq.reshape(b, self.n_heads, self.head_dim), kc, vc,
-                    bt, sl, mesh, k_scale=ks, v_scale=vs)
-            return paged_attention_decode(
-                qq.reshape(b, self.n_heads, self.head_dim), kc, vc,
-                bt, sl, k_scale=ks, v_scale=vs)
-
-        out = apply_op("paged_attention_decode", _attend, q, *kv,
-                       block_tables, seq_lens)
-        out = M.reshape(out, [b, s, self.n_heads * self.head_dim])
-        return _lora("o_proj", out, self.o_proj(out)), kv
-
-    def forward_paged_prefill(self, x, cos_c, sin_c, kv,
-                              block_table, cache_len, chunk_len):
-        """One CHUNK of prompt prefill over the paged cache (the chunked
-        prefill / prefix-cache serving path).
-
-        x (1, S, hidden) holds tokens at absolute positions
-        cache_len..cache_len+S-1, of which only the first chunk_len are
-        live (the rest is bucket padding); cos_c/sin_c (S, D/2) are the
-        rope rows already gathered at those absolute positions;
-        kv = (k_cache, v_cache) or the quantized (k, v, k_scale,
-        v_scale) tuple (int8 pages + fp32 per-slot scales, ISSUE 6);
-        block_table (P,) is the sequence's page ids (PAD_PAGE-padded).
-        Writes the chunk's roped K/V into the pages at offset cache_len
-        (quantize-on-write for int8), then attends over the GATHERED
-        dense view of the sequence's pages — the cached prefix
-        [0, cache_len) plus the chunk itself, dequantized during the
-        gather on the int8 path — with a position mask
-        kpos <= cache_len + i. Prefill is compute-bound, so one XLA
-        gather per layer is the right capability-axis cost; a fused
-        chunk-attention Pallas kernel is a perf follow-up (BASELINE).
-        Returns (out, kv).
-        """
-        from ..kernels.paged_attention import paged_cache_write_range
-        b, s, _ = x.shape
-        q, k = self._paged_qk(_lora("q_proj", x, self.q_proj(x)),
-                              _lora("k_proj", x, self.k_proj(x)),
-                              cos_c, sin_c, (0, 2))
-        v = M.reshape(_lora("v_proj", x, self.v_proj(x)),
-                      [b, s, self.n_kv, self.head_dim])
+        where = (span.start,) if kind == "decode" else (span.start, span.live)
 
         def _write(*arrs):
-            kc, vc, ks, vs, (kn, vn, bt, ln, st) = _split_kv_args(arrs, 5)
-            return paged_cache_write_range(kc, vc, kn[0], vn[0], bt,
-                                           ln, st, k_scale=ks, v_scale=vs)
-
-        kv = apply_op("paged_cache_write_range", _write, *kv, k, v,
-                      block_table, chunk_len, cache_len)
-        kd, vd = self._gathered_dense(kv, block_table, 1, q._data.dtype)
-        if self.n_kv != self.n_heads:
-            rep = self.n_heads // self.n_kv
-            kd = apply_op("repeat_kv",
-                          lambda a: jnp.repeat(a, rep, axis=2), kd)
-            vd = apply_op("repeat_kv",
-                          lambda a: jnp.repeat(a, rep, axis=2), vd)
-        sk = int(kd.shape[1])
-
-        def _mask(cl):
-            qpos = jnp.asarray(cl, jnp.int32) + jnp.arange(s, dtype=jnp.int32)
-            kpos = jnp.arange(sk, dtype=jnp.int32)
-            return (kpos[None, :] <= qpos[:, None])[None, None]
-
-        mask = apply_op("chunk_mask", _mask, cache_len)
-        out = F.scaled_dot_product_attention(q, kd, vd, attn_mask=mask)
-        out = M.reshape(out, [b, s, self.n_heads * self.head_dim])
-        return _lora("o_proj", out, self.o_proj(out)), kv
-
-    def forward_paged_verify(self, x, cos_bs, sin_bs, kv,
-                             block_tables, seq_lens, draft_lens):
-        """One speculative VERIFY step over the paged cache: each row
-        scores 1 + K tokens (the last emitted token plus K draft tokens)
-        against its own paged prefix in ONE launch — the multi-token
-        sibling of `forward_paged` (decode) built from the same pieces
-        as `forward_paged_prefill` (gathered-prefix attention), batched.
-
-        x (B, S, hidden): row b's tokens sit at absolute positions
-        seq_lens[b]-1 .. seq_lens[b]-1+S-1, of which the first
-        1 + draft_lens[b] are live (the rest is K-bucket padding);
-        cos_bs/sin_bs (B, S, D/2) are rope rows pre-gathered at those
-        positions; k/v_cache (num_pages, KVH, page, D); block_tables
-        (B, max_pages); seq_lens (B,) counts tokens through the FIRST
-        input token (the `forward_paged` convention — its position is
-        seq_lens-1). kv = (k_cache, v_cache) or the quantized 4-tuple
-        (ISSUE 6). Writes all live positions' roped K/V via
-        `paged_cache_write_span` (idempotent for position seq_lens-1,
-        like the decode write — quantize-on-write is deterministic, so
-        retries and rollback-rewrites stay bit-identical), then attends
-        over the gathered dense view of each row's pages (dequantized
-        during the gather on the int8 path) under the causal mask
-        kpos <= (seq_lens-1) + j. Returns (out, kv).
-        """
-        from ..kernels.paged_attention import paged_cache_write_span
-        b, s, _ = x.shape
-        q, k = self._paged_qk(self.q_proj(x), self.k_proj(x), cos_bs, sin_bs,
-                              (2,))
-        v = M.reshape(self.v_proj(x), [b, s, self.n_kv, self.head_dim])
-
-        def _write(*arrs):
-            kc, vc, ks, vs, (kn, vn, bt, sl, dl) = _split_kv_args(arrs, 5)
+            kc, vc, ks, vs, (kn, vn, bt, start, *live) = _split_kv_args(
+                arrs, 3 + len(where))
+            scales = dict(k_scale=ks, v_scale=vs)
+            if kind == "decode":
+                return paged_cache_write(
+                    kc, vc, kn[:, 0], vn[:, 0], bt,
+                    start.astype(jnp.int32) - 1, **scales)
+            if kind == "prefill":
+                return paged_cache_write_range(kc, vc, kn[0], vn[0], bt,
+                                               live[0], start, **scales)
             return paged_cache_write_span(
                 kc, vc, kn, vn, bt,
-                dl.astype(jnp.int32) + 1,            # live span tokens
-                sl.astype(jnp.int32) - 1,            # first token's slot
-                k_scale=ks, v_scale=vs)
+                live[0].astype(jnp.int32) + 1,       # live span tokens
+                start.astype(jnp.int32) - 1,         # first token's slot
+                **scales)
 
-        kv = apply_op("paged_cache_write_span", _write, *kv, k, v,
-                      block_tables, seq_lens, draft_lens)
-        kd, vd = self._gathered_dense(kv, block_tables, b, q._data.dtype)
-        if self.n_kv != self.n_heads:
-            rep = self.n_heads // self.n_kv
-            kd = apply_op("repeat_kv",
-                          lambda a: jnp.repeat(a, rep, axis=2), kd)
-            vd = apply_op("repeat_kv",
-                          lambda a: jnp.repeat(a, rep, axis=2), vd)
-        sk = int(kd.shape[1])
+        kv = apply_op({"decode": "paged_cache_write",
+                       "prefill": "paged_cache_write_range",
+                       "verify": "paged_cache_write_span"}[kind],
+                      _write, *kv, k, v, block_tables, *where)
+        if kind == "decode":
+            def _attend(qq, *arrs):
+                kc, vc, ks, vs, (bt, sl) = _split_kv_args(arrs, 2)
+                mesh = current_mesh()
+                if mesh is not None and mesh.shape.get("model", 1) > 1:
+                    # TP serving (ISSUE 8): heads/KV pages sharded over
+                    # 'model' — each shard attends its own head slice
+                    from ..kernels.paged_attention import \
+                        paged_attention_decode_tp
+                    return paged_attention_decode_tp(
+                        qq.reshape(b, self.n_heads, self.head_dim), kc, vc,
+                        bt, sl, mesh, k_scale=ks, v_scale=vs)
+                return paged_attention_decode(
+                    qq.reshape(b, self.n_heads, self.head_dim), kc, vc,
+                    bt, sl, k_scale=ks, v_scale=vs)
 
-        def _mask(sl):
-            # padded batch rows carry seq_len 0 -> qpos would be -1 and
-            # fully mask their first row (NaN softmax); clamp to 0 so
-            # dead rows stay finite — their outputs are discarded
-            qpos = jnp.maximum(
-                sl.astype(jnp.int32)[:, None] - 1
-                + jnp.arange(s, dtype=jnp.int32)[None, :], 0)   # (B, S)
-            kpos = jnp.arange(sk, dtype=jnp.int32)
-            return (kpos[None, None, :] <= qpos[:, :, None])[:, None]
+            out = apply_op("paged_attention_decode", _attend, q, *kv,
+                           block_tables, span.start)
+        else:
+            kd, vd = self._gathered_dense(kv, block_tables, b, q._data.dtype)
+            if self.n_kv != self.n_heads:
+                rep = self.n_heads // self.n_kv
+                kd = apply_op("repeat_kv",
+                              lambda a: jnp.repeat(a, rep, axis=2), kd)
+                vd = apply_op("repeat_kv",
+                              lambda a: jnp.repeat(a, rep, axis=2), vd)
+            sk = int(kd.shape[1])
 
-        mask = apply_op("verify_mask", _mask, seq_lens)
-        out = F.scaled_dot_product_attention(q, kd, vd, attn_mask=mask)
+            def _mask(start):
+                if kind == "prefill":
+                    qpos = jnp.asarray(start, jnp.int32) \
+                        + jnp.arange(s, dtype=jnp.int32)
+                    kpos = jnp.arange(sk, dtype=jnp.int32)
+                    return (kpos[None, :] <= qpos[:, None])[None, None]
+                # padded batch rows carry start 0 -> qpos would be -1 and
+                # fully mask their first row (NaN softmax); clamp to 0 so
+                # dead rows stay finite — their outputs are discarded
+                qpos = jnp.maximum(
+                    start.astype(jnp.int32)[:, None] - 1
+                    + jnp.arange(s, dtype=jnp.int32)[None, :], 0)   # (B, S)
+                kpos = jnp.arange(sk, dtype=jnp.int32)
+                return (kpos[None, None, :] <= qpos[:, :, None])[:, None]
+
+            mask = apply_op("chunk_mask" if kind == "prefill"
+                            else "verify_mask", _mask, span.start)
+            out = F.scaled_dot_product_attention(q, kd, vd, attn_mask=mask)
         out = M.reshape(out, [b, s, self.n_heads * self.head_dim])
-        return self.o_proj(out), kv
+        return _lora("o_proj", out, self.o_proj(out)), kv
 
 
 class LlamaMLP(nn.Layer):
@@ -498,28 +447,9 @@ class LlamaDecoderLayer(nn.Layer):
         x = x + self.mlp(self.post_attention_layernorm(x))
         return (x, cache) if cache is not None else x
 
-    def forward_paged(self, x, cos_b, sin_b, kv, block_tables, seq_lens):
+    def paged(self, x, cos, sin, kv, block_tables, span):
         h = self.input_layernorm(x)
-        attn, kv = self.self_attn.forward_paged(
-            h, cos_b, sin_b, kv, block_tables, seq_lens)
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x))
-        return x, kv
-
-    def forward_paged_prefill(self, x, cos_c, sin_c, kv,
-                              block_table, cache_len, chunk_len):
-        h = self.input_layernorm(x)
-        attn, kv = self.self_attn.forward_paged_prefill(
-            h, cos_c, sin_c, kv, block_table, cache_len, chunk_len)
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x))
-        return x, kv
-
-    def forward_paged_verify(self, x, cos_bs, sin_bs, kv,
-                             block_tables, seq_lens, draft_lens):
-        h = self.input_layernorm(x)
-        attn, kv = self.self_attn.forward_paged_verify(
-            h, cos_bs, sin_bs, kv, block_tables, seq_lens, draft_lens)
+        attn, kv = self.self_attn.paged(h, cos, sin, kv, block_tables, span)
         x = x + attn
         x = x + self.mlp(self.post_attention_layernorm(x))
         return x, kv
@@ -568,90 +498,41 @@ class LlamaModel(nn.Layer):
         x = self.norm(x)
         return (x, new_caches) if caches is not None else x
 
-    def forward_paged_decode(self, input_ids, paged_caches, block_tables,
-                             seq_lens):
-        """One batched decode step over per-layer paged KV caches.
+    def paged(self, input_ids, paged_caches, block_tables, span):
+        """One span over per-layer paged KV caches (`PagedSpan`):
+        input_ids (B, S); paged_caches a list of per-layer cache tuples
+        — (k_cache, v_cache), or (k, v, k_scale, v_scale) for int8 KV
+        (ISSUE 6). The rope rows are gathered at the span's positions:
+        start - 1 a decode row, start + i a chunk, (start - 1) + j a
+        verify row. Chunked prefill and radix prefix-cache hits are the
+        same program: a hit just starts at start = matched tokens.
+        Returns (hidden (B, S, H), new_caches) with the same tuple
+        arity."""
+        kind, s = span.kind, input_ids.shape[1]
 
-        input_ids (B, 1); paged_caches: list of per-layer cache tuples —
-        (k_cache, v_cache), or (k, v, k_scale, v_scale) for int8 KV
-        (ISSUE 6); seq_lens counts the token being decoded (its
-        position is seq_lens-1). Returns (hidden (B, 1, H),
-        new_caches) with the same tuple arity."""
-        def _gather_rope(c, sl):
-            return jnp.take(c, sl.astype(jnp.int32) - 1, axis=0)
-
-        cos_b = apply_op("rope_gather", _gather_rope, self.rope_cos,
-                         seq_lens)
-        sin_b = apply_op("rope_gather", _gather_rope, self.rope_sin,
-                         seq_lens)
-        x = self.embed_tokens(input_ids)
-        new_caches = []
-        for i, layer in enumerate(self.layers):
-            x, kv = layer.forward_paged(x, cos_b, sin_b, paged_caches[i],
-                                        block_tables, seq_lens)
-            new_caches.append(kv)
-        return self.norm(x), new_caches
-
-    def forward_paged_prefill(self, input_ids, paged_caches, block_table,
-                              cache_len, chunk_len):
-        """One prefill CHUNK over per-layer paged KV caches.
-
-        input_ids (1, S) — prompt tokens at absolute positions
-        cache_len..cache_len+S-1 (first chunk_len live, rest padding);
-        block_table (P,) — the sequence's pages. Returns
-        (hidden (1, S, H), new_caches). Chunked prefill and radix
-        prefix-cache hits are the same program: a hit just starts at
-        cache_len = matched tokens."""
-        s = input_ids.shape[1]
-
-        def _gather_rope(c, cl):
-            pos = jnp.asarray(cl, jnp.int32) + jnp.arange(s, dtype=jnp.int32)
-            # padded tail positions may run past the rope table; clip —
-            # their rows are masked out of the attention anyway
+        def _gather_rope(c, start):
+            if kind == "decode":
+                return jnp.take(c, start.astype(jnp.int32) - 1, axis=0)
+            if kind == "prefill":
+                pos = jnp.asarray(start, jnp.int32) \
+                    + jnp.arange(s, dtype=jnp.int32)
+            else:
+                pos = (start.astype(jnp.int32)[:, None] - 1
+                       + jnp.arange(s, dtype=jnp.int32)[None, :])    # (B, S)
+            # a chunk's padded tail, a padded row (start 0) and a padded
+            # span tail may run off the table; clip — those rows are
+            # masked out of the attention or discarded
             return jnp.take(c, jnp.clip(pos, 0, c.shape[0] - 1), axis=0)
 
-        cos_c = apply_op("rope_gather", _gather_rope, self.rope_cos,
-                         cache_len)
-        sin_c = apply_op("rope_gather", _gather_rope, self.rope_sin,
-                         cache_len)
+        cos = apply_op("rope_gather", _gather_rope, self.rope_cos,
+                       span.start)
+        sin = apply_op("rope_gather", _gather_rope, self.rope_sin,
+                       span.start)
         x = self.embed_tokens(input_ids)
         new_caches = []
         for i, layer in enumerate(self.layers):
-            x, kv = layer.forward_paged_prefill(
-                x, cos_c, sin_c, paged_caches[i], block_table, cache_len,
-                chunk_len)
-            new_caches.append(kv)
-        return self.norm(x), new_caches
-
-    def forward_paged_verify(self, input_ids, paged_caches, block_tables,
-                             seq_lens, draft_lens):
-        """One speculative VERIFY step over per-layer paged KV caches.
-
-        input_ids (B, S) — row b holds [last emitted token,
-        draft_1..draft_{S-1}] at absolute positions seq_lens[b]-1
-        onward (first 1 + draft_lens[b] live, rest K-bucket padding);
-        seq_lens counts tokens through the first input token (the
-        `forward_paged_decode` convention). Returns
-        (hidden (B, S, H), new_caches)."""
-        s = input_ids.shape[1]
-
-        def _gather_rope(c, sl):
-            pos = (sl.astype(jnp.int32)[:, None] - 1
-                   + jnp.arange(s, dtype=jnp.int32)[None, :])    # (B, S)
-            # padded rows (seq_len 0) and padded span tails may run
-            # off the table; clip — those rows are masked/discarded
-            return jnp.take(c, jnp.clip(pos, 0, c.shape[0] - 1), axis=0)
-
-        cos_bs = apply_op("rope_gather", _gather_rope, self.rope_cos,
-                          seq_lens)
-        sin_bs = apply_op("rope_gather", _gather_rope, self.rope_sin,
-                          seq_lens)
-        x = self.embed_tokens(input_ids)
-        new_caches = []
-        for i, layer in enumerate(self.layers):
-            x, kv = layer.forward_paged_verify(
-                x, cos_bs, sin_bs, paged_caches[i], block_tables,
-                seq_lens, draft_lens)
+            x, kv = layer.paged(x, cos, sin, paged_caches[i], block_tables,
+                                span)
             new_caches.append(kv)
         return self.norm(x), new_caches
 
@@ -725,55 +606,9 @@ class LlamaForCausalLM(nn.Layer):
             return out
         return (out, caches) if caches is not None else out
 
-    def forward_paged_decode(self, input_ids, paged_caches, block_tables,
-                             seq_lens):
-        """Serving decode step: paged-KV transformer + LM head.
-        Returns (logits (B, 1, V), new_caches)."""
-        h, caches = self.model.forward_paged_decode(
-            input_ids, paged_caches, block_tables, seq_lens)
-        tied = self.model.embed_tokens.weight if self.lm_head is None else None
-        logits = _head_and_loss(h, None, self.lm_head, tied)
-        return logits, caches
-
-    def forward_paged_prefill(self, input_ids, paged_caches, block_table,
-                              cache_len, chunk_len):
-        """Serving prefill chunk: paged-KV transformer + LM head at the
-        chunk's LAST LIVE position only — the sole row serving consumes
-        (and only on the final chunk at that); a full (S, V) head would
-        spend ~S x the head FLOPs per chunk for nothing.
-        Returns (logits (1, 1, V), new_caches)."""
-        h, caches = self.model.forward_paged_prefill(
-            input_ids, paged_caches, block_table, cache_len, chunk_len)
-
-        def _last(hh, ln):
-            return jax.lax.dynamic_slice_in_dim(
-                hh, jnp.asarray(ln, jnp.int32) - 1, 1, axis=1)
-
-        h_last = apply_op("chunk_last", _last, h, chunk_len)
-        tied = self.model.embed_tokens.weight if self.lm_head is None else None
-        logits = _head_and_loss(h_last, None, self.lm_head, tied)
-        return logits, caches
-
-    def forward_paged_verify(self, input_ids, paged_caches, block_tables,
-                             seq_lens, draft_lens):
-        """Serving speculative-verify step: paged-KV transformer over
-        1 + K tokens per row + LM head at EVERY position — the verify
-        consumer needs logits after each draft token (position j's
-        logits score draft j+1 and supply the correction/bonus token),
-        so unlike the chunk program the full (B, S, V) head is the
-        point, not waste (S = K+1 is small). Returns
-        (logits (B, S, V), new_caches)."""
-        h, caches = self.model.forward_paged_verify(
-            input_ids, paged_caches, block_tables, seq_lens, draft_lens)
-        tied = self.model.embed_tokens.weight if self.lm_head is None else None
-        logits = _head_and_loss(h, None, self.lm_head, tied)
-        return logits, caches
-
     # ------------------------------------------- the engine's contract
     # (models/paged.py): the cache entry, the one paged entry over a
-    # span of query positions, and no counters. The three methods above
-    # are this family's implementation of the spans; the engine names
-    # none of them.
+    # span of query positions, and no counters.
     paged_counters = ()
 
     def paged_cache_spec(self, page_size, dtype, kv_dtype=None, tp=1):
@@ -811,35 +646,30 @@ class LlamaForCausalLM(nn.Layer):
             paged_page_bytes(kvh // tp, page_size, hd, name))
 
     def paged_forward(self, input_ids, paged_caches, block_tables, span):
-        """The one paged entry (models/paged.py `PagedSpan`)."""
-        if span.kind == "decode":
-            out = self.forward_paged_decode(input_ids, paged_caches,
-                                            block_tables, span.start)
-        elif span.kind == "prefill":
-            out = self.forward_paged_prefill(input_ids, paged_caches,
-                                             block_tables, span.start,
-                                             span.live)
-        elif span.kind == "verify":
-            out = self.forward_paged_verify(input_ids, paged_caches,
-                                            block_tables, span.start,
-                                            span.live)
-        else:
-            raise ValueError(f"unknown span kind {span.kind!r}")
-        return out + ((),)
+        """The one paged entry (models/paged.py `PagedSpan`): the
+        paged-KV transformer and the LM head; no counters.
 
-    def forward_paged_decode_multi(self, input_ids, paged_caches,
-                                   block_tables, seq_lens, step_caps,
-                                   eos_ids, key, *, k_steps,
-                                   temperature=0.0, top_k=0, top_p=1.0):
-        """K decode iterations in ONE trace: `models/paged.py`
-        `decode_multi`, the scan every family shares, under the name
-        `bench_ops.py` calls. Returns (tokens (B, K), n_emit (B,),
-        ok (B,), caches)."""
-        from .paged import decode_multi
-        return decode_multi(self, input_ids, paged_caches, block_tables,
-                            seq_lens, step_caps, eos_ids, key,
-                            k_steps=k_steps, temperature=temperature,
-                            top_k=top_k, top_p=top_p)[:4]
+        A prefill span's head runs at the chunk's LAST LIVE position
+        only — the sole row serving consumes (and only on the final
+        chunk at that); a full (S, V) head would spend ~S x the head
+        FLOPs per chunk for nothing: logits (1, 1, V). Decode and verify
+        spans head EVERY position, (B, 1, V) and (B, S, V): the verify
+        consumer needs logits after each draft token (position j's
+        logits score draft j+1 and supply the correction/bonus token),
+        so there the full head is the point, not waste (S = K+1 is
+        small)."""
+        if span.kind not in ("decode", "prefill", "verify"):
+            raise ValueError(f"unknown span kind {span.kind!r}")
+        h, caches = self.model.paged(input_ids, paged_caches, block_tables,
+                                     span)
+        if span.kind == "prefill":
+            def _last(hh, ln):
+                return jax.lax.dynamic_slice_in_dim(
+                    hh, jnp.asarray(ln, jnp.int32) - 1, 1, axis=1)
+
+            h = apply_op("chunk_last", _last, h, span.live)
+        tied = self.model.embed_tokens.weight if self.lm_head is None else None
+        return _head_and_loss(h, None, self.lm_head, tied), caches, ()
 
     # -------------------------------------------------------- generation
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0,

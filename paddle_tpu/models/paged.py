@@ -1,7 +1,7 @@
 """The model side of the serving engine's contract.
 
-`ServingEngine` programs against three things a model gives, and names no
-other method of it:
+`ServingEngine` (and the draft-model proposer of `serving/spec`) programs
+against three things a model gives, and names no other method of it:
 
   paged_cache_spec(page_size, dtype, kv_dtype=None, tp=1)
       -> PagedCacheSpec: what ONE layer's cache entry is. A page id names
@@ -25,6 +25,11 @@ other method of it:
 
 `decode_multi` is K decode steps in one trace over that entry: shared by
 every model, so no family carries a copy of the scan.
+
+A family implements the entry as it likes; both families here do it with
+ONE span-shaped `paged(..., span)` a level under `paged_forward` (model,
+decoder layer, attention), branching on `span.kind` only where the spans
+differ: the positions' rope rows, the cache write, the attention.
 """
 from __future__ import annotations
 
@@ -38,7 +43,8 @@ from ..core.tensor import Tensor
 
 __all__ = ["PAGED_ENTRY", "PagedSpan", "PagedCacheSpec", "decode_multi"]
 
-# the one method name the engine's builders pass to `functional_call`
+# the one method name the engine's and the draft model's builders pass to
+# `functional_call`
 PAGED_ENTRY = "paged_forward"
 
 

@@ -77,6 +77,7 @@ interleavings.
 """
 from __future__ import annotations
 
+import inspect
 import itertools
 import time
 from typing import Dict, List, Optional
@@ -193,6 +194,17 @@ FAULT_DRAFT = faults.register_point("serving.spec.draft_storm")
 # Multi-step decode (ISSUE 13): mirrors decode_step — fires BEFORE the
 # launch, so an injected transient retries the identical K-step program.
 FAULT_MULTI = faults.register_point("serving.engine.multi_decode_step")
+
+# What a program family's launch is called and fires, beside its inputs:
+# family -> (the launch span `serving.<name>`, which is also the
+# supervisor's label and the request tracer's span; the letters of its
+# bucket dims in the step record's program label; its fault point)
+_LAUNCHES = {
+    "chunk": ("prefill_chunk", "SP", FAULT_CHUNK),
+    "decode": ("decode_step", "BP", FAULT_DECODE),
+    "multi_decode": ("multi_decode_step", "BKP", FAULT_MULTI),
+    "verify": ("verify_step", "BKP", FAULT_VERIFY),
+}
 
 # Ceiling on decode_steps (K): each launch runs K decode iterations in
 # one device-side scan, and a device loop of 4096 iterations once left
@@ -1172,6 +1184,85 @@ class ServingEngine:
             with profiler.RecordEvent("serving.model_counters", **got):
                 pass
 
+    # ---------------------------------------- the one paged program frame
+    def _paged_program(self, body):
+        """The frame of every paged program around one family's
+        `body(st, paged, *inputs) -> (*outputs, caches)`, which calls the
+        model (PAGED_ENTRY over a span, or `decode_multi`) on the state
+        wrapped in Tensors and the per-layer cache views and makes of
+        the logits what is the family's own. The inputs the body names
+        are followed by the launch's LoRA arguments, if it passes any
+        (`_lora_launch_args`); their scope is open over the WHOLE body,
+        so under a scan the gathered slot stacks are loop constants and
+        the paged gather runs once a LAUNCH, not once a decode step.
+        `program(state, kcs, vcs, kss, vss, *inputs) -> (*outputs, kcs,
+        vcs, kss, vss)`, jitted with the four cache lists donated."""
+        views, split = self._paged_views, self._split_views
+        lora_open = self._lora_trace_scope
+        n_in = len(inspect.signature(body).parameters) - 2
+
+        def program(state, kcs, vcs, kss, vss, *inputs):
+            st = {k: Tensor(v) for k, v in state.items()}
+            paged = views(kcs, vcs, kss, vss)
+            with lora_open(inputs[n_in:]):
+                *outs, caches = body(st, paged, *inputs[:n_in])
+            return tuple(outs) + split(caches)
+
+        # tpu-lint: cache-key-ok (donation is backend-constant per process)
+        return jax.jit(program, donate_argnums=self._donate)
+
+    # ------------------------------------------------ the one launch path
+    def _launcher(self, family: str, dims: tuple, builder, rids, inputs,
+                  key, largs=()):
+        """The one launch path of the four program families. Called in
+        the launch's `serving.build_inputs` span, it looks the program
+        up under `(family,) + dims + self._qkey`, records what the retry
+        hook (`_cur_rids`) and the step record read, and returns the
+        supervised launch: called, that fires the family's fault point
+        and runs the program in the family's launch span (`_LAUNCHES`)
+        under the poison scope naming `rids`, `no_grad` and the engine's
+        mesh; its result is the program's, still on the device, the four
+        cache lists last. Between the two the caller stamps its clocks;
+        after, it fetches and keeps its books in its family's order
+        (PERF.md section 3). `inputs` are the host arrays after the
+        caches, put on the device on every attempt; `key` is drawn ONCE,
+        by the caller, so a transient-failure retry re-runs the identical
+        program (bit-identical tokens) and burns no key per attempt."""
+        name, letters, fault = _LAUNCHES[family]
+        prog = self._get_program((family,) + dims + self._qkey, builder)
+        self._cur_rids = tuple(rids)
+        self._step_ev["programs"].append(":".join(
+            [family] + [f"{c}{d}" for c, d in zip(letters, dims)]))
+        who = f"req={rids[0]}" if family == "chunk" else f"reqs={rids}"
+
+        def launch():
+            faults.fire(fault)
+            with profiler.RecordEvent("serving." + name,
+                                      bucket=list(dims)), \
+                    poison_scope(f"serving.{name}[{who}]"), no_grad(), \
+                    self._trace_scope():
+                return prog(self._state, *self._cache_lists(),
+                            *[jnp.asarray(a) for a in inputs], key, *largs)
+
+        return lambda: self.supervisor.run(launch, label=name)
+
+    def _decode_batch(self, reqs: List[Request]):
+        """What the three decode-side families' launches share: the
+        batch and block-table buckets, the PAD_PAGE-filled block table,
+        the request ids, and the LoRA launch arguments with the launch's
+        adapter-mix sample. Returns (B, P, bt (B, P), rids, largs)."""
+        B = _bucket_for(len(reqs), self.batch_buckets)
+        P = _bucket_for(max(len(r.seq.pages) for r in reqs),
+                        self.pages_buckets)
+        bt = np.full((B, P), PAD_PAGE, np.int32)
+        bt[:len(reqs)] = self.allocator.block_table(
+            [r.seq for r in reqs], P)
+        largs = self._lora_launch_args(reqs, B)
+        if self.lora is not None:
+            self.metrics.on_adapter_mix(
+                len({r.adapter for r in reqs if r.adapter is not None}))
+        return B, P, bt, [r.request_id for r in reqs], largs
+
     # ----------------------------------------------------- prefill chunks
     def _build_chunk(self, S: int, P: int):
         """One padded prompt CHUNK -> paged cache + sampled token (the
@@ -1179,28 +1270,21 @@ class ServingEngine:
         # tpu-lint: cache-key-ok (per-engine cache; disk tier keys geometry)
         model = self.model
         temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
-        views, split = self._paged_views, self._split_views
-        lora_open = self._lora_trace_scope
 
-        def program(state, kcs, vcs, kss, vss, ids, cache_len, live, bt,
-                    key, *largs):
-            st = {k: Tensor(v) for k, v in state.items()}
-            paged = views(kcs, vcs, kss, vss)
-            with lora_open(largs):
-                logits, caches, counts = functional_call(
-                    model, st, Tensor(ids), paged, Tensor(bt),
-                    PagedSpan("prefill", Tensor(cache_len), Tensor(live)),
-                    method=PAGED_ENTRY)
+        def body(st, paged, ids, cache_len, live, bt, key):
+            logits, caches, counts = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt),
+                PagedSpan("prefill", Tensor(cache_len), Tensor(live)),
+                method=PAGED_ENTRY)
             last = logits._data[0, 0]   # head ran at the chunk end only
             # in-graph NaN detection (the jit counterpart of the eager
             # dispatch NaN hook): NaN/Inf anywhere in the network flows
             # into the chunk-end logits, so one reduction covers the step
             ok = jnp.all(jnp.isfinite(last))
             tok = _sample_arr(last[None], key, temperature, top_k, top_p)[0]
-            return (tok, ok, counts) + split(caches)
+            return tok, ok, counts, caches
 
-        # tpu-lint: cache-key-ok (donation is backend-constant per process)
-        return jax.jit(program, donate_argnums=self._donate)
+        return self._paged_program(body)
 
     def _run_chunk(self, chunk):
         req = chunk.request
@@ -1210,39 +1294,21 @@ class ServingEngine:
             P = _bucket_for(
                 self.allocator.pages_needed(chunk.start + chunk.length),
                 self.pages_buckets)
-            prog = self._get_program(("chunk", S, P) + self._qkey,
-                                     lambda: self._build_chunk(S, P))
             bt = np.full((P,), PAD_PAGE, np.int32)
             npages = min(len(req.seq.pages), P)
             bt[:npages] = req.seq.pages[:npages]
             padded = np.zeros((1, S), np.int32)
             padded[0, :chunk.length] = ids
-            # the RNG key is drawn ONCE, before the supervised launch, so
-            # a transient-failure retry re-runs the identical program
-            # (bit-identical token) instead of burning a new key per
-            # attempt
-            key = self._next_key() if chunk.is_last else self._null_key
-            largs = self._lora_launch_args([req], 1)
-            self._cur_rids = (req.request_id,)
-            self._step_ev["programs"].append(f"chunk:S{S}:P{P}")
-
-        def launch():
-            faults.fire(FAULT_CHUNK)
-            with profiler.RecordEvent("serving.prefill_chunk",
-                                      bucket=[S, P]), \
-                    poison_scope(f"serving.prefill_chunk[req="
-                                 f"{req.request_id}]"), no_grad(), \
-                    self._trace_scope():
-                return prog(
-                    self._state, self._k_caches, self._v_caches,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(padded), jnp.int32(chunk.start),
-                    jnp.int32(chunk.length), jnp.asarray(bt), key,
-                    *largs)
+            launch = self._launcher(
+                "chunk", (S, P), lambda: self._build_chunk(S, P),
+                [req.request_id],
+                (padded, np.int32(chunk.start), np.int32(chunk.length), bt),
+                # only a prompt's last chunk consumes its token
+                self._next_key() if chunk.is_last else self._null_key,
+                self._lora_launch_args([req], 1))
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
-        tok, ok, counts, *caches = self.supervisor.run(
-            launch, label="prefill_chunk")
+        tok, ok, counts, *caches = launch()
         with profiler.RecordEvent("serving.bookkeeping"):
             self._tr_launch((req.request_id,), "prefill_chunk", t_tr,
                             start=chunk.start, length=chunk.length,
@@ -1271,68 +1337,37 @@ class ServingEngine:
         # tpu-lint: cache-key-ok (per-engine cache; disk tier keys geometry)
         model = self.model
         temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
-        views, split = self._paged_views, self._split_views
-        lora_open = self._lora_trace_scope
 
-        def program(state, kcs, vcs, kss, vss, ids, bt, sl, key, *largs):
-            st = {k: Tensor(v) for k, v in state.items()}
-            paged = views(kcs, vcs, kss, vss)
-            with lora_open(largs):
-                logits, caches, counts = functional_call(
-                    model, st, Tensor(ids), paged, Tensor(bt),
-                    PagedSpan("decode", Tensor(sl)), method=PAGED_ENTRY)
+        def body(st, paged, ids, bt, sl, key):
+            logits, caches, counts = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt),
+                PagedSpan("decode", Tensor(sl)), method=PAGED_ENTRY)
             rows = logits._data[:, 0, :]
             # per-row finiteness: rows are independent (SERVING.md), so a
             # poisoned request flags ONLY its own row — the quarantine
             # granularity ("fail one request, not the engine")
             ok = jnp.all(jnp.isfinite(rows), axis=-1)
             toks = _sample_arr(rows, key, temperature, top_k, top_p)
-            return (toks, ok, counts) + split(caches)
+            return toks, ok, counts, caches
 
-        # tpu-lint: cache-key-ok (donation is backend-constant per process)
-        return jax.jit(program, donate_argnums=self._donate)
+        return self._paged_program(body)
 
     def _run_decode(self, reqs: List[Request]):
         with profiler.RecordEvent("serving.build_inputs"):
-            B = _bucket_for(len(reqs), self.batch_buckets)
-            max_pages = max(len(r.seq.pages) for r in reqs)
-            P = _bucket_for(max_pages, self.pages_buckets)
-            prog = self._get_program(("decode", B, P) + self._qkey,
-                                     lambda: self._build_decode(B, P))
+            B, P, bt, rids, largs = self._decode_batch(reqs)
             ids = np.zeros((B, 1), np.int32)
             sl = np.zeros((B,), np.int32)
-            seqs = [r.seq for r in reqs]
-            bt = np.full((B, P), PAD_PAGE, np.int32)
-            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
             for i, r in enumerate(reqs):
                 ids[i, 0] = r.output_ids[-1]
                 sl[i] = r.seq.num_tokens
-            key = self._next_key()  # drawn once: retries re-run identically
-            rids = [r.request_id for r in reqs]
-            largs = self._lora_launch_args(reqs, B)
-            if self.lora is not None:
-                self.metrics.on_adapter_mix(
-                    len({r.adapter for r in reqs if r.adapter is not None}))
-            self._cur_rids = tuple(rids)
-            self._step_ev["programs"].append(f"decode:B{B}:P{P}")
+            launch = self._launcher(
+                "decode", (B, P), lambda: self._build_decode(B, P), rids,
+                (ids, bt, sl), self._next_key(), largs)
             self._step_ev["decode_k"] = 1
-
-        def launch():
-            faults.fire(FAULT_DECODE)
-            with profiler.RecordEvent("serving.decode_step",
-                                      bucket=[B, P]), \
-                    poison_scope(f"serving.decode_step[reqs={rids}]"), \
-                    no_grad(), self._trace_scope():
-                return prog(
-                    self._state, self._k_caches, self._v_caches,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
-                    key, *largs)
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
-        toks, oks, counts, *caches = self.supervisor.run(
-            launch, label="decode_step")
+        toks, oks, counts, *caches = launch()
         with profiler.RecordEvent("serving.fetch"):
             toks = np.asarray(toks)    # host fetch = the honest sync
             self._count_model(counts)
@@ -1351,21 +1386,21 @@ class ServingEngine:
                 written=len(reqs) * self.kv_bytes_per_token,
                 read=sum(r.seq.num_tokens for r in reqs)
                 * self.kv_bytes_per_token)
-            poison = faults.fire(FAULT_NAN)
-            if poison is not None:
-                for i in self._poison_rows(poison, reqs):
-                    oks[i] = False
+            self._fire_nan(oks, reqs)
             for r in reqs:
                 # this step wrote the K/V of each row's input token
                 r.num_computed = r.seq.num_tokens
             self.metrics.on_decode(len(reqs))
         return toks, oks
 
-    @staticmethod
-    def _poison_rows(poison, reqs) -> List[int]:
-        """Normalize a nan_logits fault payload into row indices:
-        callable(reqs) -> rows, True/'all' -> every row, int or list of
-        ints -> those rows (out-of-range ignored)."""
+    def _fire_nan(self, oks, reqs):
+        """The nan_logits fault point, once a decode-side launch: the
+        payload's rows of `oks` go down. A payload is callable(reqs) ->
+        rows, True/'all' -> every row, an int or a list of ints -> those
+        rows (out-of-range ignored)."""
+        poison = faults.fire(FAULT_NAN)
+        if poison is None:
+            return
         if callable(poison):
             rows = poison(reqs)
         elif poison is True or poison == "all":
@@ -1374,7 +1409,9 @@ class ServingEngine:
             rows = [poison]
         else:
             rows = poison
-        return [int(i) for i in rows if 0 <= int(i) < len(reqs)]
+        for i in map(int, rows):
+            if 0 <= i < len(reqs):
+                oks[i] = False
 
     # --------------------------------------- multi-step decode (ISSUE 13)
     def _build_multi_decode(self, B: int, K: int, P: int):
@@ -1388,27 +1425,16 @@ class ServingEngine:
         # tpu-lint: cache-key-ok (per-engine cache; disk tier keys geometry)
         model = self.model
         temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
-        views, split = self._paged_views, self._split_views
-        lora_open = self._lora_trace_scope
 
-        def program(state, kcs, vcs, kss, vss, ids, bt, sl, caps, eos,
-                    key, *largs):
-            st = {k: Tensor(v) for k, v in state.items()}
-            paged = views(kcs, vcs, kss, vss)
-            # the scope spans the whole scan trace: the gathered slot
-            # stacks become loop constants, so the paged gather runs
-            # once per LAUNCH, not once per decode step
-            with lora_open(largs):
-                toks, n_emit, ok, caches, counts = functional_call(
-                    model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
-                    Tensor(caps), Tensor(eos), key,
-                    method=decode_multi, k_steps=K,
-                    temperature=temperature, top_k=top_k, top_p=top_p)
-            return (toks._data, n_emit._data, ok._data, counts) \
-                + split(caches)
+        def body(st, paged, ids, bt, sl, caps, eos, key):
+            toks, n_emit, ok, caches, counts = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
+                Tensor(caps), Tensor(eos), key,
+                method=decode_multi, k_steps=K,
+                temperature=temperature, top_k=top_k, top_p=top_p)
+            return toks._data, n_emit._data, ok._data, counts, caches
 
-        # tpu-lint: cache-key-ok (donation is backend-constant per process)
-        return jax.jit(program, donate_argnums=self._donate)
+        return self._paged_program(body)
 
     def _run_multi_decode(self, reqs: List[Request], caps: List[int],
                           K: int):
@@ -1416,19 +1442,11 @@ class ServingEngine:
         sequence is already extended by caps[i] - 1 slots; returns
         (toks (B, K), n_emit (B,), oks (B,), launch seconds)."""
         with profiler.RecordEvent("serving.build_inputs"):
-            B = _bucket_for(len(reqs), self.batch_buckets)
-            max_pages = max(len(r.seq.pages) for r in reqs)
-            P = _bucket_for(max_pages, self.pages_buckets)
-            prog = self._get_program(
-                ("multi_decode", B, K, P) + self._qkey,
-                lambda: self._build_multi_decode(B, K, P))
+            B, P, bt, rids, largs = self._decode_batch(reqs)
             ids = np.zeros((B,), np.int32)
             sl = np.zeros((B,), np.int32)
             cp = np.zeros((B,), np.int32)
             eos = np.full((B,), -1, np.int32)
-            bt = np.full((B, P), PAD_PAGE, np.int32)
-            seqs = [r.seq for r in reqs]
-            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
             for i, (r, c) in enumerate(zip(reqs, caps)):
                 ids[i] = r.output_ids[-1]
                 # seq_lens counts through the FIRST input token (the
@@ -1438,34 +1456,15 @@ class ServingEngine:
                 cp[i] = c
                 if r.eos_token_id is not None:
                     eos[i] = r.eos_token_id
-            key = self._next_key()  # drawn once: retries re-run identically
-            rids = [r.request_id for r in reqs]
-            largs = self._lora_launch_args(reqs, B)
-            if self.lora is not None:
-                self.metrics.on_adapter_mix(
-                    len({r.adapter for r in reqs if r.adapter is not None}))
-            self._cur_rids = tuple(rids)
-            self._step_ev["programs"].append(
-                f"multi_decode:B{B}:K{K}:P{P}")
+            launch = self._launcher(
+                "multi_decode", (B, K, P),
+                lambda: self._build_multi_decode(B, K, P), rids,
+                (ids, bt, sl, cp, eos), self._next_key(), largs)
             self._step_ev["decode_k"] = K
-
-        def launch():
-            faults.fire(FAULT_MULTI)
-            with profiler.RecordEvent("serving.multi_decode_step",
-                                      bucket=[B, K, P]), \
-                    poison_scope(f"serving.multi_decode_step[reqs="
-                                 f"{rids}]"), no_grad(), \
-                    self._trace_scope():
-                return prog(
-                    self._state, self._k_caches, self._v_caches,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
-                    jnp.asarray(cp), jnp.asarray(eos), key, *largs)
 
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
         t0 = _perf_counter()
-        toks, n_emit, oks, counts, *caches = self.supervisor.run(
-            launch, label="multi_decode_step")
+        toks, n_emit, oks, counts, *caches = launch()
         # the host fetch is the sync: convert
         # BEFORE stamping the launch time so TPOT covers device work
         with profiler.RecordEvent("serving.fetch"):
@@ -1491,10 +1490,7 @@ class ServingEngine:
                 read=reads * self.kv_bytes_per_token)
             for r in reqs:
                 r.num_computed = r.seq.num_tokens
-            poison = faults.fire(FAULT_NAN)
-            if poison is not None:
-                for i in self._poison_rows(poison, reqs):
-                    oks[i] = False
+            self._fire_nan(oks, reqs)
         return toks, n_emit, oks, dt
 
     def _multi_decode_step(self, decodes: List[Request], emitted):
@@ -1610,11 +1606,8 @@ class ServingEngine:
         # tpu-lint: cache-key-ok (per-engine cache; disk tier keys geometry)
         model = self.model
         temperature, top_k, top_p = self.temperature, self.top_k, self.top_p
-        views, split = self._paged_views, self._split_views
 
-        def program(state, kcs, vcs, kss, vss, ids, bt, sl, dl, key):
-            st = {k: Tensor(v) for k, v in state.items()}
-            paged = views(kcs, vcs, kss, vss)
+        def body(st, paged, ids, bt, sl, dl, key):
             logits, caches, counts = functional_call(
                 model, st, Tensor(ids), paged, Tensor(bt),
                 PagedSpan("verify", Tensor(sl), Tensor(dl)),
@@ -1663,10 +1656,9 @@ class ServingEngine:
                 sampled = jax.random.categorical(
                     k_r, jnp.log(res + 1e-30), axis=-1).astype(jnp.int32)
                 toks = jnp.where(jpos < n_acc[:, None], idsn, sampled)
-            return (toks, n_acc, ok, counts) + split(caches)
+            return toks, n_acc, ok, counts, caches
 
-        # tpu-lint: cache-key-ok (donation is backend-constant per process)
-        return jax.jit(program, donate_argnums=self._donate)
+        return self._paged_program(body)
 
     def _extend_slots(self, req: Request, want: int):
         """Grow the request's sequence by up to `want` token slots (the
@@ -1713,20 +1705,13 @@ class ServingEngine:
         sequence is already extended by len(drafts[i]); returns
         (toks (B, K+1), n_acc (B,), oks (B,))."""
         with profiler.RecordEvent("serving.build_inputs"):
-            B = _bucket_for(len(reqs), self.batch_buckets)
+            # lora and a proposer are a refused pair: no LoRA arguments
+            B, P, bt, rids, _ = self._decode_batch(reqs)
             K = _bucket_for(max((len(d) for d in drafts), default=0) or 1,
                             self.spec_buckets)
-            max_pages = max(len(r.seq.pages) for r in reqs)
-            P = _bucket_for(max_pages, self.pages_buckets)
-            prog = self._get_program(("verify", B, K, P) + self._qkey,
-                                     lambda: self._build_verify(B, K, P))
-            S = K + 1
-            ids = np.zeros((B, S), np.int32)
+            ids = np.zeros((B, K + 1), np.int32)
             sl = np.zeros((B,), np.int32)
             dl = np.zeros((B,), np.int32)
-            bt = np.full((B, P), PAD_PAGE, np.int32)
-            seqs = [r.seq for r in reqs]
-            bt[:len(reqs)] = self.allocator.block_table(seqs, P)
             for i, (r, d) in enumerate(zip(reqs, drafts)):
                 ids[i, 0] = r.output_ids[-1]
                 ids[i, 1:1 + len(d)] = d
@@ -1735,29 +1720,15 @@ class ServingEngine:
                 # decode span's convention); the drafts extended
                 # num_tokens past it, so subtract them back out
                 sl[i] = r.seq.num_tokens - len(d)
-            key = self._next_key()  # drawn once: retries re-run identically
-            rids = [r.request_id for r in reqs]
-            self._cur_rids = tuple(rids)
-            self._step_ev["programs"].append(f"verify:B{B}:K{K}:P{P}")
+            launch = self._launcher(
+                "verify", (B, K, P), lambda: self._build_verify(B, K, P),
+                rids, (ids, bt, sl, dl), self._next_key())
             # tokens-per-launch context for the step record: a verify
             # launch can emit up to K drafts + 1 correction/bonus per row
             self._step_ev["decode_k"] = K + 1
 
-        def launch():
-            faults.fire(FAULT_VERIFY)
-            with profiler.RecordEvent("serving.verify_step",
-                                      bucket=[B, K, P]), \
-                    poison_scope(f"serving.verify_step[reqs={rids}]"), \
-                    no_grad(), self._trace_scope():
-                return prog(
-                    self._state, self._k_caches, self._v_caches,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(ids), jnp.asarray(bt), jnp.asarray(sl),
-                    jnp.asarray(dl), key)
-
         t_tr = self.tracer.now_ns() if self.tracer is not None else 0
-        toks, n_acc, oks, counts, *caches = self.supervisor.run(
-            launch, label="verify_step")
+        toks, n_acc, oks, counts, *caches = launch()
         with profiler.RecordEvent("serving.bookkeeping"):
             if self.tracer is not None:
                 t1 = self.tracer.now_ns()
@@ -1778,10 +1749,7 @@ class ServingEngine:
             toks = np.asarray(toks)
             n_acc = np.asarray(n_acc).astype(int)
             self._count_model(counts)
-        poison = faults.fire(FAULT_NAN)
-        if poison is not None:
-            for i in self._poison_rows(poison, reqs):
-                oks[i] = False
+        self._fire_nan(oks, reqs)
         return toks, n_acc, oks
 
     def _spec_decode_step(self, decodes: List[Request], emitted):

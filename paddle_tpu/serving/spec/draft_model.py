@@ -7,10 +7,10 @@ then scores in one verify launch. TPU-shaped like the engine itself:
 the draft model owns its OWN `BlockAllocator` + paged K/V caches in
 the same (num_pages, KVH, page, D) block-table layout the kernels
 expect, and all its device work runs through a small bucketed program
-grid — a per-sequence catch-up CHUNK program (reusing
-`forward_paged_prefill`) plus a BATCHED greedy decode program (reusing
-`forward_paged_decode`) — so drafting never triggers unbounded
-recompilation either.
+grid — a per-sequence catch-up CHUNK program (a "prefill" span of the
+draft model's `paged_forward`, the engine's contract of
+`models/paged.py`) plus a BATCHED greedy decode program (a "decode"
+span) — so drafting never triggers unbounded recompilation either.
 
 Drafting is greedy by design: a deterministic proposal is verified
 with the one-hot rejection rule (accept draft d with probability
@@ -40,6 +40,7 @@ import numpy as np
 from ...core.autograd import no_grad
 from ...core.tensor import Tensor
 from ...jit.api import functional_call
+from ...models.paged import PAGED_ENTRY, PagedSpan
 from ..kv_cache import BlockAllocator, BlocksExhausted, PAD_PAGE
 from .proposer import Proposer
 
@@ -111,9 +112,9 @@ class DraftModelProposer(Proposer):
             "draft_decode", lambda: (len(self.batch_buckets)
                                      * len(self.pages_buckets)))
         self._donate = (1, 2) if jax.default_backend() == "tpu" else ()
-        # draft-model structure rides every draft program key (B1):
-        # the builders close over num_layers as a Python constant, so
-        # two proposers of different depth must never share a program
+        # draft-model structure rides every draft program key (B1): a
+        # program is traced over num_layers cache pairs, so two
+        # proposers of different depth must never share one
         self._dkey = (("layers", self.num_layers),)
         self._states: Dict[int, _DraftSeq] = {}
         # drafting turned itself off (see propose()): the engine keeps
@@ -139,47 +140,50 @@ class DraftModelProposer(Proposer):
     def _get_program(self, key, builder):
         return self.programs.get(key, builder)
 
-    def _build_chunk(self, S, P):
-        """Catch-up chunk: write one span of ONE sequence's history into
-        the draft cache and return the greedy next token (the first
-        draft, when the span reaches the history end)."""
-        L = self.num_layers
-        # tpu-lint: cache-key-ok (per-proposer cache, no disk tier)
-        model = self.model
-
-        def program(state, kcs, vcs, ids, cache_len, live, bt):
+    def _paged_program(self, body):
+        """The frame of the draft model's two programs around
+        `body(st, paged, *inputs) -> (greedy token(s), caches)`: the
+        state wrapped in Tensors, the two cache lists as per-layer
+        (k, v) views and back, jitted with both lists donated."""
+        def program(state, kcs, vcs, *inputs):
             st = {k: Tensor(v) for k, v in state.items()}
-            paged = [(Tensor(kcs[l]), Tensor(vcs[l])) for l in range(L)]
-            logits, caches = functional_call(
-                model, st, Tensor(ids), paged, Tensor(bt),
-                Tensor(cache_len), Tensor(live),
-                method="forward_paged_prefill")
-            tok = jnp.argmax(logits._data[0, 0]).astype(jnp.int32)
-            return (tok, [c[0]._data for c in caches],
-                    [c[1]._data for c in caches])
-
-        # tpu-lint: cache-key-ok (donation is backend-constant per process)
-        return jax.jit(program, donate_argnums=self._donate)
-
-    def _build_decode(self, B, P):
-        """One batched greedy draft step over the draft paged caches."""
-        L = self.num_layers
-        # tpu-lint: cache-key-ok (per-proposer cache, no disk tier)
-        model = self.model
-
-        def program(state, kcs, vcs, ids, bt, sl):
-            st = {k: Tensor(v) for k, v in state.items()}
-            paged = [(Tensor(kcs[l]), Tensor(vcs[l])) for l in range(L)]
-            logits, caches = functional_call(
-                model, st, Tensor(ids), paged, Tensor(bt), Tensor(sl),
-                method="forward_paged_decode")
-            toks = jnp.argmax(logits._data[:, 0, :], axis=-1).astype(
-                jnp.int32)
+            paged = [(Tensor(k), Tensor(v)) for k, v in zip(kcs, vcs)]
+            toks, caches = body(st, paged, *inputs)
             return (toks, [c[0]._data for c in caches],
                     [c[1]._data for c in caches])
 
         # tpu-lint: cache-key-ok (donation is backend-constant per process)
         return jax.jit(program, donate_argnums=self._donate)
+
+    def _build_chunk(self, S, P):
+        """Catch-up chunk: write one span of ONE sequence's history into
+        the draft cache and return the greedy next token (the first
+        draft, when the span reaches the history end)."""
+        # tpu-lint: cache-key-ok (per-proposer cache, no disk tier)
+        model = self.model
+
+        def body(st, paged, ids, cache_len, live, bt):
+            logits, caches, _ = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt),
+                PagedSpan("prefill", Tensor(cache_len), Tensor(live)),
+                method=PAGED_ENTRY)
+            return jnp.argmax(logits._data[0, 0]).astype(jnp.int32), caches
+
+        return self._paged_program(body)
+
+    def _build_decode(self, B, P):
+        """One batched greedy draft step over the draft paged caches."""
+        # tpu-lint: cache-key-ok (per-proposer cache, no disk tier)
+        model = self.model
+
+        def body(st, paged, ids, bt, sl):
+            logits, caches, _ = functional_call(
+                model, st, Tensor(ids), paged, Tensor(bt),
+                PagedSpan("decode", Tensor(sl)), method=PAGED_ENTRY)
+            return jnp.argmax(logits._data[:, 0, :], axis=-1).astype(
+                jnp.int32), caches
+
+        return self._paged_program(body)
 
     # ------------------------------------------------------------- helpers
     def _state_of(self, req) -> _DraftSeq:

@@ -2,7 +2,7 @@
 scan's trip count is PROVABLY bounded under the 512-iteration wedge
 cap — `min(k, <=512)` resolves through the clamp even though `k`
 itself is a runtime value (the committed
-`models/llama.py::forward_paged_decode_multi` idiom), and small static
+`models/paged.py::decode_multi` idiom), and small static
 aranges/lengths pass. Data-driven scan lengths (no static bound at
 all) stay un-flagged by design — XLA scans over sequence lengths are
 normal; A4's wedge class is the statically huge trip count."""

@@ -90,8 +90,8 @@ def both_rotations(monkeypatch, *args):
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
 def test_kv_pages_are_those_apply_rotary_writes(monkeypatch, kv_dtype):
-    """`forward_paged_prefill` (two chunks) and `forward_paged` (decode
-    steps) write the same roped K, lane for lane and bit for bit, as with
+    """Prefill spans (two chunks) and decode spans (`paged_forward`)
+    write the same roped K, lane for lane and bit for bit, as with
     `apply_rotary` in the rotation's place: what the radix cache, page
     transport and the int8 quantise-on-write see has not changed. Run op
     by op (`disable_jit`), where no backend can fuse a multiply into an

@@ -203,7 +203,7 @@ def test_chunked_prefill_identity_and_recompile_bound(model):
 def test_engine_matches_eager_generate_greedy(model):
     """The paged chunk-prefill + decode path reproduces the model's own
     dense-cache greedy generate token-for-token (cross-validates
-    paged_cache_write_range/forward_paged_prefill/paged_attention_decode
+    paged_cache_write_range/the prefill span/paged_attention_decode
     against the concat-cache forward)."""
     rng = np.random.RandomState(3)
     prompt = rng.randint(0, 128, (1, 9))

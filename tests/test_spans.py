@@ -198,21 +198,26 @@ def test_engine_step_spans_nest_and_carry_the_step_number(model, rec, kw,
     assert numbers == list(range(1, len(steps) + 1))
     records = eng.timeline()
     assert records and {r["step"] for r in records} <= set(numbers)
-    # a step's phases come in the order of the work: schedule first, a
-    # launch after its build_inputs, bookkeeping last. A decode launch is
-    # waited for at once (the TPOT sample ends with the tokens on the
-    # host); a chunk and a verify launch store the caches and close the
-    # request's launch span first, as they did before they had spans
+    # a step's phases come in the order of the work: schedule first, the
+    # step's own bookkeeping last, and each launch of the one launch path
+    # as its family's five phases, whole and in the family's order
+    # (`serve_idle_in_*` attribute the device's gaps by them). A decode
+    # and a multi-decode launch are waited for at once (the TPOT sample
+    # ends with the tokens on the host) and keep their books after; a
+    # chunk and a verify launch store the caches and close the request's
+    # launch span first, as they did before they had spans
+    fetch_first = ["serving.fetch", "serving.bookkeeping"]
     for i in steps:
         kids = rec.children(i)
         assert kids[0] == "serving.schedule"
         assert kids[-1] == "serving.bookkeeping"
+        assert kids.count("serving.build_inputs") == \
+            sum(k in LAUNCHES for k in kids)
         for j, k in enumerate(kids):
             if k in LAUNCHES:
-                assert kids[j - 1] == "serving.build_inputs"
-                after = ["serving.fetch"] if "decode" in k else \
-                    ["serving.bookkeeping", "serving.fetch"]
-                assert kids[j + 1:j + 1 + len(after)] == after, k
+                after = fetch_first if "decode" in k else fetch_first[::-1]
+                assert kids[j - 1:j + 4] == \
+                    ["serving.build_inputs", k] + after + ["serving.emit"], k
     # the join to the host-clock recorders: every launch span of the
     # RequestTracer carries a step number the profiler's trace has, and
     # the flight recorder's record of that step names the same program

@@ -41,6 +41,7 @@ BAD_FIXTURES = {
     "bad_a4_decode_loop.py": "A4",
     "bad_a5_purity.py": "A5",
     "bad_b1_cachekey.py": "B1",
+    "bad_b1_forwarded.py": "B1",
     "bad_b2_protocol.py": "B2",
     "bad_b3_faultpoint.py": "B3",
     "bad_b4_refusal.py": "B4",
@@ -57,6 +58,7 @@ GOOD_FIXTURES = [
     "good_a4_decode_loop.py",
     "good_a5_purity.py",
     "good_b1_cachekey.py",
+    "good_b1_forwarded.py",
     "good_b2_protocol.py",
     "good_b3_faultpoint.py",
     "good_b4_refusal.py",
