@@ -27,7 +27,11 @@ is that family's implementation of the spans:
   * decode program, keyed by (batch bucket, block-table-width bucket):
     one batched step through a "decode" span — per-row rope
     positions, `paged_cache_write` of the current token, Pallas
-    `paged_attention_decode` over the block tables — plus sampling;
+    `paged_attention_decode` over the block tables — plus sampling. The
+    engine enqueues the NEXT step's decode launch before it fetches
+    this one's tokens (ISSUE 34, `_plain_decode_step`): its input ids
+    are read from this launch's tokens on the device (`_ids_after`), so
+    the host's work between two launches runs under the device's;
   * VERIFY program (speculative decoding, ISSUE 5), keyed by
     ("verify", batch bucket, draft-length bucket, block-table bucket):
     when a `Proposer` is configured, the decode launch is replaced by
@@ -212,6 +216,40 @@ _LAUNCHES = {
 # 64 leaves an order of magnitude of headroom while still amortizing
 # the per-launch host round trip ~64x.
 MAX_DECODE_STEPS = 64
+
+
+class _DecodeLaunch:
+    """One plain decode launch from its enqueue to its fetch: the rows
+    it carries (`row`: request -> its row; a row that finishes meanwhile
+    stays in it, and its token is never read), each row's length through
+    its input token, the launch's tokens, finiteness flags and model
+    counters still on the device, when it was enqueued (None for a
+    launch enqueued AHEAD: its time runs from the fetch of the launch
+    before it) and its label for the step record of the step that
+    returns its tokens."""
+
+    __slots__ = ("reqs", "row", "dims", "sl", "out", "t0", "t_tr", "label")
+
+    def __init__(self, reqs, dims, sl, out, t0, t_tr, label):
+        self.reqs, self.dims, self.sl, self.out = reqs, dims, sl, out
+        self.row = {r: i for i, r in enumerate(reqs)}
+        self.t0, self.t_tr, self.label = t0, t_tr, label
+
+    @property
+    def ahead(self) -> bool:
+        return self.t0 is None
+
+
+@jax.jit
+def _ids_after(toks, src, fresh):
+    """The (B, 1) input ids of a decode launch enqueued while the one
+    before it is in flight: row i continues row `src[i]` of that launch,
+    whose token is `toks[src[i]]`, on the device and not yet fetched; or,
+    where `src[i]` is -1, has just finished its prefill and starts from
+    `fresh[i]`, the first token the host already holds. `src` and `fresh`
+    are int32 arrays built with NumPy."""
+    took = jnp.take(toks, jnp.maximum(src, 0), mode="clip")
+    return jnp.where(src >= 0, took, fresh)[:, None]
 
 
 def _bucket_for(value: int, buckets: List[int]) -> int:
@@ -685,6 +723,12 @@ class ServingEngine:
         # RequestTracer's launch spans (the join between the clocks)
         self._step_no = 0
         self._last_launch_s: Optional[float] = None
+        # the plain decode launch enqueued ahead and not yet fetched
+        # (`_plain_decode_step`), and when the last decode launch's
+        # tokens reached the host (engine timer, tracer clock): a launch
+        # enqueued ahead is timed from there
+        self._flight: Optional[_DecodeLaunch] = None
+        self._fetched = (0.0, 0)
 
         # the pool: one list over the layers for each array of the
         # model's cache entry (Llama: K pages, V pages and, for int8,
@@ -989,6 +1033,12 @@ class ServingEngine:
         return True
 
     def has_work(self) -> bool:
+        """Whether another `step()` has anything to do: a request is
+        queued, prefilling or decoding. A decode launch enqueued ahead
+        (`step()`) never outlives its rows (the step that ends the last
+        of them drops it, and `vacate`, `shutdown` and a failure take it
+        back), and what it holds is a token NOT yet returned, never one
+        held back: there is nothing to flush."""
         return self.scheduler.has_work()
 
     # ---------------------------------------------------- TP placement
@@ -1352,46 +1402,85 @@ class ServingEngine:
 
         return self._paged_program(body)
 
-    def _run_decode(self, reqs: List[Request]):
+    def _enqueue_decode(self, reqs: List[Request],
+                        prev: Optional[_DecodeLaunch] = None):
+        """Enqueue one decode launch over `reqs`, each with the slot of
+        its input token reserved, and return it in flight: nothing is
+        waited for. With `prev`, the launch before it whose tokens have
+        not been fetched, a row that rode in `prev` takes its input id
+        from `prev`'s tokens where they are, on the device; any other
+        row's last token is on the host. The caches chain from launch to
+        launch as device futures, so they are stored here."""
         with profiler.RecordEvent("serving.build_inputs"):
             B, P, bt, rids, largs = self._decode_batch(reqs)
-            ids = np.zeros((B, 1), np.int32)
+            fresh = np.zeros((B,), np.int32)
+            src = np.full((B,), -1, np.int32)
             sl = np.zeros((B,), np.int32)
+            row = {} if prev is None else prev.row
             for i, r in enumerate(reqs):
-                ids[i, 0] = r.output_ids[-1]
                 sl[i] = r.seq.num_tokens
+                if r in row:
+                    src[i] = row[r]
+                else:
+                    fresh[i] = r.output_ids[-1]
+            ids = fresh[:, None] if prev is None \
+                else _ids_after(prev.out[0], src, fresh)
             launch = self._launcher(
                 "decode", (B, P), lambda: self._build_decode(B, P), rids,
                 (ids, bt, sl), self._next_key(), largs)
-            self._step_ev["decode_k"] = 1
 
-        t_tr = self.tracer.now_ns() if self.tracer is not None else 0
-        t0 = _perf_counter()
+        t_tr = t0 = None
+        if prev is None:
+            t_tr = self.tracer.now_ns() if self.tracer is not None else 0
+            t0 = _perf_counter()
         toks, oks, counts, *caches = launch()
+        self._store_caches(*caches)
+        # the label goes to the record of the step that FETCHES the launch
+        return _DecodeLaunch(reqs, (B, P), sl, (toks, oks, counts), t0, t_tr,
+                             self._step_ev["programs"].pop())
+
+    def _fetch_decode(self, flight: _DecodeLaunch, live: List[Request]):
+        """Wait for `flight` and keep its books over `live`, those of its
+        rows that are still decoding: a row that finished while the
+        launch was in flight (its end was read from the token before, it
+        was aborted, it expired) counts nowhere. Returns (request, token,
+        finite) for each of `live`. ONE fetch brings tokens, flags and model
+        counters; the TPOT sample and the requests' launch spans end
+        there, and begin where the launch was enqueued or, for a launch
+        enqueued ahead, where the last launch's tokens arrived: the time
+        these tokens took to follow those."""
         with profiler.RecordEvent("serving.fetch"):
-            toks = np.asarray(toks)    # host fetch = the honest sync
+            # host fetch = the honest sync
+            toks, oks, counts = jax.device_get(flight.out)
             self._count_model(counts)
-            # the TPOT sample and the request's launch span end here,
-            # with the tokens on the host
-            self._last_launch_s = _perf_counter() - t0
+            t1 = _perf_counter()
             t1_tr = self.tracer.now_ns() if self.tracer is not None else 0
-            oks = np.asarray(oks)[:len(reqs)].copy()
+            t0, t0_tr = self._fetched if flight.ahead \
+                else (flight.t0, flight.t_tr)
+            self._last_launch_s = t1 - t0
+            self._fetched = (t1, t1_tr)
+            oks = np.array(oks[:len(flight.reqs)])
         with profiler.RecordEvent("serving.bookkeeping"):
-            self._tr_launch(rids, "decode_step", t_tr, t1_tr,
-                            batch=len(reqs), bucket=[B, P], k=1)
-            self._store_caches(*caches)
-            # bytes-moved accounting: this step wrote one token per live
+            B, P = flight.dims
+            self._step_ev["programs"].append(flight.label)
+            self._step_ev["decode_k"] = 1
+            self._step_ev["decode_ahead"] = flight.ahead
+            self._tr_launch([r.request_id for r in live], "decode_step",
+                            t0_tr, t1_tr, batch=len(live), bucket=[B, P],
+                            k=1)
+            self._fire_nan(oks, flight.reqs)
+            context = 0
+            for r in live:
+                # the launch wrote the K/V of the row's input token
+                r.num_computed = int(flight.sl[flight.row[r]])
+                context += r.num_computed
+            # bytes-moved accounting: the launch wrote one token per live
             # row and the attention kernel read every live token's K/V
             self.metrics.on_kv_bytes(
-                written=len(reqs) * self.kv_bytes_per_token,
-                read=sum(r.seq.num_tokens for r in reqs)
-                * self.kv_bytes_per_token)
-            self._fire_nan(oks, reqs)
-            for r in reqs:
-                # this step wrote the K/V of each row's input token
-                r.num_computed = r.seq.num_tokens
-            self.metrics.on_decode(len(reqs))
-        return toks, oks
+                written=len(live) * self.kv_bytes_per_token,
+                read=context * self.kv_bytes_per_token)
+            self.metrics.on_decode(len(live))
+        return [(r, toks[flight.row[r]], oks[flight.row[r]]) for r in live]
 
     def _fire_nan(self, oks, reqs):
         """The nan_logits fault point, once a decode-side launch: the
@@ -1574,7 +1663,7 @@ class ServingEngine:
                                               dt)
             else:
                 # the isolation path's solo launches counted decode_tokens
-                # inside _run_decode; record their row count too (one row
+                # in _fetch_decode; record their row count too (one row
                 # per solo launch, k=1, no timing) or the
                 # tokens-per-launch ratio would keep a numerator with no
                 # denominator and read ABOVE its true value after any
@@ -1969,6 +2058,7 @@ class ServingEngine:
         """Unrecoverable: drain to a serializable snapshot and raise
         EngineFailure. The engine refuses further work afterwards."""
         self.metrics.on_engine_failure()
+        self._settle()
         # stamp the FAILING (partial) step into the flight recorder
         # before the snapshot captures the ring — the postmortem's
         # last record is the step that died, not merely the one before
@@ -1995,12 +2085,33 @@ class ServingEngine:
     def step(self):
         """One engine iteration: cancellation sweep, schedule, run
         prefill chunks, run the batched decode step. Returns
-        [(request_id, token)] in emission order (empty when idle).
+        [(request_id, token)] in emission order (empty when idle),
+        every token exactly once.
+
+        The plain decode family runs ONE LAUNCH AHEAD (ISSUE 34;
+        SERVING.md "The decode loop runs one launch ahead"): before this
+        step fetches its decode launch's tokens it enqueues the next
+        step's launch, which reads them on the device. The contract:
+        (1) every request's tokens are those of the serial order;
+        (2) a step returns its own launch's tokens, so between two calls
+        at most one decode launch is in flight and it holds only tokens
+        not returned yet; (3) a row known to end (by length, an abort,
+        a deadline) is left out of the launch ahead, and a row that ends
+        only by the token read (`eos_token_id`, not finite) rode in it
+        for nothing: that token is never emitted, counted or donated;
+        (4) the launch ahead is enqueued only over a quiet step (every
+        slot there without a preemption or a page copy; never with a
+        proposer or `decode_steps > 1`), else the step is the serial
+        order's; (5) `vacate`, `shutdown`, the prefix calls and
+        the drain of a failure take the launch in flight back first, and
+        `snapshot` holds exactly what has been returned.
 
         Failure semantics per launch: transients retried by the
         supervisor; a poison failure quarantines the offending
         request(s) and the step continues; anything else drains to a
-        snapshot and raises EngineFailure."""
+        snapshot and raises EngineFailure. A failure of the launch
+        enqueued ahead is handled after this step's tokens are
+        emitted (`_plain_decode_step`)."""
         if self.failed:
             raise EngineFailure("engine has failed; resume from "
                                 "last_snapshot", snapshot=self.last_snapshot)
@@ -2051,7 +2162,7 @@ class ServingEngine:
 
         decodes = [r for r in sched.decodes
                    if r.state is not RequestState.FINISHED]
-        if decodes:
+        if decodes or self._flight is not None:
             for req in decodes:
                 self._apply_copies(req.pending_copies)
                 req.pending_copies = []
@@ -2130,6 +2241,9 @@ class ServingEngine:
             # verify launch, 0 for no decode-side launch this step
             "decode_k": int(self._step_ev.get("decode_k", 0))
             if n_decode else 0,
+            # whether the step's plain decode launch had been enqueued
+            # by the step before, ahead of that step's fetch (ISSUE 34)
+            "decode_ahead": bool(self._step_ev.get("decode_ahead", False)),
             "tokens_out": int(n_emitted),
             "preempted": int(c["requests_preempted"]
                              - pre["requests_preempted"]),
@@ -2164,43 +2278,132 @@ class ServingEngine:
         return self.recorder.records()
 
     def _plain_decode_step(self, decodes: List[Request], emitted):
-        """One batched single-token decode launch + emission (the
-        non-speculative path, unchanged semantics)."""
-        degraded = False
+        """The plain decode family's part of a step: return the tokens
+        of THIS step's launch over `decodes`, with the next step's
+        launch enqueued before they are fetched where the step is quiet.
+
+        This step's launch is the one the last step enqueued ahead, or
+        is enqueued now (the serial order: the first decode step, and
+        any step after one that was not quiet). Then, BEFORE its tokens
+        are fetched, `_launch_ahead` reserves the next slot of every row
+        that goes on and enqueues the next launch, its input ids taken
+        on the device from this launch's tokens; the host fetches, keeps
+        its books and emits for this launch while the device runs that
+        one. One launch ahead, never more.
+
+        A row in `decodes` is a row of the launch (the scheduler adds to
+        the decode batch only what `_launch_ahead` saw); a row of the
+        launch that is no longer in `decodes` finished while it was in
+        flight, and its token is dropped unread.
+
+        Failures: of this step's launch (enqueue or fetch) as ever, a
+        poison failure isolates the rows in solo launches and anything
+        else drains to a snapshot. Of the launch ahead: its slots are
+        given back, this step's tokens are emitted first, and then
+        anything but poison drains to a snapshot, with those tokens in
+        it; after a poison failure the next step enqueues its launch in
+        the serial order and isolates there if it fails again."""
+        flight, self._flight = self._flight, None
+        if not decodes:
+            # the boundary cancelled every row of the launch in flight:
+            # it is dropped unread (each row gave its pages back whole)
+            return
+        ahead = ahead_exc = None
         try:
-            toks, oks = self._run_decode(decodes)
+            if flight is None:
+                flight = self._enqueue_decode(decodes)
+            try:
+                ahead = self._launch_ahead(flight)
+            except Exception as exc:   # noqa: BLE001
+                ahead_exc = exc
+            rows = self._fetch_decode(flight, decodes)
         except Exception as exc:   # noqa: BLE001
-            if classify_failure(exc) == POISON:
-                # unattributed poison (a FloatingPointError raised
-                # by an eager/dispatch NaN hook instead of the
-                # in-graph flags): isolate by running rows solo
-                toks, oks = self._isolate_poisoned(decodes)
-                degraded = True
-            else:
+            # what was enqueued ahead is taken back before anything else
+            self._flight, ahead, flight = ahead, None, None
+            self._settle()
+            if classify_failure(exc) != POISON:
                 self._fail(exc)
+            # unattributed poison (a FloatingPointError raised by an
+            # eager/dispatch NaN hook instead of the in-graph flags):
+            # isolate by running rows solo
+            rows = list(zip(decodes, *self._isolate_poisoned(decodes)))
         with profiler.RecordEvent("serving.emit"):
             n0 = len(emitted)
-            for i, req in enumerate(decodes):
-                if not oks[i]:
+            for req, tok, ok in rows:
+                if not ok:
                     self._quarantine(req)
                     continue
-                reason = self._emit(req, int(toks[i]), emitted)
+                reason = self._emit(req, int(tok), emitted)
                 if reason is not None:
                     self.scheduler.finish(req, reason)
                     self._on_finished(req)
-            if not degraded:
+            if flight is not None:
                 # TPOT sample: launch wall seconds / tokens emitted, so
                 # the per-token percentiles stay comparable across K
                 # (ISSUE 13)
                 self.metrics.on_decode_launch(1, len(decodes),
                                               len(emitted) - n0,
-                                              self._last_launch_s)
+                                              self._last_launch_s,
+                                              ahead=flight.ahead)
             else:
                 # solo isolation launches counted decode_tokens in
-                # _run_decode; keep the tokens-per-launch denominator
+                # _fetch_decode; keep the tokens-per-launch denominator
                 # honest (no TPOT sample — solo timings aren't a batch
                 # launch's)
                 self.metrics.on_decode_launch(1, len(decodes), 0, None)
+        if ahead_exc is not None and \
+                classify_failure(ahead_exc) != POISON:
+            self._fail(ahead_exc)
+        # a launch nobody rides any more (every row's end was read from
+        # this step's tokens) is dropped here, unread
+        if ahead is not None and any(r.reserved_ahead for r in ahead.reqs):
+            self._flight = ahead
+
+    def _launch_ahead(self, flight: _DecodeLaunch):
+        """Enqueue the launch after `flight` before `flight`'s tokens
+        are fetched, if the next step is quiet by what can be seen now;
+        returns it, or None where the serial order stands.
+
+        Its rows are what the next `schedule()` would decode: the
+        scheduler's decode batch less every row known to end first (a
+        row of `flight` whose pending token is its last by length; a row
+        already aborted or past its deadline, which the next boundary
+        cancels). What only the pending token can tell (an
+        `eos_token_id` hit, a row not finite) is not waited for: such a
+        row rides along and its token of that launch is dropped. Quiet
+        means: each row's slot is there without preempting anybody or
+        copying a page, at most for a cached prefix nobody uses
+        (`Scheduler.reserve_ahead`), so nothing the next `schedule()`
+        would have decided about live work is decided here. Raises what
+        the enqueue raises, with the slots given back."""
+        now = self._now()
+        rows = [r for r in self.scheduler.running
+                if not r.aborted
+                and not (r.deadline is not None and now >= r.deadline)
+                and not (r in flight.row and r.remaining_new_tokens() <= 1)]
+        if not rows or not self.scheduler.reserve_ahead(rows):
+            return None
+        try:
+            return self._enqueue_decode(rows, flight)
+        except Exception:
+            self.scheduler.release_ahead(rows)
+            raise
+
+    def _settle(self):
+        """Leave nothing in flight: called by whatever touches the pool,
+        the prefix tree or the requests from outside `step()`, so that
+        it sees the state the serial order has after the last returned
+        token. The launch enqueued ahead is TAKEN BACK: its rows' slots
+        are given back and its tokens are never read, so each row's next
+        launch reads the row's last RETURNED token again and computes
+        the same one. The device still runs the launch; what it writes
+        lies beyond every row's computed length (never donated) and the
+        next launch of the row, or of whoever holds the page by then,
+        writes over it, since the device runs launches in the order they
+        were enqueued."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self.scheduler.release_ahead(flight.reqs)
 
     def _isolate_poisoned(self, reqs: List[Request]):
         """Degraded mode for an UNATTRIBUTED poison failure of a decode
@@ -2215,14 +2418,15 @@ class ServingEngine:
         oks = np.ones((len(reqs),), bool)
         for i, req in enumerate(reqs):
             try:
-                t, o = self._run_decode([req])
+                [(_, t, o)] = self._fetch_decode(
+                    self._enqueue_decode([req]), [req])
             except Exception as exc:   # noqa: BLE001
                 if classify_failure(exc) == POISON:
                     oks[i] = False
                     continue
                 self._fail(exc)
-            toks[i] = int(t[0])
-            oks[i] = bool(o[0])
+            toks[i] = int(t)
+            oks[i] = bool(o)
         return toks, oks
 
     def _retain(self, req: Request):
@@ -2260,7 +2464,11 @@ class ServingEngine:
         the flight-recorder ring — the cross-process worker's
         heartbeats ship a snapshot ~20x/s and the supervisor only reads
         the request records, so the postmortem payload stays on the
-        drain/failure snapshots where it is read."""
+        drain/failure snapshots where it is read. A decode launch in
+        flight (ISSUE 34) is neither in the snapshot nor disturbed by
+        it: a record holds the tokens `step()` has returned, the launch
+        has returned none, and whoever resumes computes its token
+        again."""
         now = self._now()
         recs = []
         for req in self.requests.values():
@@ -2388,7 +2596,10 @@ class ServingEngine:
         abort/expired metrics for the same reason) and drop the radix
         tree. Pure host bookkeeping, so it works on a FAILED engine —
         the fleet calls this on a dead replica's pool and then asserts
-        full page/refcount reclamation. Returns pages freed."""
+        full page/refcount reclamation. A decode launch in flight is
+        taken back first: its token was never returned, and whoever
+        adopts the request computes it again. Returns pages freed."""
+        self._settle()
         before = self.allocator.num_free
         for req in list(self.requests.values()):
             if req.state is not RequestState.FINISHED:
@@ -2435,7 +2646,9 @@ class ServingEngine:
         """Drop every cached prefix (the tree's page refs release);
         returns the number of pages returned to the free list. With no
         live requests this brings allocator occupancy back to zero —
-        the drain-reclamation check in the acceptance test."""
+        the drain-reclamation check in the acceptance test. Takes a
+        decode launch in flight back first (`_settle`)."""
+        self._settle()
         if self.radix is None:
             return 0
         return self.radix.clear()
@@ -2449,7 +2662,9 @@ class ServingEngine:
         mailbox frames. promote_budget=0 pins the walk to the device
         tier — a pull must never charge this engine's own prefill
         budget or its device pool for a sibling's benefit. The LRU bump
-        is deliberate: a pulled prefix is hot."""
+        is deliberate: a pulled prefix is hot. Takes a decode launch in
+        flight back first (`_settle`)."""
+        self._settle()
         if self.radix is None:
             return 0, []
         pages, m = self.radix.match(tokens, promote_budget=0)
@@ -2466,7 +2681,9 @@ class ServingEngine:
         prefix). Degrades to 0 — never raises — on a corrupt payload,
         a dry device pool, or a span the tree already holds: a failed
         pull just means the prefix recomputes, exactly the spill tier's
-        fallback contract. Returns pages newly adopted."""
+        fallback contract. Takes a decode launch in flight back first
+        (`_settle`). Returns pages newly adopted."""
+        self._settle()
         if self.radix is None or not payloads:
             return 0
         n = min(len(payloads) * self.page_size,
@@ -2510,7 +2727,9 @@ class ServingEngine:
         prefill-role pool can never fill with spans that already live
         on decode workers. `drop=True` frees the deepest childless
         nodes of the span outright (strict accounting — tests assert
-        exact reclamation with it). Returns pages demoted/freed."""
+        exact reclamation with it). Takes a decode launch in flight
+        back first (`_settle`). Returns pages demoted/freed."""
+        self._settle()
         if self.radix is None:
             return 0
         chain = [child for child, _ in self.radix._walk_prefix(tokens)]
@@ -2557,6 +2776,7 @@ class ServingEngine:
         return out
 
     def shutdown(self):
+        self._settle()
         if self.proposer is not None:
             self.proposer.reset()
         self.metrics.unregister()

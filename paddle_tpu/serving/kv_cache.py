@@ -206,6 +206,12 @@ class BlockAllocator:
         seq.num_tokens = pos + 1
         return copies
 
+    def append_copies(self, seq: KVSequence) -> bool:
+        """Whether the next `append_token` would copy a page (the slot
+        it writes lies in a page shared with a fork)."""
+        j = seq.num_tokens // self.page_size
+        return j < len(seq.pages) and self._refs[seq.pages[j]] > 1
+
     def truncate_sequence(self, seq: KVSequence, num_tokens: int):
         """Shrink `seq` to its first `num_tokens` tokens, releasing the
         pages that covered only the dropped tail — the speculative-

@@ -81,6 +81,10 @@ class ServingMetrics:
             "decode_launches": 0,          # decode-side program launches
             "decode_launch_steps": 0,      # K summed over those launches
             "decode_launch_rows": 0,       # live rows summed over them
+            # plain decode launches enqueued BEFORE the launch before
+            # them was fetched (ISSUE 34): over decode_launches, the
+            # share of launches whose host work ran under the device's
+            "decode_launches_ahead": 0,
             "multi_decode_slot_shortfall": 0,  # K-1 slots the pool denied
             # --- multi-LoRA serving (ISSUE 15) ---
             # registry lifecycle (AdapterRegistry.bind_counters homes
@@ -256,13 +260,16 @@ class ServingMetrics:
         self.counters["decode_tokens"] += num_tokens
 
     def on_decode_launch(self, k: int, rows: int, tokens: int,
-                         seconds: Optional[float] = None):
+                         seconds: Optional[float] = None,
+                         ahead: bool = False):
         """One decode-side program launch (plain K=1 or multi-step K)
         over `rows` live rows: `tokens` tokens were emitted in
         `seconds` of launch wall time. The TPOT sample divides the
         launch latency by the tokens it emitted — the per-token number
-        that stays comparable across K."""
+        that stays comparable across K. `ahead`: the launch was
+        enqueued before the launch before it was fetched."""
         self.counters["decode_launches"] += 1
+        self.counters["decode_launches_ahead"] += int(ahead)
         self.counters["decode_launch_steps"] += int(k)
         self.counters["decode_launch_rows"] += int(rows)
         if seconds is not None and seconds > 0 and tokens > 0:

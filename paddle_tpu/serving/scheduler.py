@@ -117,6 +117,10 @@ class Request:
         self.output_ids: List[int] = []
         self.seq = None                 # KVSequence while holding pages
         self.pending_copies = []        # CoW copies due before this step
+        # the slot of the NEXT step's decode launch is already reserved
+        # (`Scheduler.reserve_ahead`: the engine enqueued that launch
+        # before this step's tokens were fetched)
+        self.reserved_ahead = False
         self.num_preemptions = 0
         self.finish_reason: Optional[str] = None
         self.arrival = self.request_id  # FCFS key (monotonic ids)
@@ -332,6 +336,7 @@ class Scheduler:
         self._donate(victim)
         self.allocator.free_sequence(victim.seq)
         victim.seq = None
+        victim.reserved_ahead = False
         victim.state = RequestState.WAITING
         victim.num_computed = 0
         victim.cached_tokens = 0
@@ -341,17 +346,64 @@ class Scheduler:
         self.waiting.appendleft(victim)
         return victim
 
+    # ---- slots reserved a step ahead -----------------------------------
+    def reserve_ahead(self, reqs: List[Request]) -> bool:
+        """Reserve NOW the slot each of `reqs` writes in the decode
+        launch after the one in flight: what `schedule()` step 1 would
+        do for them at the next step, done early so that the engine can
+        enqueue that launch before it has fetched this one's tokens.
+        Quiet or not at all: nobody is preempted and no page is copied
+        for it. A dry free list is refilled from cached prefixes that no
+        request uses, the ladder's first rung (as `_extend_slots` takes
+        it for draft slots): a pool full of donated prefixes is a
+        server's steady state, and dropping one decides nothing about
+        live work. Where a slot needs more than that, what was reserved
+        is given back and the answer is False: the next `schedule()`
+        then reserves in its own order. It finds `reserved_ahead` on
+        each request served here and appends nothing for it."""
+        done: List[Request] = []
+        for req in reqs:
+            quiet = not self.allocator.append_copies(req.seq)
+            while quiet:
+                try:
+                    self.allocator.append_token(req.seq)
+                    break
+                except BlocksExhausted:
+                    quiet = self._reclaim(1)
+            if not quiet:
+                self.release_ahead(done)
+                return False
+            req.reserved_ahead = True
+            done.append(req)
+        return True
+
+    def release_ahead(self, reqs: List[Request]):
+        """Give back the slots `reserve_ahead` took for those of `reqs`
+        that still hold one (a request that finished meanwhile gave its
+        pages back whole)."""
+        for req in reqs:
+            if req.reserved_ahead:
+                req.reserved_ahead = False
+                self.allocator.truncate_sequence(
+                    req.seq, req.seq.num_tokens - 1)
+
     # ---- the per-step decision ------------------------------------------
     def schedule(self) -> ScheduleStep:
         preempted: List[Request] = []
 
         # 1. guarantee every decoding request can append this step's
         #    token (may cross a page boundary); on pressure evict cached
-        #    prefixes first, then the newest in-flight request.
+        #    prefixes first, then the newest in-flight request. A request
+        #    whose slot `reserve_ahead` took already has it.
         survivors: List[Request] = []
         for req in list(self.running):
             if req not in self.running:
                 continue               # evicted by an earlier iteration
+            if req.reserved_ahead:
+                req.reserved_ahead = False
+                req.pending_copies = []
+                survivors.append(req)
+                continue
             while True:
                 try:
                     copies = self.allocator.append_token(req.seq)
@@ -476,6 +528,7 @@ class Scheduler:
                 self._donate(req)
             self.allocator.free_sequence(req.seq)
             req.seq = None
+        req.reserved_ahead = False
         req.state = RequestState.FINISHED
         req.finish_reason = reason
 

@@ -505,3 +505,25 @@ def test_paged_programs_leave_the_projection_weights_where_they_are(
         assert "paged_attention_decode" in text
     assert _weight_relayouts(text) == []
     assert _stray_weight_readers(text) == []
+
+
+def test_the_ids_of_a_launch_ahead_are_built_from_rows_alone(for_chip,
+                                                             one_chip):
+    """ISSUE 34: the decode launch enqueued before the last one is fetched
+    takes its input ids from that launch's tokens on the device, in a
+    program of its own BESIDE the decode program (which the case above
+    holds as it was: the same builder, the same inputs). That program
+    sees one value a row (the gather pads its indices to one tile of
+    1,024): no weight, no pool, nothing 64 bits wide (an index computed
+    with `jnp` under the package's x64 mode is emulated on the device)."""
+    from paddle_tpu.serving.engine import _ids_after
+    B = 64                               # serve-offline-decode's bucket
+    row = _sds((B,), jnp.int32, one_chip)
+    compiled = _ids_after.lower(row, row, row).compile()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert (out.shape, out.dtype) == ((B, 1), jnp.int32)
+    text = compiled.as_text()
+    assert "s64[" not in text and "u64[" not in text
+    sizes = [n for body in _hlo_computations(text).values()
+             for _, n, _, _ in body if n is not None]
+    assert sizes and max(sizes) <= 1024
