@@ -44,7 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_bshd", "flash_attention_varlen_bshd",
-           "flashmask_attention_bshd"]
+           "flashmask_attention_bshd", "flash_attention_chunk_gqa",
+           "chunk_gqa_unsupported_reason"]
 
 _INTERPRET_CACHE = [None]
 
@@ -99,7 +100,7 @@ def _flashmask_block_mask(s, qi, ki, block_q, block_k, q_offset, fm_blk,
 
 def _apply_masks(s, qi, ki, *, block_q, block_k, q_offset, causal,
                  segq_blk=None, segk_blk=None, posq_blk=None, posk_blk=None,
-                 fm_blk=None, fm_causal=True, fm_cols=0):
+                 fm_blk=None, fm_causal=True, fm_cols=0, window=None):
     if causal and segq_blk is None:
         s = _causal_block_mask(s, qi, ki, block_q, block_k, q_offset)
     if segq_blk is not None:
@@ -111,6 +112,12 @@ def _apply_masks(s, qi, ki, *, block_q, block_k, q_offset, causal,
             # q/k lengths differ
             allow = jnp.logical_and(allow,
                                     posk_blk[None, :] <= posq_blk[:, None])
+        if window is not None:
+            # a window layer: the last `window` positions, the query's own
+            # among them
+            allow = jnp.logical_and(
+                allow,
+                posk_blk[None, :] > posq_blk[:, None] - np.int32(window))
         s = jnp.where(allow, s, NEG_INF)
     if fm_cols:
         s = _flashmask_block_mask(s, qi, ki, block_q, block_k, q_offset,
@@ -141,7 +148,7 @@ def _unpack_refs(refs, n_fixed, use_seg, fm_cols):
 
 def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
                        segq_blk, segk_blk, posq_blk=None, posk_blk=None,
-                       fm_blk=None, fm_causal=True, fm_cols=0):
+                       fm_blk=None, fm_causal=True, fm_cols=0, window=None):
     """Whether this (q block, k block) tile can contain any unmasked score
     (cheap bound checks -> pl.when skips the matmuls entirely)."""
     if causal and segq_blk is None:
@@ -164,6 +171,12 @@ def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
             contributes = jnp.logical_and(
                 contributes,
                 jnp.logical_not(jnp.logical_and(one_seq, all_future)))
+        if window is not None:
+            # every key lies behind every query's window
+            all_past = jnp.max(posk_blk) <= \
+                jnp.min(posq_blk) - np.int32(window)
+            contributes = jnp.logical_and(contributes,
+                                          jnp.logical_not(all_past))
     if fm_cols == 1 and fm_causal and fm_blk is not None:
         # document mask: every row/col masked iff first q row >= max(start)
         q0 = qi * block_q
@@ -173,7 +186,7 @@ def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
 
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
-                use_seg, fm_causal, fm_cols):
+                use_seg, fm_causal, fm_cols, window=None):
     sm_scale = np.float32(sm_scale)  # strong f32: x64 mode makes bare
     # python/np floats f64, which Mosaic cannot store into f32 refs
     (q_ref, k_ref, v_ref), segq_ref, segk_ref, fm_ref, rest = _unpack_refs(
@@ -182,6 +195,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     masked_rows = use_seg or fm_cols  # rows may see no valid key yet
+    windowed = {} if window is None else {"window": window}
 
     @pl.when(ki == 0)
     def _init():
@@ -198,7 +212,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
         qi, ki, block_q=block_q, block_k=block_k, q_offset=q_offset,
         causal=causal, segq_blk=segq_blk, segk_blk=segk_blk,
         posq_blk=posq_blk, posk_blk=posk_blk, fm_blk=fm_blk,
-        fm_causal=fm_causal, fm_cols=fm_cols)
+        fm_causal=fm_causal, fm_cols=fm_cols, **windowed)
 
     @pl.when(contributes)
     def _step():
@@ -212,7 +226,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
                          q_offset=q_offset, causal=causal, segq_blk=segq_blk,
                          segk_blk=segk_blk, posq_blk=posq_blk,
                          posk_blk=posk_blk, fm_blk=fm_blk,
-                         fm_causal=fm_causal, fm_cols=fm_cols)
+                         fm_causal=fm_causal, fm_cols=fm_cols, **windowed)
         m_prev = m_ref[:, :1]                      # (bq, 1), lanes equal
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
@@ -273,12 +287,15 @@ def _extra_in_specs(B, H, Sq, Sk, block_q, block_k, use_seg, fm_cols, fm_heads,
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
-         fm_causal=True, H=1):
+         fm_causal=True, H=1, window=None):
     """(BH, Sq, D) x (BH, Sk, D)^2 -> out (BH, Sq, D), lse (BH, Sq) f32.
 
     seg: optional (segq (B,2,Sq), segk (B,2,Sk)) int32 [segment id;
     causal position-within-sequence] rows.
-    fm: optional (B*Hm, C, Sk) flashmask bounds."""
+    fm: optional (B*Hm, C, Sk) flashmask bounds.
+    window: optional int, with `seg` and `causal`: a query also sees no
+    key `window` or more positions behind it; blocks that lie wholly
+    behind are skipped. Forward only (`flash_attention_chunk_gqa`)."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     nq = Sq // block_q
@@ -290,7 +307,8 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, nk=nk, q_offset=Sk - Sq, use_seg=use_seg,
-        fm_causal=fm_causal, fm_cols=fm_cols)
+        fm_causal=fm_causal, fm_cols=fm_cols,
+        **({} if window is None else {"window": int(window)}))
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, _I0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),
@@ -777,6 +795,52 @@ def flash_attention_varlen_bshd(q, k, v, q_segment_ids, kv_segment_ids,
                           float(sm_scale), bool(causal), int(block_q),
                           int(block_k), int(H))
     return _from_bhsd(out, B, H, Sq, D)
+
+
+def chunk_gqa_unsupported_reason(s, t, heads, kv_heads, d, dtype):
+    """Why `flash_attention_chunk_gqa` refuses a chunk of `s` queries of
+    `heads` heads over `t` keys of `kv_heads`, or None: the kernel's
+    tiling rule over the folded query axis."""
+    if heads % kv_heads:
+        return f"{heads} heads are not whole groups of {kv_heads} KV heads"
+    return unsupported_reason((1, s * (heads // kv_heads), 1, d),
+                              (1, t, 1, d), dtype)
+
+
+def flash_attention_chunk_gqa(q, k, v, q_positions, kv_positions, *,
+                              sm_scale=None, window=None):
+    """ONE sequence's prefill chunk over its gathered keys, grouped-query
+    heads, forward only: q (S, H, D) at `q_positions` (S,), k and v
+    (T, KVH, D) at `kv_positions` (T,); a query sees the keys at
+    positions <= its own and, with `window`, > its own - window. Returns
+    (S, H, D).
+
+    The G = H / KVH query heads of a KV head are laid along the query
+    axis, (KVH, G * S, D) against (KVH, T, D), so K and V are never
+    repeated G times (at 128 query heads over 8 that is 16 x a 13,056-key
+    table a layer). Positions are data, as in the varlen form: key
+    blocks wholly in a query block's future, or wholly behind its
+    window, are skipped (`_block_contributes`)."""
+    S, H, D = q.shape
+    T, KVH, _ = k.shape
+    G = H // KVH
+    why = chunk_gqa_unsupported_reason(S, T, H, KVH, D, q.dtype)
+    if why is not None:
+        raise ValueError(why)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(D)
+    qf = jnp.transpose(q.reshape(S, KVH, G, D), (1, 2, 0, 3))
+    qf = qf.reshape(KVH, G * S, D)
+    kf, vf = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
+    qpos = jnp.tile(q_positions.astype(jnp.int32), G)
+    kpos = kv_positions.astype(jnp.int32)
+    segq = jnp.stack([jnp.ones_like(qpos), qpos])[None]      # (1, 2, G*S)
+    segk = jnp.stack([jnp.ones_like(kpos), kpos])[None]
+    out, _ = _fwd(qf, kf, vf, float(sm_scale), True,
+                  int(_pick_block_q(G * S, D)), int(_pick_block_k(T, D)),
+                  seg=(segq, segk), H=KVH, window=window)
+    out = jnp.transpose(out.reshape(KVH, G, S, D), (2, 0, 1, 3))
+    return out.reshape(S, H, D)
 
 
 def flashmask_attention_bshd(q, k, v, startend_row_indices, causal=True,

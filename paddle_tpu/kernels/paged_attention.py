@@ -117,7 +117,8 @@ def paged_page_bytes(num_kv_heads, page_size, head_dim, kv_dtype=None):
 
 
 def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
-                   *rest_refs, sm_scale, page_size, fold, quantized=False):
+                   *rest_refs, sm_scale, page_size, fold, quantized=False,
+                   window=None):
     """One step of the flat grid: `row_ref[w]`'s step `step_ref[w]`. It
     GATHERS `fold` pages (one BlockSpec fetch each, all kv heads) and
     COMPUTES on them as one token tile: the pages are upcast and joined
@@ -151,7 +152,13 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
 
     quantized=True streams int8 value pages plus their fp32 per-slot
     scale pages (same gathered page ids) and dequantizes each page on
-    the VMEM side before the join — K/V bytes moved drop ~2x."""
+    the VMEM side before the join — K/V bytes moved drop ~2x.
+
+    window=w (a layer that attends to the last w positions): the row's
+    steps begin at the tile that holds its first visible key, len - w
+    (`_live_steps`; `step_ref` stays the tile's index in the row's
+    table), the running stats are reset there, and the keys before it
+    inside that tile are masked."""
     k_refs = rest_refs[:fold]
     v_refs = rest_refs[fold:2 * fold]
     if quantized:
@@ -180,7 +187,13 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
             pages.append(page)
         return pages[0] if fold == 1 else jnp.concatenate(pages, axis=1)
 
-    @pl.when(i == 0)
+    if window is None:
+        first_key, first_step = None, 0
+    else:
+        first_key = jnp.maximum(sl - np.int32(window), 0)
+        first_step = jax.lax.div(first_key, np.int32(tile_tokens))
+
+    @pl.when(i == first_step)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -193,7 +206,10 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
     s = s * sm_scale                                # (KVH, G, T)
     pos = (i * tile_tokens
            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
-    s = jnp.where(pos < sl, s, NEG_INF)
+    seen = pos < sl
+    if window is not None:
+        seen = jnp.logical_and(seen, pos >= first_key)
+    s = jnp.where(seen, s, NEG_INF)
     m_prev = m_ref[:, :, :1]
     l_prev = l_ref[:, :, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -301,13 +317,18 @@ def paged_blockspecs(B, H, KVH, D, page_size, num_pages, max_pages=None,
     return specs, scratch
 
 
-def _live_steps(seq_lens, tile_tokens, steps_per_row, fold):
+def _live_steps(seq_lens, tile_tokens, steps_per_row, fold, window=None):
     """The kernel's grid: the flat list of the rows' LIVE steps. Row b
     runs ceil(len / T) of them (one for an empty row, so that every
     output block is written); the bound is their sum, known on the
     device only. A step past a row's length costs the scalar core as
     much as a live one (_decode_kernel), and at serving lengths half
     the table is such steps.
+
+    With a `window` a row's steps begin at the tile of its first visible
+    key, len - window: a row far past the window runs window / T + 1
+    steps whatever its length, and `step_of` stays the tile's index in
+    the row's table.
 
     Returns (total, row_of, step_of, first_of): for flat step w its
     row, its step within the row and its first slot in the flattened
@@ -320,19 +341,26 @@ def _live_steps(seq_lens, tile_tokens, steps_per_row, fold):
     B = seq_lens.shape[0]
     steps_of = jnp.clip((seq_lens + (tile_tokens - 1)) // tile_tokens,
                         1, steps_per_row)
+    if window is not None:
+        skipped = jnp.minimum(
+            jnp.maximum(seq_lens - window, 0) // tile_tokens, steps_of - 1)
+        steps_of = steps_of - skipped
     ends = jnp.cumsum(steps_of, dtype=jnp.int32)
     flat = jnp.arange(B * steps_per_row + 1, dtype=jnp.int32)
     row_of = jnp.minimum(
         jnp.sum(flat[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
         B - 1)
-    step_of = jnp.minimum(flat - (ends - steps_of)[row_of],
-                          steps_per_row - 1)
+    step_of = flat - (ends - steps_of)[row_of]
+    if window is not None:
+        step_of = step_of + skipped[row_of]
+    step_of = jnp.minimum(step_of, steps_per_row - 1)
     first_of = (row_of * steps_per_row + step_of) * fold
     return ends[-1], row_of, step_of, first_of
 
 
 def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
-                           sm_scale=None, k_scale=None, v_scale=None):
+                           sm_scale=None, k_scale=None, v_scale=None,
+                           window=None):
     """One decode step of attention over a paged KV cache.
 
     q:            (B, H, D) — current-step queries.
@@ -347,6 +375,11 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
                   dequant scales for int8 caches (both or neither);
                   the kernel streams the scale pages alongside the
                   value pages and dequantizes in fp32.
+    window:       optional int (static) — the layer attends to the last
+                  `window` positions only: the query (at seq_len - 1)
+                  sees key j iff seq_len - window <= j < seq_len. Table
+                  slots before the first visible key's page are never
+                  read and may hold the pad page. None lowers as ever.
     Returns (B, H, D).
     """
     B, H, D = q.shape
@@ -381,7 +414,8 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         max_pages += pad
 
     total, row_of, step_of, first_of = _live_steps(
-        sl, fold * page_size, max_pages // fold, fold)
+        sl, fold * page_size, max_pages // fold, fold,
+        **({} if window is None else {"window": int(window)}))
     # Every scalar-prefetch array ends in 128+ zero words (row 0, step 0,
     # slot 0, the pad page, length 0: all valid) on a 128-word boundary.
     # Without a tail a v5e HALTED (on-device check) on the engine's small
@@ -395,7 +429,9 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
 
     kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
                                page_size=page_size, fold=fold,
-                               quantized=quantized)
+                               quantized=quantized,
+                               **({} if window is None
+                                  else {"window": int(window)}))
 
     def row_block(w, slots, first_of, sl, row_of, step_of):
         return row_of[w], _I0, _I0, _I0

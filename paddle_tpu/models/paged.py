@@ -10,7 +10,9 @@ against three things a model gives, and names no other method of it:
       whatever the entry holds: Llama's is (K pages, V pages[, their
       int8 scales]) of `(pages, KVH, page, D)`; a latent-attention
       model's is one `(pages, page, width)` array in which K and V are
-      views of the same bytes. The engine sets the number of pages.
+      views of the same bytes. The engine sets the number of pages. The
+      spec also says which layers attend to a window only (`windows`,
+      `layer_groups`): those keep pages of their own.
   paged_forward(input_ids, caches, block_tables, span)
       -> (logits, caches, counters): the one paged entry over a SPAN of
       query positions: 1 a row to decode, 1 + K a row to verify drafts,
@@ -77,10 +79,24 @@ class PagedCacheSpec:
     whole array, page axis first, under tensor parallelism, or None).
     `page_bytes` is what one page of one layer costs over all shards and
     `page_bytes_shard` on one chip; at most four arrays (the engine's
-    programs take four cache lists)."""
+    programs take four cache lists).
+
+    LAYER GROUPS. `windows` names the groups of layers by what a query
+    reads: None, every earlier position, or w, the last w of them (query
+    q sees key j iff q - w < j <= q). Group 0 is the unbounded one and
+    is what the engine's `num_pages` sizes. `layer_groups` gives each
+    layer's group (None: every layer in group 0). Each group has a pool
+    and a page list a sequence of its own: a windowed group gives a
+    row's pages back as the row advances (`serving/kv_cache.py`
+    `WindowGroup`). A model of one group takes its block tables as ever,
+    (B, P) or a chunk's (P,); a model of G > 1 takes them stacked,
+    (G, B, P) or (G, P), table g holding group g's pages at the SAME
+    positions with the pad page where one was given back."""
     entries: Tuple[Tuple[tuple, Any, Optional[Any]], ...]
     page_bytes: int
     page_bytes_shard: int
+    windows: Tuple[Optional[int], ...] = (None,)
+    layer_groups: Optional[Tuple[int, ...]] = None
 
 
 def decode_multi(model, input_ids, paged_caches, block_tables, seq_lens,

@@ -102,6 +102,7 @@ from .errors import (EngineFailure, EngineOverloaded,
                      SnapshotVersionError, check_feature_conflicts)
 from .lora.adapter import AdapterNotLoaded
 from .kv_cache import (BlockAllocator, BlocksExhausted, HostPageCorrupt,
+                       WindowGroup,
                        HostPageLost, HostPagesExhausted, HostPageSlow,
                        HostPageStore, PAD_PAGE, decode_page_payload,
                        encode_page_payload)
@@ -209,6 +210,14 @@ _LAUNCHES = {
     "multi_decode": ("multi_decode_step", "BKP", FAULT_MULTI),
     "verify": ("verify_step", "BKP", FAULT_VERIFY),
 }
+
+# What an engine whose model has a windowed layer group counts beside the
+# model's own counters (PERF.md section 3): each step, over the rows in
+# flight, the pages the windowed groups hold and the pages they would
+# hold without their windows (the unbounded group's count a windowed
+# group); and the pages given back as rows advanced.
+_WINDOW_COUNTERS = ("kv_window_pages_held", "kv_window_pages_full",
+                    "kv_window_pages_released")
 
 # Ceiling on decode_steps (K): each launch runs K decode iterations in
 # one device-side scan, and a device loop of 4096 iterations once left
@@ -548,6 +557,21 @@ class ServingEngine:
                                       kv_dtype=kv_dtype, tp=self.tp)
         self.kv_page_bytes = spec.page_bytes
         self.kv_page_bytes_shard = spec.page_bytes_shard
+        # the layer groups (models/paged.py): group 0 is unbounded and is
+        # what `num_pages` / `kv_pool_bytes` size; a windowed group's
+        # pool follows from the batch and the chunk budget below, because
+        # a row's need of it is bounded (kv_cache.py `WindowGroup`)
+        self._layer_groups = tuple(spec.layer_groups
+                                   or (0,) * self.num_layers)
+        if spec.windows[0] is not None or \
+                len(self._layer_groups) != self.num_layers or \
+                set(self._layer_groups) != set(range(len(spec.windows))):
+            raise ValueError(
+                "a paged cache spec names an unbounded group first and a "
+                f"group for every layer; got windows {spec.windows} over "
+                f"layers {self._layer_groups}")
+        if len(spec.windows) > 1:
+            check_feature_conflicts(active | {"windowed_cache"})
         if kv_pool_bytes is not None:
             # size the pool from a PER-CHIP HBM byte budget: the page
             # count is what kv_dtype="int8" roughly doubles and TP
@@ -660,9 +684,19 @@ class ServingEngine:
         # lora x proposer / lora x tensor_parallel conflicts via the
         # capability table (checked above)
 
-        self.allocator = BlockAllocator(self.num_pages, self.page_size)
+        rows, budget = self.batch_buckets[-1], \
+            min(token_budget, self.prefill_buckets[-1])
+        self.allocator = BlockAllocator(
+            self.num_pages, self.page_size,
+            [WindowGroup(WindowGroup.pages_for(w, self.page_size, rows,
+                                               budget), self.page_size, w)
+             for w in spec.windows[1:]])
+        # a model with a windowed group donates nothing: a prefix hit
+        # would have to bring the window's pages before the match point,
+        # which were given back (SERVING.md "Layer groups")
         self.radix = (RadixCache(self.allocator)
-                      if enable_prefix_cache else None)
+                      if enable_prefix_cache and not self.allocator.windows
+                      else None)
         self.scheduler = Scheduler(
             self.allocator, max_batch_size=self.batch_buckets[-1],
             token_budget=min(token_budget, self.prefill_buckets[-1]),
@@ -737,9 +771,11 @@ class ServingEngine:
         # The compiled programs take four cache lists unconditionally, so
         # every entry shares one program shape: the lists an entry does
         # not fill are empty pytrees.
-        pools = [[self._place(jnp.zeros((self.num_pages,) + tuple(page), dt),
+        group_pages = [self.num_pages] + [g.pool.num_pages
+                                          for g in self.allocator.windows]
+        pools = [[self._place(jnp.zeros((group_pages[g],) + tuple(page), dt),
                               pspec)
-                  for _ in range(self.num_layers)]
+                  for g in self._layer_groups]
                  for page, dt, pspec in spec.entries]
         pools += [[] for _ in range(4 - len(pools))]
         self._k_caches, self._v_caches, self._k_scales, self._v_scales = \
@@ -750,7 +786,9 @@ class ServingEngine:
         # tokens are fetched. A dense model names none and its programs
         # return an empty pytree there.
         self._model_counters = tuple(model.paged_counters)
-        for name in self._model_counters:
+        for name in self._model_counters + (_WINDOW_COUNTERS
+                                            if self.allocator.windows
+                                            else ()):
             self.metrics.counters.setdefault(name, 0)
         # bytes-moved accounting (ServingMetrics): one token's K+V
         # across every layer, scales included — GLOBAL bytes (the sum
@@ -1304,9 +1342,15 @@ class ServingEngine:
         B = _bucket_for(len(reqs), self.batch_buckets)
         P = _bucket_for(max(len(r.seq.pages) for r in reqs),
                         self.pages_buckets)
+        seqs = [r.seq for r in reqs]
         bt = np.full((B, P), PAD_PAGE, np.int32)
-        bt[:len(reqs)] = self.allocator.block_table(
-            [r.seq for r in reqs], P)
+        bt[:len(reqs)] = self.allocator.block_table(seqs, P)
+        if self.allocator.windows:
+            # a table a layer group, at the same positions: (G, B, P)
+            bt = np.stack([bt] + [np.full_like(bt, PAD_PAGE)
+                                  for _ in self.allocator.windows])
+            for g in range(1, len(bt)):
+                bt[g, :len(reqs)] = self.allocator.block_table(seqs, P, g)
         largs = self._lora_launch_args(reqs, B)
         if self.lora is not None:
             self.metrics.on_adapter_mix(
@@ -1347,6 +1391,10 @@ class ServingEngine:
             bt = np.full((P,), PAD_PAGE, np.int32)
             npages = min(len(req.seq.pages), P)
             bt[:npages] = req.seq.pages[:npages]
+            if self.allocator.windows:
+                bt = np.stack([bt] + [
+                    self.allocator.block_table([req.seq], P, g)[0]
+                    for g in range(1, 1 + len(self.allocator.windows))])
             padded = np.zeros((1, S), np.int32)
             padded[0, :chunk.length] = ids
             launch = self._launcher(
@@ -2006,6 +2054,23 @@ class ServingEngine:
                 host_pages_dropped=self.radix.num_host_dropped_pages)
         return out
 
+    def _window_gauges(self) -> dict:
+        """update_gauges kwargs of the windowed layer groups, empty for
+        a model of one group: each group's used pages, and this step's
+        part of `_WINDOW_COUNTERS` over the rows in flight. Host
+        arithmetic over at most a batch of rows: no sync."""
+        groups = self.allocator.windows
+        if not groups:
+            return {}
+        c = self.metrics.counters
+        for r in self.scheduler.running + self.scheduler.prefilling:
+            c["kv_window_pages_full"] += len(groups) * len(r.seq.pages)
+            c["kv_window_pages_held"] += sum(
+                r.seq.window_held(g) for g in range(len(groups)))
+        c["kv_window_pages_released"] = sum(g.pages_released
+                                            for g in groups)
+        return {"kv_window_used_pages": [g.pool.num_used for g in groups]}
+
     # ------------------------------------------------------------- step
     def _emit(self, req: Request, tok: int, emitted):
         """Record one generated token + run the finish checks."""
@@ -2185,7 +2250,7 @@ class ServingEngine:
                 radix_nodes=self.radix.num_nodes if self.radix else 0,
                 radix_evicted_pages=(self.radix.num_evicted_pages
                                      if self.radix else None),
-                **self._spill_gauges())
+                **self._spill_gauges(), **self._window_gauges())
             self._record_step(pre, n_chunks=len(sched.prefills),
                               n_decode=len(decodes),
                               n_emitted=len(emitted))

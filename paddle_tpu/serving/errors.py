@@ -97,6 +97,8 @@ class UnsupportedFeature(ValueError):
 #   host_spill        host_spill_pages > 0 (ISSUE 17)
 #   no_prefix_cache   enable_prefix_cache=False
 #   prefill_role      role="prefill" (ISSUE 18 disaggregation)
+#   windowed_cache    the model's cache spec has a windowed layer group
+#                     (models/paged.py): derived from the model, not a kwarg
 #
 # Adding a conflict = adding a row; the engine's single
 # `check_feature_conflicts(active)` call enforces all of them. Reasons
@@ -133,6 +135,33 @@ FEATURE_CONFLICTS = {
         "a prefill-role engine needs the radix cache: handoff ships "
         "the prefilled KV out of the donated radix prefix "
         "(enable_prefix_cache=True)",
+    # a windowed layer group gives a row's pages back as the row
+    # advances (kv_cache.py `WindowGroup`): what needs them back later,
+    # or a table of them another program's way, is refused until it is
+    # written and tested
+    frozenset({"windowed_cache", "proposer"}):
+        "a proposer over a model with a windowed layer group is not "
+        "supported yet: a rejected draft rolls the row back past pages "
+        "the window already gave back",
+    frozenset({"windowed_cache", "multi_step_decode"}):
+        "decode_steps > 1 over a model with a windowed layer group is "
+        "not supported yet: the K-step launch reserves K slots ahead and "
+        "its scan has no window-release points",
+    frozenset({"windowed_cache", "host_spill"}):
+        "host spill over a model with a windowed layer group is not "
+        "supported yet: such a model donates no prefix, so the radix "
+        "cache the spill tier lives under holds nothing",
+    frozenset({"windowed_cache", "prefill_role"}):
+        "a prefill-role engine over a model with a windowed layer group "
+        "is not supported yet: handoff ships a donated radix prefix, and "
+        "such a model donates none",
+    frozenset({"windowed_cache", "tensor_parallel"}):
+        "tensor parallelism over a model with a windowed layer group is "
+        "not supported yet: the windowed decode kernel has no per-shard "
+        "wrapper",
+    frozenset({"windowed_cache", "lora"}):
+        "lora over a model with a windowed layer group is not supported "
+        "yet: the family's projections carry no adapter hooks",
 }
 
 

@@ -23,7 +23,8 @@ import numpy as np
 
 from ..utils import faults
 
-__all__ = ["BlockAllocator", "KVSequence", "BlocksExhausted", "PAD_PAGE",
+__all__ = ["BlockAllocator", "KVSequence", "WindowGroup", "BlocksExhausted",
+           "PAD_PAGE",
            "HostPageStore", "HostPagesExhausted", "HostPageError",
            "HostPageCorrupt", "HostPageSlow", "HostPageLost",
            "encode_page_payload", "decode_page_payload"]
@@ -51,17 +52,73 @@ class BlocksExhausted(Exception):
 
 class KVSequence:
     """One sequence's view of the cache: ordered page ids + token count.
-    Page j covers token positions [j*page_size, (j+1)*page_size)."""
+    Page j covers token positions [j*page_size, (j+1)*page_size).
 
-    __slots__ = ("pages", "num_tokens", "freed")
+    `pages` are those of the unbounded layer group. Where the allocator
+    has windowed groups (`WindowGroup`), `windows[g]` is group g's list
+    at the SAME absolute positions, PAD_PAGE where a page was given back:
+    slots [window_first[g], len(windows[g])) are held, every slot before
+    them is PAD_PAGE."""
 
-    def __init__(self):
+    __slots__ = ("pages", "num_tokens", "freed", "windows", "window_first")
+
+    def __init__(self, n_windows: int = 0):
         self.pages: List[int] = []
         self.num_tokens = 0
         self.freed = False
+        self.windows: List[List[int]] = [[] for _ in range(n_windows)]
+        self.window_first: List[int] = [0] * n_windows
 
     def num_pages(self):
         return len(self.pages)
+
+    def window_held(self, g: int = 0) -> int:
+        """Pages group g holds for this sequence."""
+        return len(self.windows[g]) - self.window_first[g]
+
+
+class WindowGroup:
+    """The pages of the layers that attend to a WINDOW: a query at
+    position q reads the keys q - window < j <= q, so a page that lies
+    wholly behind every query still to come is given back as the row
+    advances, not at its end. The group has a pool of its own (its page
+    ids name rows of ITS layers' arrays, page 0 its pad page), sized by
+    `pages_for` from what the engine already knows, because a row's need
+    is bounded.
+
+    One token of slack: with the next query at q the group keeps the
+    pages from position q - window on, not q - window + 1, so that the
+    slot a launch ahead reserved can be given back (`truncate_sequence`
+    by one token) and the row's last query run again. window + 1 tokens
+    span at most window / page + 1 pages, the bound of `window` tokens,
+    so the slack costs no page."""
+
+    def __init__(self, num_pages: int, page_size: int, window: int):
+        if window < 1:
+            raise ValueError(f"window {window} must be positive")
+        self.window = int(window)
+        self.pool = BlockAllocator(num_pages, page_size)
+        self.pages_released = 0      # given back as rows advanced
+
+    def first_kept(self, first_query: int) -> int:
+        """The first page a row keeps when its next query sits at
+        `first_query`."""
+        return max(0, first_query - self.window) // self.pool.page_size
+
+    def row_pages(self) -> int:
+        """The most pages a row holds between two launches."""
+        return -(-self.window // self.pool.page_size) + 1
+
+    @staticmethod
+    def pages_for(window: int, page_size: int, rows: int,
+                  chunk_tokens: int) -> int:
+        """Pages that never run out under `rows` rows in flight and
+        `chunk_tokens` of prefill chunks a step: each row keeps at most
+        window / page + 1 pages between launches, a step's chunks add at
+        most their tokens' pages and one a chunk, and page 0 is the pad
+        page."""
+        per_row = -(-window // page_size) + 1
+        return rows * (per_row + 1) + -(-chunk_tokens // page_size) + 1
 
 
 class BlockAllocator:
@@ -72,7 +129,7 @@ class BlockAllocator:
     positive refcount — never both, never negative.
     """
 
-    def __init__(self, num_pages: int, page_size: int):
+    def __init__(self, num_pages: int, page_size: int, windows=()):
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is the pad page)")
         if page_size <= 0 or page_size % 8 != 0:
@@ -85,6 +142,11 @@ class BlockAllocator:
         # instead of hammering the most recently freed ones
         self._free = deque(range(1, num_pages))
         self._refs: Dict[int, int] = {}
+        # the windowed layer groups riding on this (unbounded) group's
+        # sequences; empty for a model of one group
+        self.windows: List[WindowGroup] = list(windows)
+        if any(g.pool.page_size != page_size for g in self.windows):
+            raise ValueError("every layer group shares one page size")
 
     # ---- low-level page ops ---------------------------------------------
     def _alloc_page(self) -> int:
@@ -151,7 +213,7 @@ class BlockAllocator:
         if need > self.num_free:
             raise BlocksExhausted(
                 f"need {need} pages, {self.num_free} free")
-        seq = KVSequence()
+        seq = KVSequence(len(self.windows))
         seq.pages = self._alloc_pages(need)
         seq.num_tokens = num_tokens
         return seq
@@ -172,7 +234,7 @@ class BlockAllocator:
         if fresh > self.num_free:
             raise BlocksExhausted(
                 f"need {fresh} fresh pages, {self.num_free} free")
-        seq = KVSequence()
+        seq = KVSequence(len(self.windows))
         for pid in prefix_pages:
             self._incref(pid)
         try:
@@ -193,6 +255,9 @@ class BlockAllocator:
             raise RuntimeError("append to a freed sequence")
         copies: List[Tuple[int, int]] = []
         pos = seq.num_tokens
+        if self.windows:
+            # first: on exhaustion there nothing of this group has moved
+            self.advance_windows(seq, pos, pos + 1)
         j = pos // self.page_size
         if j == len(seq.pages):            # crossing into a new page
             seq.pages.append(self._alloc_page())
@@ -239,13 +304,43 @@ class BlockAllocator:
         del seq.pages[keep:]
         for pid in dropped:
             self._decref(pid)
+        for g, group in enumerate(self.windows):
+            pages = seq.windows[g]
+            for pid in pages[max(keep, seq.window_first[g]):]:
+                group.pool._decref(pid)
+            del pages[keep:]
+            seq.window_first[g] = min(seq.window_first[g], len(pages))
         seq.num_tokens = num_tokens
+
+    # ---- windowed layer groups ------------------------------------------
+    def advance_windows(self, seq: KVSequence, first_query: int, end: int):
+        """Make every windowed group ready for a launch whose queries sit
+        at positions first_query .. end - 1: give back the pages that lie
+        wholly behind `first_query - window` (`WindowGroup.first_kept`),
+        then hold a page for every position up to `end`. Does again what
+        was done for nothing (a retry after BlocksExhausted finds the
+        pages it got), and with end == first_query only gives back."""
+        need = self.pages_needed(end)
+        for g, group in enumerate(self.windows):
+            pages = seq.windows[g]
+            lo = min(group.first_kept(first_query), need)
+            for j in range(seq.window_first[g], min(lo, len(pages))):
+                group.pool._decref(pages[j])
+                pages[j] = PAD_PAGE
+                group.pages_released += 1
+            pages.extend([PAD_PAGE] * (lo - len(pages)))
+            seq.window_first[g] = max(seq.window_first[g], lo)
+            if need > len(pages):
+                pages.extend(group.pool._alloc_pages(need - len(pages)))
 
     def fork_sequence(self, seq: KVSequence) -> KVSequence:
         """Prefix fork: the child shares every page (refcounts bumped);
         the first divergent append to a shared page triggers CoW."""
         if seq.freed:
             raise RuntimeError("fork of a freed sequence")
+        if self.windows:
+            raise NotImplementedError(
+                "fork of a sequence with windowed layer groups")
         child = KVSequence()
         child.pages = list(seq.pages)
         child.num_tokens = seq.num_tokens
@@ -258,21 +353,28 @@ class BlockAllocator:
             raise RuntimeError("double free of sequence")
         for pid in seq.pages:
             self._decref(pid)
+        for g, group in enumerate(self.windows):
+            for pid in seq.windows[g][seq.window_first[g]:]:
+                group.pool._decref(pid)
+            seq.windows[g], seq.window_first[g] = [], 0
         seq.pages = []
         seq.num_tokens = 0
         seq.freed = True
 
     # ---- kernel-facing tensors ------------------------------------------
-    def block_table(self, seqs, max_pages: int) -> np.ndarray:
+    def block_table(self, seqs, max_pages: int, group: int = 0) -> np.ndarray:
         """(B, max_pages) int32 block table; unused slots hold PAD_PAGE
-        (the `paged_attention_decode` padding contract)."""
+        (the `paged_attention_decode` padding contract). `group` 0 is
+        this allocator's own; g > 0 is windowed group g - 1's, at the
+        same positions, PAD_PAGE where a page was given back."""
         bt = np.full((len(seqs), max_pages), PAD_PAGE, np.int32)
         for i, s in enumerate(seqs):
-            if len(s.pages) > max_pages:
+            pages = s.pages if group == 0 else s.windows[group - 1]
+            if len(pages) > max_pages:
                 raise ValueError(
-                    f"sequence holds {len(s.pages)} pages > table width "
+                    f"sequence holds {len(pages)} pages > table width "
                     f"{max_pages}")
-            bt[i, :len(s.pages)] = s.pages
+            bt[i, :len(pages)] = pages
         return bt
 
     def seq_lens(self, seqs) -> np.ndarray:
@@ -286,6 +388,8 @@ class BlockAllocator:
         assert all(r > 0 for r in self._refs.values())
         assert PAD_PAGE not in free and PAD_PAGE not in held
         assert len(free) + len(held) == self.num_pages - 1
+        for group in self.windows:
+            group.pool.check_invariants()
 
 
 # ---------------------------------------------------------------------------

@@ -176,6 +176,9 @@ class ServingMetrics:
         self.kv_occupancy = 0.0
         self.cached_pages = 0
         self.radix_nodes = 0
+        # used pages of each windowed layer group (None: the model has
+        # one group, and the snapshot shows nothing new)
+        self.kv_window_used_pages = None
         # static KV-geometry gauges (set once at engine construction)
         self.kv_dtype = None
         self.kv_page_bytes = 0
@@ -391,7 +394,8 @@ class ServingMetrics:
                       host_pages_used=None, host_occupancy=None,
                       radix_evict_demoted=None, radix_evict_dropped=None,
                       kv_pages_demoted=None, kv_pages_promoted=None,
-                      host_prefix_hits=None, host_pages_dropped=None):
+                      host_prefix_hits=None, host_pages_dropped=None,
+                      kv_window_used_pages=None):
         """None for an optional field means "leave it untouched" — the
         engine passes its radix/spill sync kwargs only when the
         corresponding subsystem exists, so a cache-off or spill-off
@@ -408,6 +412,8 @@ class ServingMetrics:
             self.host_pages_used = host_pages_used
         if host_occupancy is not None:
             self.host_occupancy = host_occupancy
+        if kv_window_used_pages is not None:
+            self.kv_window_used_pages = list(kv_window_used_pages)
         # radix-owned counters synced by assignment (idempotent), the
         # radix_evicted_pages pattern
         for key, val in (("radix_evict_demoted", radix_evict_demoted),
@@ -473,6 +479,8 @@ class ServingMetrics:
             "radix_nodes": self.radix_nodes,
             "tokens_per_second": round(self.tokens_per_second(), 2),
         })
+        if self.kv_window_used_pages is not None:
+            snap["kv_window_used_pages"] = list(self.kv_window_used_pages)
         # pool bytes gate the block (not page bytes): a heterogeneous
         # fleet merge zeroes the per-page gauges as sentinels while the
         # pooled bytes stay exact — they must still surface

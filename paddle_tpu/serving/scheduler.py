@@ -388,8 +388,27 @@ class Scheduler:
                     req.seq, req.seq.num_tokens - 1)
 
     # ---- the per-step decision ------------------------------------------
+    def _window_chunk(self, req: Request, start: int, take: int) -> bool:
+        """Hold the windowed layer groups' pages for the chunk
+        [start, start + take) of `req` (the unbounded group's were taken
+        at admission; a windowed group's are taken a chunk at a time and
+        given back as the row advances). False where that pool is dry:
+        it is sized never to be (`WindowGroup.pages_for`), so only an
+        injected fault gets here, and the chunk waits a step."""
+        try:
+            self.allocator.advance_windows(req.seq, start, start + take)
+        except BlocksExhausted:
+            return False
+        return True
+
     def schedule(self) -> ScheduleStep:
         preempted: List[Request] = []
+        if self.allocator.windows:
+            # a row between two chunks keeps its window and no more: what
+            # its last chunk read from further back goes back first
+            for req in self.prefilling:
+                self.allocator.advance_windows(req.seq, req.num_computed,
+                                               req.num_computed)
 
         # 1. guarantee every decoding request can append this step's
         #    token (may cross a page boundary); on pressure evict cached
@@ -429,7 +448,8 @@ class Scheduler:
                 break
             n = len(req.resume_ids)
             take = min(budget, n - req.num_computed)
-            if take <= 0:
+            if take <= 0 or not self._window_chunk(req, req.num_computed,
+                                                   take):
                 continue
             chunks.append(PrefillChunk(req, req.num_computed, take,
                                        req.num_computed + take == n,
@@ -480,12 +500,16 @@ class Scheduler:
                     n, mpages)
             except BlocksExhausted:
                 break
+            take = min(budget, n - m)
+            if not self._window_chunk(req, m, take):
+                self.allocator.free_sequence(req.seq)
+                req.seq = None
+                break
             self.waiting.popleft()
             req.state = RequestState.PREFILL
             req.num_computed = m
             req.cached_tokens = m
             self.prefilling.append(req)
-            take = min(budget, n - m)
             chunks.append(PrefillChunk(req, m, take, m + take == n,
                                        is_first=True))
             budget -= take
