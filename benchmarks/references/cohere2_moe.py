@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.references.llama import (HI, _embed, _leaf, _leaves_from,
-                                         _seed_words)
+                                         _next_token_loss, _seed_words)
 
 BRANCH_GAIN = 0.5          # as references/llama.py: a branch's last matrix
 # q and k entries have variance 2, so that scores (q . k) / sqrt(head_dim)
@@ -372,12 +372,6 @@ def logits(weights, cfg, ids):
     ids = jnp.asarray(ids, jnp.int32)
     st = _Static(cfg)
     return jnp.stack([_sequence(weights, cfg, st, row)[0] for row in ids])
-
-
-@jax.jit
-def _next_token_loss(lg, labels):
-    logp = jax.nn.log_softmax(lg[:, :-1], axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, 1:, None], axis=-1))
 
 
 def loss(weights, cfg, ids) -> float:

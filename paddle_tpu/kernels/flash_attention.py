@@ -195,7 +195,6 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     masked_rows = use_seg or fm_cols  # rows may see no valid key yet
-    windowed = {} if window is None else {"window": window}
 
     @pl.when(ki == 0)
     def _init():
@@ -212,7 +211,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
         qi, ki, block_q=block_q, block_k=block_k, q_offset=q_offset,
         causal=causal, segq_blk=segq_blk, segk_blk=segk_blk,
         posq_blk=posq_blk, posk_blk=posk_blk, fm_blk=fm_blk,
-        fm_causal=fm_causal, fm_cols=fm_cols, **windowed)
+        fm_causal=fm_causal, fm_cols=fm_cols, window=window)
 
     @pl.when(contributes)
     def _step():
@@ -226,7 +225,7 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
                          q_offset=q_offset, causal=causal, segq_blk=segq_blk,
                          segk_blk=segk_blk, posq_blk=posq_blk,
                          posk_blk=posk_blk, fm_blk=fm_blk,
-                         fm_causal=fm_causal, fm_cols=fm_cols, **windowed)
+                         fm_causal=fm_causal, fm_cols=fm_cols, window=window)
         m_prev = m_ref[:, :1]                      # (bq, 1), lanes equal
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
@@ -307,8 +306,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
         block_k=block_k, nk=nk, q_offset=Sk - Sq, use_seg=use_seg,
-        fm_causal=fm_causal, fm_cols=fm_cols,
-        **({} if window is None else {"window": int(window)}))
+        fm_causal=fm_causal, fm_cols=fm_cols, window=window)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, _I0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),

@@ -414,8 +414,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         max_pages += pad
 
     total, row_of, step_of, first_of = _live_steps(
-        sl, fold * page_size, max_pages // fold, fold,
-        **({} if window is None else {"window": int(window)}))
+        sl, fold * page_size, max_pages // fold, fold, window=window)
     # Every scalar-prefetch array ends in 128+ zero words (row 0, step 0,
     # slot 0, the pad page, length 0: all valid) on a 128-word boundary.
     # Without a tail a v5e HALTED (on-device check) on the engine's small
@@ -429,9 +428,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
 
     kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
                                page_size=page_size, fold=fold,
-                               quantized=quantized,
-                               **({} if window is None
-                                  else {"window": int(window)}))
+                               quantized=quantized, window=window)
 
     def row_block(w, slots, first_of, sl, row_of, step_of):
         return row_of[w], _I0, _I0, _I0

@@ -131,6 +131,14 @@ class Cohere2MoeConfig:
         return (int(self.sliding_window)
                 if self.layer_types[layer] == SLIDING else None)
 
+    @property
+    def layer_groups(self) -> Optional[Tuple[int, ...]]:
+        """Each layer's cache group, 0 the full layers' and 1 the window
+        layers'; None for a model without window layers (one group)."""
+        if SLIDING not in self.layer_types:
+            return None
+        return tuple(int(t == SLIDING) for t in self.layer_types)
+
 
 def cohere2_moe_tiny(**kw):
     """The same layer at toy widths (tests; widths the kernels accept)."""
@@ -297,9 +305,8 @@ class Cohere2MoeAttention(nn.Layer):
             q, k, v = self._qkv(xx, pp, wq, wk, wv)
             if kind == "decode":
                 kc, vc = paged_cache_write(kc, vc, k[:, 0], v[:, 0], bt, fst)
-                o = paged_attention_decode(
-                    q[:, 0], kc, vc, bt, fst + 1, sm_scale=scale,
-                    **({} if window is None else {"window": window}))
+                o = paged_attention_decode(q[:, 0], kc, vc, bt, fst + 1,
+                                           sm_scale=scale, window=window)
                 return jnp.dot(o.reshape(b, 1, -1), wo), kc, vc
             # a prefill chunk: one sequence
             kc, vc = paged_cache_write_range(kc, vc, k[0], v[0], bt, cnt[0],
@@ -463,18 +470,17 @@ class Cohere2MoeForCausalLM(nn.Layer):
         kvh, d = cfg.num_key_value_heads, cfg.head_dim
         check_supported_paged((1, cfg.num_attention_heads, d),
                               (1, kvh, page_size, d), dtype)
-        kinds = set(cfg.layer_types)
-        if FULL not in kinds:
+        if FULL not in cfg.layer_types:
             raise ValueError("a model of window layers only is not "
                              "supported: group 0 of a paged cache spec is "
                              "the unbounded one")
         nbytes = paged_page_bytes(kvh, page_size, d, str(dtype))
         entries = (((kvh, page_size, d), dtype, None),) * 2
-        if SLIDING not in kinds:
+        if cfg.layer_groups is None:
             return PagedCacheSpec(entries, nbytes, nbytes)
         return PagedCacheSpec(
             entries, nbytes, nbytes, windows=(None, int(cfg.sliding_window)),
-            layer_groups=tuple(int(t == SLIDING) for t in cfg.layer_types))
+            layer_groups=cfg.layer_groups)
 
     def paged_forward(self, input_ids, paged_caches, block_tables, span):
         """The one paged entry (models/paged.py `PagedSpan`; a verify
@@ -491,14 +497,14 @@ class Cohere2MoeForCausalLM(nn.Layer):
         # the real tokens: the experts neither compute nor count the rest
         live = apply_op(
             "span_live", lambda c: jnp.arange(s)[None, :] < c[:, None], count)
-        grouped = SLIDING in cfg.layer_types
+        groups = cfg.layer_groups or (None,) * cfg.num_hidden_layers
         x = m.embed_tokens(input_ids)
         caches = []
         counts = jnp.zeros((len(MOE_COUNTERS),), jnp.int32)
         for i, layer in enumerate(m.layers):
-            group = int(cfg.layer_types[i] == SLIDING) if grouped else None
-            x, cache, c = layer.paged(x, paged_caches[i], block_tables, group,
-                                      span.kind, pos, count, first, live)
+            x, cache, c = layer.paged(x, paged_caches[i], block_tables,
+                                      groups[i], span.kind, pos, count,
+                                      first, live)
             caches.append(cache)
             counts = counts + c._data
         if span.kind == "prefill":

@@ -684,6 +684,7 @@ class ServingEngine:
         # lora x proposer / lora x tensor_parallel conflicts via the
         # capability table (checked above)
 
+        # the scheduler's two bounds, which size a windowed group's pool too
         rows, budget = self.batch_buckets[-1], \
             min(token_budget, self.prefill_buckets[-1])
         self.allocator = BlockAllocator(
@@ -698,8 +699,7 @@ class ServingEngine:
                       if enable_prefix_cache and not self.allocator.windows
                       else None)
         self.scheduler = Scheduler(
-            self.allocator, max_batch_size=self.batch_buckets[-1],
-            token_budget=min(token_budget, self.prefill_buckets[-1]),
+            self.allocator, max_batch_size=rows, token_budget=budget,
             max_prompt_len=self.max_seq_len,
             prefix_cache=self.radix,
             max_queue_len=max_queue_len)
@@ -1343,14 +1343,17 @@ class ServingEngine:
         P = _bucket_for(max(len(r.seq.pages) for r in reqs),
                         self.pages_buckets)
         seqs = [r.seq for r in reqs]
-        bt = np.full((B, P), PAD_PAGE, np.int32)
-        bt[:len(reqs)] = self.allocator.block_table(seqs, P)
+
+        def table(group):
+            bt = np.full((B, P), PAD_PAGE, np.int32)
+            bt[:len(reqs)] = self.allocator.block_table(seqs, P, group)
+            return bt
+
+        bt = table(0)
         if self.allocator.windows:
             # a table a layer group, at the same positions: (G, B, P)
-            bt = np.stack([bt] + [np.full_like(bt, PAD_PAGE)
-                                  for _ in self.allocator.windows])
-            for g in range(1, len(bt)):
-                bt[g, :len(reqs)] = self.allocator.block_table(seqs, P, g)
+            bt = np.stack([bt] + [
+                table(g) for g in range(1, 1 + len(self.allocator.windows))])
         largs = self._lora_launch_args(reqs, B)
         if self.lora is not None:
             self.metrics.on_adapter_mix(

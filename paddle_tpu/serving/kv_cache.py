@@ -105,10 +105,6 @@ class WindowGroup:
         `first_query`."""
         return max(0, first_query - self.window) // self.pool.page_size
 
-    def row_pages(self) -> int:
-        """The most pages a row holds between two launches."""
-        return -(-self.window // self.pool.page_size) + 1
-
     @staticmethod
     def pages_for(window: int, page_size: int, rows: int,
                   chunk_tokens: int) -> int:

@@ -204,6 +204,42 @@ def test_paged_attention_decode_serving_cell(for_chip, one_chip, H, KVH):
     assert MARKER in text
 
 
+# `serve-mixed-context`'s calls (PR 35): B 48 rows of 16 query heads a KV
+# head over an 816-page table (39,168 table words beside the four step
+# arrays in scalar-prefetch memory), a full layer's and a window layer's;
+# and a 2,048-token chunk's attention through the flash kernel with the
+# group's heads laid along the query axis, over the window's 392 pages
+# and over the whole table.
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_paged_attention_decode_mixed_context_cell(for_chip, one_chip,
+                                                   window):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    B, H, KVH, D, pages, page, table = 48, 128, 8, 128, 12513, 16, 816
+    cache = _sds((pages, KVH, page, D), BF16, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, bt, sl: paged_attention_decode(
+            q, k, v, bt, sl, window=window),
+        _sds((B, H, D), BF16, one_chip), cache, cache,
+        _sds((B, table), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip))
+    assert "paged_attention_decode" in text and MARKER in text
+
+
+@pytest.mark.parametrize("keys,window", [(392 * 16, 4096), (816 * 16, None)],
+                         ids=["window", "full"])
+def test_flash_chunk_gqa_mixed_context_cell(for_chip, one_chip, keys,
+                                            window):
+    S, H, KVH, D = 2048, 128, 8, 128
+    assert fa.chunk_gqa_unsupported_reason(S, keys, H, KVH, D, BF16) is None
+    kv = _sds((keys, KVH, D), BF16, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, qp, kp: fa.flash_attention_chunk_gqa(
+            q, k, v, qp, kp, window=window),
+        _sds((S, H, D), BF16, one_chip), kv, kv,
+        _sds((S,), jnp.int32, one_chip), _sds((keys,), jnp.int32, one_chip))
+    assert "flash_attention_fwd" in text and MARKER in text
+
+
 def test_quant_matmul(for_chip, one_chip):
     from paddle_tpu.kernels.quant_matmul import quant_matmul
     M, K, N = 32, 4096, 14336
