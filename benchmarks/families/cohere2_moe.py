@@ -98,24 +98,23 @@ def reference_weights(pcfg, cfg: dict, seed: int):
 # What a served request is held to (serve driver's check), for bfloat16:
 # over its served tokens, how far each token's reference logit lies under
 # the reference's best, in logit units, over the positions the reference
-# keeps (it leaves out those at a routing tie, `ROUTE_TIE`, a third of
-# them); logits are about N(0, 1 / 16) by the weights' scales (the tied
-# embedding's, `references/cohere2_moe.py` EMBED_GAIN), a quarter of the
-# other families' spread. Set from chip readings at the cell's own size
-# and load (PERF.md section 6, PR 35, has every reading):
+# keeps (it leaves out those within `ROUTE_TIE` of a routing tie, a little
+# over half of them); logits are about N(0, 1 / 16) by the weights' scales
+# (the tied embedding's, `references/cohere2_moe.py` EMBED_GAIN), a
+# quarter of the other families' spread. Set from chip readings at the
+# cell's own size and load (PERF.md section 6, PR 35, has every reading):
 # `control_gap_fresh.py` on three seeds with the int8 control on each,
-# and fifteen 45 s runs of the cell. MEAN: the program 8.4e-5 to 1.73e-4
-# on eighteen readings, the int8 control 1.81e-3 to 2.01e-3: the limit is the geometric middle of
-# the program's largest and the control's smallest, three times of room on
-# both sides; the control fails it on every seed. WIDEST: the program
-# 0.0089 to 0.0165 on seventeen readings and 0.088 on one; a routing flip just beyond `ROUTE_TIE` costs the
-# program and the control alike up to a tenth of a logit (0.088 at a
-# distance of 4.4e-3 on one seed, 0.109 the largest at any distance), so
-# the two ranges OVERLAP here (the control 0.065 to 0.177) and no limit
-# lies between them: this one is three times the program's largest, a
-# logit spread, and is there for a token served at random (about four
-# spreads under the best), which the mean of a thousand would not show.
-GAP_LIMITS_BF16 = {"mean": 5.6e-4, "widest": 0.27}
+# every position kept and held against `ROUTE_TIE` 2^-7 afterwards, and
+# the cell's own 45 s runs. MEAN: the program 9.8e-5 to 1.56e-4, the int8
+# control 1.50e-3 to 1.79e-3: the geometric middle, three times of room on
+# both sides. WIDEST: the program 0.0080 to 0.0107 on the three seeds (and
+# up to 0.0165 in fifteen runs read at a tie of 2^-8, where one more read
+# 0.088: a routing flip 4.4e-3 from the cut, which 2^-7 leaves out); the
+# control 0.0415, 0.0466, 0.177. The limit lies between, nearer the
+# control: 1.8 times the program's largest at either tie and 1.4 times
+# under the control's smallest, because one run over it refuses a PR
+# that did nothing, and the mean refuses the control by itself.
+GAP_LIMITS_BF16 = {"mean": 4.8e-4, "widest": 0.03}
 
 
 def gap_limits(cfg: dict) -> dict:
@@ -181,6 +180,24 @@ def kv_bytes_per_key(cfg: dict) -> int:
     return 2 * cfg["num_key_value_heads"] * cfg["head_dim"] * _el(cfg)
 
 
+def window_clip_share(cfg: dict, cell: dict) -> float:
+    """Of the keys the cell's tokens see in a full layer, the share they
+    see in a window layer: the sum of min(context, window) over the sum of
+    the contexts, over every position of the cell's own cycle of
+    (prompt, output) lengths (`harness/loadgen.py` `draw_sizes`; an open
+    loop's cycle depends on the window, so 1,024 draws of its law stand
+    for it). The driver keeps the MEAN context only, and clipping the
+    mean at the window counts a window layer a tenth too high."""
+    from benchmarks.harness.loadgen import draw_sizes
+    traffic, w = cell["traffic"], cfg["sliding_window"]
+    p, o = draw_sizes(traffic, int(traffic.get("pool", 1024)),
+                      cfg["max_position_embeddings"])
+    n = (p + o - 1).astype(float)          # contexts 1 .. n of a request
+    m = n.clip(max=w)
+    return float((m * (m + 1) / 2 + (n - m) * w).sum()
+                 / (n * (n + 1) / 2).sum())
+
+
 def serve_flops_per_token(cfg: dict, cell: dict, values):
     """Forward of one token the engine processed, THIS CHIP's required
     work: 2 x (the non-routed matmul parameters + the routed pairs this
@@ -188,15 +205,14 @@ def serve_flops_per_token(cfg: dict, cell: dict, values):
     slice only where a token comes out of it
     (`values['head_tokens_per_processed']`); and attention, QK^T and PV
     over head_dim a head a layer, over the keys the token had to see: the
-    mean context (`values['mean_context_tokens']`) in a full layer, at
-    most the window in a window layer (the minimum of the MEAN context
-    and the window: a little over the mean of the minima, which the
-    driver does not keep)."""
+    mean context (`values['mean_context_tokens']`) in a full layer, and
+    `window_clip_share` of it in a window layer."""
     ctx = values.get("mean_context_tokens")
     heads = values.get("head_tokens_per_processed")
     if ctx is None or heads is None:
         return None
-    keys = sum(ctx if w is None else min(ctx, w) for w in layer_windows(cfg))
+    clip = window_clip_share(cfg, cell)
+    keys = sum(ctx if w is None else ctx * clip for w in layer_windows(cfg))
     attn = 4 * keys * cfg["num_attention_heads"] * cfg["head_dim"]
     routed = cfg["num_hidden_layers"] * held_pairs_per_token(cfg) \
         * expert_params(cfg)
@@ -214,6 +230,21 @@ def window_decode_kv(cfg: dict, cell: dict, values) -> dict:
     if keys is None:
         return {}
     return {"flops": 0.0, "bytes": float(keys) * kv_bytes_per_key(cfg)}
+
+
+def window_chunk_attention(cfg: dict, cell: dict, values) -> dict:
+    """What the prefill chunks' attention of the traced slice REQUIRED,
+    from the program's own counter (`attn_chunk_pairs`, summed over the
+    slice's chunk launches): QK^T and PV over head_dim for every head
+    and every (query, key) pair a chunk had to score, a query's whole
+    context in a full layer and at most the window in a window layer.
+    Operations only: a chunk reads each key once a KV head and is bound
+    by its products."""
+    pairs = (values.get("slice_counters") or {}).get("attn_chunk_pairs")
+    if pairs is None:
+        return {}
+    return {"flops": 4.0 * pairs * cfg["num_attention_heads"]
+            * cfg["head_dim"], "bytes": 0.0}
 
 
 def moe_held_experts(cfg: dict, cell: dict, values) -> dict:

@@ -76,10 +76,16 @@ EMBED_GAIN = 0.25
 # A position is left out of `token_gaps` where, in any layer, a held
 # expert's score lies closer than this to the selection's cut: there the
 # program's choice of that expert is decided by the rounding of the
-# router's input (`references/kimi_k2.py` has the chip readings the value
-# was chosen from: bfloat16's step at the scores' size). Only the
-# REFERENCE's own float32 scores decide it.
-ROUTE_TIE = 2.0 ** -8
+# router's input. Only the REFERENCE's own float32 scores decide it. TWO
+# of bfloat16's steps at the scores' size (a sigmoid's, in [0.5, 1)):
+# at one step, `references/kimi_k2.py`'s value, the chip served a token
+# 0.088 under the best through an expert chosen at a distance of 4.4e-3
+# from the cut (PERF.md section 6, PR 35), as far as the int8 control's
+# flips reach, and no limit on the widest gap lay between the two; at
+# two, every reading kept (`chiprun_out/control_gap_routed.npz`) parts
+# them: the program 0.008 to 0.011, the control 0.042 to 0.177. 45 % of
+# the positions stay.
+ROUTE_TIE = 2.0 ** -7
 SLIDING = "sliding_attention"
 ATTN_LEAVES = ("input_layernorm.weight", "self_attn.q_proj.weight",
                "self_attn.k_proj.weight", "self_attn.v_proj.weight",

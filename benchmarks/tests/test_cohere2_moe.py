@@ -50,6 +50,8 @@ def test_the_toy_cell_through_the_commands_own_dispatch(toy_run):
     # 32-token window in each of the three window layers
     assert 0 < c["attn_decode_keys"] \
         <= c["decode_tokens"] * (256 + 3 * 32)
+    assert 0 < c["attn_chunk_pairs"] \
+        <= c["prefill_tokens"] * (256 + 3 * 32)
     assert 0 < c["kv_window_pages_held"] < c["kv_window_pages_full"]
     got = observe.read_metrics(obs)
     assert {"serve_step_ms", "batch_occupancy", "serve_mfu"} <= set(got)
@@ -75,16 +77,18 @@ def test_the_new_metrics_readers_on_the_toy_run(toy_run, monkeypatch):
     # the slice by the slice's OWN counters over the ops' time
     m_moe = metric("cohere2_moe_expert_roofline")
     m_attn = metric("window_attn_hbm_share")
-    assert trace_op_counters.read(obs, m_moe["args"]) is None
-    assert trace_op_counters.read(obs, m_attn["args"]) is None
+    m_chunk = metric("flash_attn_chunk_roofline")
+    for m in (m_moe, m_attn, m_chunk):
+        assert trace_op_counters.read(obs, m["args"]) is None
     fam = lookup.family(obs["cfg"])
     traced = dict(obs, trace={"window_s": 0.5, "ops": {
         "moe_grouped_matmul_gate_up.3": 0.002, "fusion.1": 1.0,
         "moe_grouped_matmul_down.4": 0.001,
         "paged_attention_decode.7": 0.003,
-        "paged_attention_decode.9": 0.001}})
+        "paged_attention_decode.9": 0.001,
+        "flash_attention_fwd.2": 0.002, "flash_attention_fwd.5": 0.003}})
     in_slice = {"moe_pairs_held": 40.0, "moe_experts_touched": 12.0,
-                "attn_decode_keys": 5000.0}
+                "attn_decode_keys": 5000.0, "attn_chunk_pairs": 6.0e6}
     monkeypatch.setattr(trace_op_counters, "slice_counters",
                         lambda o: in_slice)
     need = fam.moe_held_experts(obs["cfg"], obs["cell"],
@@ -96,13 +100,17 @@ def test_the_new_metrics_readers_on_the_toy_run(toy_run, monkeypatch):
     # K and V of 2 KV heads x 64 in float32: 1,024 B a key a layer
     assert trace_op_counters.read(traced, m_attn["args"]) == pytest.approx(
         100 * 5000 * 2 * 2 * 64 * 4 / 819e9 / 0.004)
+    # the chunks' pairs: QK^T and PV over 8 heads x 64, by operations
+    assert trace_op_counters.read(traced, m_chunk["args"]) == pytest.approx(
+        100 * 6.0e6 * 4 * 8 * 64 / 197e12 / 0.005)
     # a program older than the counters writes no such span: nothing, and
-    # no error; a slice without the attention counter likewise
+    # no error; a slice without the attention counters likewise
     monkeypatch.setattr(trace_op_counters, "slice_counters", lambda o: None)
     assert trace_op_counters.read(traced, m_attn["args"]) is None
     monkeypatch.setattr(trace_op_counters, "slice_counters",
                         lambda o: {"moe_pairs_held": 1.0})
     assert trace_op_counters.read(traced, m_attn["args"]) is None
+    assert trace_op_counters.read(traced, m_chunk["args"]) is None
 
 
 def test_work_counts_at_the_published_widths():
@@ -118,19 +126,35 @@ def test_work_counts_at_the_published_widths():
     assert fam.weight_bytes(cfg) / 1e9 == pytest.approx(9.47, abs=0.01)
     assert fam.held_pairs_per_token(cfg) == 1.0
     assert fam.layer_windows(cfg) == [4096, 4096, 4096, None]
-    # a decoded token at 5,170 keys: 2 x (non-routed + 1 pair x 4 layers
-    # + the head's slice) + attention over 5,170 + 3 x 4,096 keys
+    # of the keys the cell's tokens see in a full layer, a window layer
+    # sees the cycle's own share: every position of every request, by hand
+    from benchmarks.harness.loadgen import draw_sizes
+    p, o = draw_sizes(cell["traffic"], 48, 13_056)
+    ctx = np.concatenate([np.arange(1, n + 1) for n in p + o - 1])
+    clip = np.minimum(ctx, 4096).sum() / ctx.sum()
+    assert fam.window_clip_share(cfg, cell) == pytest.approx(clip)
+    assert 0.70 < clip < 0.82
+    # a token at a mean of 5,170 keys: 2 x (non-routed + 1 pair x 4
+    # layers + the head's slice) + attention over 5,170 x (1 + 3 x clip)
     got = fam.serve_flops_per_token(cfg, cell, {
         "mean_context_tokens": 5170.0, "head_tokens_per_processed": 1.0})
     want = 2 * (4 * 344_457_216 + 4 * 50_331_648 + 4096 * 32768) \
-        + 4 * (5170 + 3 * 4096) * 128 * 128
+        + 4 * 5170 * (1 + 3 * clip) * 128 * 128
     assert got == pytest.approx(want)
-    # under the window a window layer reads the context
-    short = fam.serve_flops_per_token(cfg, cell, {
-        "mean_context_tokens": 1000.0, "head_tokens_per_processed": 0.0})
-    assert short == pytest.approx(
-        2 * (4 * 344_457_216 + 4 * 50_331_648) + 4 * 4000 * 128 * 128)
+    # a cell of prompts under the window clips nothing
+    short = dict(cell, traffic=dict(cell["traffic"], prompt={
+        "dist": "constant", "value": 1000}))
+    assert fam.window_clip_share(cfg, short) == 1.0
+    assert fam.serve_flops_per_token(cfg, short, {
+        "mean_context_tokens": 1000.0, "head_tokens_per_processed": 0.0}) \
+        == pytest.approx(2 * (4 * 344_457_216 + 4 * 50_331_648)
+                         + 4 * 4000 * 128 * 128)
     assert fam.serve_flops_per_token(cfg, cell, {}) is None
+    # a chunk's attention: 4 x head_dim operations a pair a head
+    assert fam.window_chunk_attention(cfg, cell, {}) == {}
+    assert fam.window_chunk_attention(cfg, cell, {"slice_counters": {
+        "attn_chunk_pairs": 10.0}}) == {"flops": 10 * 4 * 128 * 128.0,
+                                        "bytes": 0.0}
     assert fam.window_decode_kv(cfg, cell, {}) == {}
     assert fam.moe_held_experts(cfg, cell, {}) == {}
     assert fam.window_decode_kv(cfg, cell, {"slice_counters": {
@@ -179,6 +203,28 @@ def test_the_control_comes_out_not_correct_at_toy_width():
         assert not np.array_equal(low, np.asarray(w[name]))
 
 
+def test_the_control_as_a_run_comes_out_not_correct(capsys, monkeypatch):
+    """`control_as_run.py`: the toy cell through `measure` with a
+    control's tokens in the served ones' place is refused by the run's own
+    comparison at the family's limits, by a gap limit and by nothing else;
+    the family's `token_gaps` is its own again afterwards. (The int8
+    control: the toy cell's sample is some forty tokens, too few for
+    float32's own control, bfloat16, to put another token first.)"""
+    from benchmarks import control_as_run
+    monkeypatch.setattr(control_gap, "controls_for", lambda dtype: ("int8",))
+    fam = lookup.family(common.load_json(os.path.join(
+        DATA, "configs", "toy-cohere.json")))
+    real = fam.token_gaps
+    assert control_as_run.main(["--workload", "toy-cohere-closed", "--seed",
+                                "3500000039", "--seconds", "1.5", "--cpu",
+                                DATA]) == 0
+    assert fam.token_gaps is real
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    over = {k for k, c in line["checks"].items() if c["value"] > c["limit"]}
+    assert not line["correct"] and over \
+        and over <= {"logit_gap_mean", "logit_gap_widest"}
+
+
 def test_the_configuration_file_against_the_catalog_and_the_manifest():
     man = common.load_json(os.path.join(common.REPO, "BENCHMARK.json"))
     entry = next(c for c in man["configs"]
@@ -210,7 +256,7 @@ def test_the_configuration_file_against_the_catalog_and_the_manifest():
         and t["driver"] == "closed_loop"
     assert (t["traffic"]["clients"], t["traffic"]["pool"],
             t["engine"]["max_batch_size"], t["engine"]["batch_buckets"],
-            t["engine"]["pages_buckets"]) == (96, 12, 48, [48], [816])
+            t["engine"]["pages_buckets"]) == (96, 48, 48, [48], [816])
     assert t["traffic"]["prompt"] == {"dist": "lognormal", "median": 4096,
                                       "sigma": 0.7, "min": 512, "max": 12288}
     assert t["traffic"]["output"] == {"dist": "lognormal", "median": 384,

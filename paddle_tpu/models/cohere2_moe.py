@@ -52,7 +52,6 @@ One layer, as published (`Cohere2MoeConfig` carries the config.json keys):
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 from types import SimpleNamespace
 from typing import Optional, Tuple
@@ -76,10 +75,13 @@ __all__ = ["Cohere2MoeConfig", "Cohere2MoeForCausalLM", "cohere2_moe_tiny",
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
-# the expert layers' four counts (`models/kimi_k2.py`) and, over the
-# attention layers of a DECODE span, the keys the step had to read: a
-# row's context in a full layer, at most the window in a window layer
-PAGED_COUNTERS = MOE_COUNTERS + ("attn_decode_keys",)
+# the expert layers' four counts (`models/kimi_k2.py`) and what the
+# attention layers had to see, whatever implements them. Over a DECODE
+# span, the keys: a row's context in a full layer, at most the window in a
+# window layer. Over a PREFILL chunk, the (query, key) pairs: a live
+# query at position p sees p + 1 keys in a full layer and at most the
+# window in a window layer. Each is 0 in the other kind of span.
+PAGED_COUNTERS = MOE_COUNTERS + ("attn_decode_keys", "attn_chunk_pairs")
 
 
 @dataclass
@@ -172,9 +174,10 @@ def _rope_rows(pos, head_dim, theta):
 
 
 def _dense_attention(q, k, v, qpos, kpos, sm_scale, window):
-    """q (S, H, D) against k, v (T, KVH, D) as an XLA composition: what a
-    prefill chunk falls back to where the flash kernel's tiling rule
-    refuses its shapes, and the uncached forward's attention."""
+    """q (S, H, D) against k, v (T, KVH, D) as an XLA composition, a
+    float32 score matrix of the whole sequence: the UNCACHED forward's
+    attention (tests, a few hundred positions). The paged path never
+    takes it."""
     g = q.shape[1] // k.shape[1]
     qg = q.reshape(q.shape[0], k.shape[1], g, q.shape[2])
     sc = jnp.einsum("sngd,tnd->ngst", qg, k,
@@ -256,24 +259,6 @@ class Cohere2MoeAttention(nn.Layer):
             return jnp.dot(o.reshape(b, s, -1), wo)
         return apply_op("cohere_attention", f, x, *self._weights())
 
-    def chunk_path(self, s, t, dtype) -> str:
-        """Which form a prefill chunk of `s` queries over `t` gathered
-        keys attends in: "flash" wherever the kernel's tiling rule takes
-        the shapes, else "xla", the composition, which keeps a float32
-        score matrix of the whole chunk and says so when it is chosen."""
-        from ..kernels.flash_attention import chunk_gqa_unsupported_reason
-        cfg = self.cfg
-        why = chunk_gqa_unsupported_reason(
-            s, t, cfg.num_attention_heads, cfg.num_key_value_heads,
-            cfg.head_dim, dtype)
-        if why is None:
-            return "flash"
-        warnings.warn(f"a prefill chunk of {s} tokens over {t} keys attends "
-                      f"through the XLA composition, not the flash kernel "
-                      f"({why}): pick chunk and table buckets in whole "
-                      f"tiles", stacklevel=2)
-        return "xla"
-
     def _chunk_keys(self, s, table_pages, page_size):
         """Pages a prefill chunk of `s` tokens gathers from a table of
         `table_pages`: the whole table in a full layer; in a window layer
@@ -323,13 +308,11 @@ class Cohere2MoeAttention(nn.Layer):
                     n * ps, pool.shape[1], pool.shape[3])
 
             kpos = lo * ps + jnp.arange(n * ps, dtype=jnp.int32)
-            if self.chunk_path(s, n * ps, xx.dtype) == "flash":
-                o = flash_attention_chunk_gqa(
-                    q[0], keys(kc), keys(vc), pp[0], kpos, sm_scale=scale,
-                    window=window)
-            else:
-                o = _dense_attention(q[0], keys(kc), keys(vc), pp[0], kpos,
-                                     scale, window)
+            # the flash kernel or nothing: it raises, when the program is
+            # built, for a chunk or table bucket its tiling rule refuses
+            o = flash_attention_chunk_gqa(
+                q[0], keys(kc), keys(vc), pp[0], kpos, sm_scale=scale,
+                window=window)
             return jnp.dot(o.reshape(1, s, -1), wo), kc, vc
 
         out, kc, vc = apply_op("cohere_paged_attention", f, x, *cache,
@@ -488,12 +471,18 @@ class Cohere2MoeForCausalLM(nn.Layer):
         cache): logits at the chunk's last live position (prefill) or of
         every row (decode), the caches, and PAGED_COUNTERS summed over
         the layers."""
+        from ..serving.lora.runtime import current_lora
         if span.kind not in ("decode", "prefill"):
             raise ValueError(f"span kind {span.kind!r} is not supported by "
                              f"this family")
+        if current_lora() is not None:
+            raise ValueError("this family's projections carry no adapter "
+                             "hooks: an engine with a LoRA registry would "
+                             "serve the base model under an adapter's name")
         cfg, m = self.cfg, self.model
         b, s = input_ids.shape
-        pos, count, first = (Tensor(a) for a in _span_positions(span, s))
+        positions, n_live, first = _span_positions(span, s)
+        pos, count, first = Tensor(positions), Tensor(n_live), Tensor(first)
         # the real tokens: the experts neither compute nor count the rest
         live = apply_op(
             "span_live", lambda c: jnp.arange(s)[None, :] < c[:, None], count)
@@ -507,17 +496,23 @@ class Cohere2MoeForCausalLM(nn.Layer):
                                       first, live)
             caches.append(cache)
             counts = counts + c._data
+        windows = [cfg.window_of(i) for i in range(cfg.num_hidden_layers)]
         if span.kind == "prefill":
-            keys = jnp.zeros((), jnp.int32)
+            # a live query at position p sees p + 1 keys; padding sees none
+            seen = jnp.where(jnp.arange(s) < n_live[0], positions[0] + 1, 0)
+            keys = jnp.stack([jnp.zeros((), jnp.int32), sum(
+                jnp.sum(seen if w is None else jnp.minimum(seen, w))
+                for w in windows)])
             x = apply_op(
                 "chunk_last", lambda hh, ln: jax.lax.dynamic_slice_in_dim(
                     hh, jnp.asarray(ln, jnp.int32).reshape(()) - 1, 1,
                     axis=1), x, span.live)
         else:
-            # a decoding row's context runs through its input token
+            # a decoding row's context: `start` counts its tokens THROUGH
+            # the input token (models/paged.py), 0 for a padded row
             ctx = jnp.asarray(span.start._data, jnp.int32)
-            keys = sum(jnp.sum(ctx if w is None else jnp.minimum(ctx, w))
-                       for w in map(cfg.window_of,
-                                    range(cfg.num_hidden_layers)))
-        counts = jnp.concatenate([counts, keys.astype(jnp.int32)[None]])
+            keys = jnp.stack([sum(
+                jnp.sum(ctx if w is None else jnp.minimum(ctx, w))
+                for w in windows), jnp.zeros((), jnp.int32)])
+        counts = jnp.concatenate([counts, keys.astype(jnp.int32)])
         return self._head(x), caches, counts

@@ -212,8 +212,8 @@ _LAUNCHES = {
 }
 
 # What an engine whose model has a windowed layer group counts beside the
-# model's own counters (PERF.md section 3): each step, over the rows in
-# flight, the pages the windowed groups hold and the pages they would
+# model's own counters (PERF.md section 3): each step, over the DECODING
+# rows, the pages the windowed groups hold and the pages they would
 # hold without their windows (the unbounded group's count a windowed
 # group); and the pages given back as rows advanced.
 _WINDOW_COUNTERS = ("kv_window_pages_held", "kv_window_pages_full",
@@ -2060,13 +2060,16 @@ class ServingEngine:
     def _window_gauges(self) -> dict:
         """update_gauges kwargs of the windowed layer groups, empty for
         a model of one group: each group's used pages, and this step's
-        part of `_WINDOW_COUNTERS` over the rows in flight. Host
+        part of `_WINDOW_COUNTERS` over the decoding rows. Host
         arithmetic over at most a batch of rows: no sync."""
         groups = self.allocator.windows
         if not groups:
             return {}
         c = self.metrics.counters
-        for r in self.scheduler.running + self.scheduler.prefilling:
+        # decoding rows only: a prefilling row's unbounded pages are taken
+        # whole at admission and its windowed pages a chunk at a time, so
+        # beside it the share would read better than the window earns
+        for r in self.scheduler.running:
             c["kv_window_pages_full"] += len(groups) * len(r.seq.pages)
             c["kv_window_pages_held"] += sum(
                 r.seq.window_held(g) for g in range(len(groups)))

@@ -159,9 +159,6 @@ FEATURE_CONFLICTS = {
         "tensor parallelism over a model with a windowed layer group is "
         "not supported yet: the windowed decode kernel has no per-shard "
         "wrapper",
-    frozenset({"windowed_cache", "lora"}):
-        "lora over a model with a windowed layer group is not supported "
-        "yet: the family's projections carry no adapter hooks",
 }
 
 

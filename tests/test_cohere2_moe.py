@@ -9,6 +9,7 @@ import functools
 import os
 import subprocess
 import sys
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -88,7 +89,7 @@ def paged_logits(model, ids, n_prompt, chunk=32):
                 kind, eng._state, pools, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(bt), jnp.asarray(start, jnp.int32),
                 None if live is None else jnp.int32(live))
-        assert counts.shape == (5,)
+        assert counts.shape == (6,)
         return np.asarray(lg, np.float32), np.asarray(counts)
 
     def tables(rows):
@@ -104,7 +105,12 @@ def paged_logits(model, ids, n_prompt, chunk=32):
         padded = np.zeros((1, chunk), np.int32)
         padded[0, :n] = ids[done:done + n]
         lg, counts = call(padded, tables(1)[:, 0], "prefill", done, n)
-        assert counts[4] == 0            # chunk spans count no decode keys
+        # a chunk counts no decode keys; its live queries at positions
+        # done .. done + n - 1 see position + 1 keys in the full layer and
+        # at most the window in each of the three window layers
+        seen = np.arange(done, done + n) + 1
+        assert counts[4] == 0 and counts[5] == \
+            seen.sum() + 3 * np.minimum(seen, WINDOW).sum()
         done += n
         alloc.advance_windows(seq, done, done)
     out.append(lg[0, 0])
@@ -113,7 +119,9 @@ def paged_logits(model, ids, n_prompt, chunk=32):
         assert seq.window_held(0) <= WINDOW // PAGE + 1
         # row 1 is a padded row (length 0): it must neither write nor count
         lg, counts = call([[ids[j]], [0]], tables(2), "decode", [j + 1, 0])
-        assert counts[4] == (j + 1) + 3 * min(j + 1, WINDOW)
+        # ... and the live row counts its context THROUGH its input token
+        assert counts[4] == (j + 1) + 3 * min(j + 1, WINDOW) \
+            and counts[5] == 0
         out.append(lg[0, 0])
     alloc.free_sequence(seq)
     alloc.check_invariants()
@@ -522,6 +530,33 @@ def test_the_model_refuses_int8_pages_a_model_axis_and_a_verify_span(served):
     with pytest.raises(ValueError, match="verify"):
         model.paged_forward(paddle.Tensor(jnp.zeros((1, 2), jnp.int32)), [],
                             None, PagedSpan("verify", None, None))
+    # ... and adapters: its projections carry no hooks, and an engine with
+    # a registry would serve the base model under an adapter's name
+    from paddle_tpu.serving.lora.runtime import lora_scope
+    with lora_scope(object()), pytest.raises(ValueError, match="adapter"):
+        model.paged_forward(paddle.Tensor(jnp.zeros((1, 2), jnp.int32)), [],
+                            None, PagedSpan("decode", None))
+
+
+def test_a_chunk_bucket_the_flash_kernel_refuses_is_refused_not_composed(
+        served):
+    """A prefill chunk attends through the flash kernel or not at all: a
+    bucket its tiling rule refuses (15 queries x 4 heads a KV head are not
+    whole sublanes) raises when its program is built, with the rule, and
+    no float32 score matrix of the chunk is composed in its place."""
+    eng = engine(served[0], prefill_buckets=[15], token_budget=15)
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            with pytest.raises(ValueError, match="multiple of 8"):
+                with paddle.no_grad():
+                    eng._build_chunk(15, 32).lower(
+                        eng._state, *eng._cache_lists(),
+                        jnp.zeros((1, 15), jnp.int32), jnp.int32(0),
+                        jnp.int32(15), jnp.zeros((2, 32), jnp.int32),
+                        eng._null_key)
+    finally:
+        eng.shutdown()
 
 
 # ------------------------------------------------- the benchmark's look-ups
