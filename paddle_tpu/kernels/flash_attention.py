@@ -146,9 +146,53 @@ def _unpack_refs(refs, n_fixed, use_seg, fm_cols):
     return fixed, segq_ref, segk_ref, fm_ref, refs[idx:]
 
 
+# The block rule where segments and positions are data, stated ONCE. The
+# forward reads it off every block at once (`_seg_block_table`: (nq, 1)
+# against (1, nk) arrays, handed to the kernel in scalar memory); the two
+# backward kernels read it off one block's own vectors, as scalars. A
+# side's bounds are (segment min, segment max, position min, position max)
+# of its block.
+def _block_bounds(seg_blk, pos_blk):
+    return (jnp.min(seg_blk), jnp.max(seg_blk),
+            jnp.min(pos_blk), jnp.max(pos_blk))
+
+
+def _seg_block_contributes(qb, kb, causal, window):
+    """Whether a (q block, k block) tile can hold any visible score."""
+    # contiguous segment ids: ranges must overlap
+    contributes = jnp.logical_and(qb[0] <= kb[1], qb[1] >= kb[0])
+    if causal:
+        # the packed-global causal bound is invalid with per-sequence
+        # alignment; skip instead when both blocks sit in one shared
+        # sequence and every key position exceeds every query position
+        one_seq = jnp.logical_and(qb[0] == kb[1], qb[1] == kb[0])
+        all_future = kb[2] > qb[3]
+        contributes = jnp.logical_and(contributes, jnp.logical_not(
+            jnp.logical_and(one_seq, all_future)))
+    if window is not None:
+        # every key lies behind every query's window
+        all_past = kb[3] <= qb[2] - np.int32(window)
+        contributes = jnp.logical_and(contributes,
+                                      jnp.logical_not(all_past))
+    return contributes
+
+
+def _seg_block_table(segq, segk, block_q, block_k, causal, window):
+    """(B, nq, nk) bool: the rule above over every block of the (B, 2, S)
+    [segment; position] rows, the tiles the forward computes.
+    `_fwd_positions` hands the kernel this table."""
+    def bounds(rows, block, axis):
+        seg, pos = (rows[:, i].reshape(rows.shape[0], -1, block)
+                    for i in (0, 1))
+        return tuple(jnp.expand_dims(x, axis) for x in (
+            seg.min(-1), seg.max(-1), pos.min(-1), pos.max(-1)))
+    return _seg_block_contributes(bounds(segq, block_q, 2),
+                                  bounds(segk, block_k, 1), causal, window)
+
+
 def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
                        segq_blk, segk_blk, posq_blk=None, posk_blk=None,
-                       fm_blk=None, fm_causal=True, fm_cols=0, window=None):
+                       fm_blk=None, fm_causal=True, fm_cols=0):
     """Whether this (q block, k block) tile can contain any unmasked score
     (cheap bound checks -> pl.when skips the matmuls entirely)."""
     if causal and segq_blk is None:
@@ -156,27 +200,9 @@ def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
     else:
         contributes = ki >= 0
     if segq_blk is not None:
-        # contiguous segment ids: ranges must overlap
-        overlap = jnp.logical_and(jnp.min(segq_blk) <= jnp.max(segk_blk),
-                                  jnp.max(segq_blk) >= jnp.min(segk_blk))
-        contributes = jnp.logical_and(contributes, overlap)
-        if causal:
-            # the packed-global causal bound is invalid with per-sequence
-            # alignment; skip instead when both blocks sit in one shared
-            # sequence and every key position exceeds every query position
-            one_seq = jnp.logical_and(
-                jnp.min(segq_blk) == jnp.max(segk_blk),
-                jnp.max(segq_blk) == jnp.min(segk_blk))
-            all_future = jnp.min(posk_blk) > jnp.max(posq_blk)
-            contributes = jnp.logical_and(
-                contributes,
-                jnp.logical_not(jnp.logical_and(one_seq, all_future)))
-        if window is not None:
-            # every key lies behind every query's window
-            all_past = jnp.max(posk_blk) <= \
-                jnp.min(posq_blk) - np.int32(window)
-            contributes = jnp.logical_and(contributes,
-                                          jnp.logical_not(all_past))
+        contributes = jnp.logical_and(contributes, _seg_block_contributes(
+            _block_bounds(segq_blk, posq_blk),
+            _block_bounds(segk_blk, posk_blk), causal, None))
     if fm_cols == 1 and fm_causal and fm_blk is not None:
         # document mask: every row/col masked iff first q row >= max(start)
         q0 = qi * block_q
@@ -186,15 +212,18 @@ def _block_contributes(qi, ki, *, block_q, block_k, q_offset, causal,
 
 
 def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
-                use_seg, fm_causal, fm_cols, window=None):
+                fm_causal, fm_cols):
+    """The forward where the mask is a function of the tile's place (plain
+    causal: the train step's) or of flashmask columns. Segments and
+    positions as data have a kernel of their own, `_fwd_positions_kernel`."""
     sm_scale = np.float32(sm_scale)  # strong f32: x64 mode makes bare
     # python/np floats f64, which Mosaic cannot store into f32 refs
-    (q_ref, k_ref, v_ref), segq_ref, segk_ref, fm_ref, rest = _unpack_refs(
-        refs, 3, use_seg, fm_cols)
+    (q_ref, k_ref, v_ref), _, _, fm_ref, rest = _unpack_refs(
+        refs, 3, False, fm_cols)
     o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    masked_rows = use_seg or fm_cols  # rows may see no valid key yet
+    masked_rows = fm_cols  # rows may see no valid key yet
 
     @pl.when(ki == 0)
     def _init():
@@ -202,16 +231,11 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    segq_blk = segq_ref[0, 0] if use_seg else None
-    posq_blk = segq_ref[0, 1] if use_seg else None
-    segk_blk = segk_ref[0, 0] if use_seg else None
-    posk_blk = segk_ref[0, 1] if use_seg else None
     fm_blk = fm_ref[0] if fm_cols else None
     contributes = _block_contributes(
         qi, ki, block_q=block_q, block_k=block_k, q_offset=q_offset,
-        causal=causal, segq_blk=segq_blk, segk_blk=segk_blk,
-        posq_blk=posq_blk, posk_blk=posk_blk, fm_blk=fm_blk,
-        fm_causal=fm_causal, fm_cols=fm_cols, window=window)
+        causal=causal, segq_blk=None, segk_blk=None, fm_blk=fm_blk,
+        fm_causal=fm_causal, fm_cols=fm_cols)
 
     @pl.when(contributes)
     def _step():
@@ -222,10 +246,8 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
         s = _apply_masks(s, qi, ki, block_q=block_q, block_k=block_k,
-                         q_offset=q_offset, causal=causal, segq_blk=segq_blk,
-                         segk_blk=segk_blk, posq_blk=posq_blk,
-                         posk_blk=posk_blk, fm_blk=fm_blk,
-                         fm_causal=fm_causal, fm_cols=fm_cols, window=window)
+                         q_offset=q_offset, causal=causal, fm_blk=fm_blk,
+                         fm_causal=fm_causal, fm_cols=fm_cols)
         m_prev = m_ref[:, :1]                      # (bq, 1), lanes equal
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
@@ -285,37 +307,30 @@ def _extra_in_specs(B, H, Sq, Sk, block_q, block_k, use_seg, fm_cols, fm_heads,
     return specs
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
-         fm_causal=True, H=1, window=None):
+def _fwd(q, k, v, sm_scale, causal, block_q, block_k, fm=None,
+         fm_causal=True, H=1):
     """(BH, Sq, D) x (BH, Sk, D)^2 -> out (BH, Sq, D), lse (BH, Sq) f32.
 
-    seg: optional (segq (B,2,Sq), segk (B,2,Sk)) int32 [segment id;
-    causal position-within-sequence] rows.
-    fm: optional (B*Hm, C, Sk) flashmask bounds.
-    window: optional int, with `seg` and `causal`: a query also sees no
-    key `window` or more positions behind it; blocks that lie wholly
-    behind are skipped. Forward only (`flash_attention_chunk_gqa`)."""
+    fm: optional (B*Hm, C, Sk) flashmask bounds. (Segment ids and
+    positions as data: `_fwd_positions`.)"""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     nq = Sq // block_q
     nk = Sk // block_k
     grid = (BH, nq, nk)
-    use_seg = seg is not None
     fm_cols = fm.shape[1] if fm is not None else 0
     fm_heads = (fm.shape[0] * H) // BH if fm is not None else 1
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk, q_offset=Sk - Sq, use_seg=use_seg,
-        fm_causal=fm_causal, fm_cols=fm_cols, window=window)
+        block_k=block_k, nk=nk, q_offset=Sk - Sq, fm_causal=fm_causal,
+        fm_cols=fm_cols)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, _I0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, _I0)),
-    ] + _extra_in_specs(BH // H, H, Sq, Sk, block_q, block_k, use_seg,
+    ] + _extra_in_specs(BH // H, H, Sq, Sk, block_q, block_k, False,
                         fm_cols, fm_heads)
     args = [q, k, v]
-    if use_seg:
-        args += [seg[0], seg[1]]
     if fm_cols:
         args.append(fm)
     out, lse3 = pl.pallas_call(
@@ -341,6 +356,177 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, seg=None, fm=None,
         name="flash_attention_fwd",
     )(*args)
     return out, lse3[:, 0, :]
+
+
+# ------------------------------------------- forward, positions are data
+# The block table's room: a word a tile over the batch rows, in half of a
+# v5e's 1 MiB of scalar memory (a table of 2^18 words is refused by the
+# compiler; 2^17 is 131,072 packed tokens a row at tiles of 1,024, two
+# such rows at 512).
+_TABLE_TILES = 1 << 17
+
+
+def _fwd_positions_kernel(table_ref, span_ref, q_ref, k_ref, v_ref, segq_ref,
+                          segk_ref, o_ref, *rest, sm_scale, causal, block_q,
+                          block_k, nq, nk, H, window):
+    """`_fwd_kernel` where segments and positions are data (every serving
+    chunk; nothing a train step runs). Whether a tile is computed is read
+    from `table_ref` (`_seg_block_table`, in scalar memory). Both products
+    take their operands in the dtype they are stored in and accumulate in
+    float32; max, sum and the accumulator are float32."""
+    del span_ref                                   # the index maps' own
+    *lse_ref, acc_ref, m_ref, l_ref = rest         # lse: only if asked for
+    sm_scale = np.float32(sm_scale)                # strong f32, as above
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    row = jax.lax.div(pl.program_id(0), np.int32(H)) * np.int32(nq) + qi
+
+    @pl.when(table_ref[row * np.int32(nk) + ki] != 0)
+    def _step():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]     # (bq, D), (bk, D) x 2
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = _apply_masks(
+            s * sm_scale, qi, ki, block_q=block_q, block_k=block_k,
+            q_offset=0, causal=causal, segq_blk=segq_ref[0, 0],
+            segk_blk=segk_ref[0, 0], posq_blk=segq_ref[0, 1],
+            posk_blk=segk_ref[0, 1], window=window)
+        m_prev = m_ref[:, :1]                      # (bq, 1), lanes equal
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = _masked_exp(s, m_new)
+        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                          jnp.exp(m_prev - m_new))
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        l = l_ref[:, :1]
+        safe_l = jnp.maximum(l, np.float32(1e-30))
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        if lse_ref:             # a column laid along lanes: not for nothing
+            lse_ref[0][0, 0] = m_ref[:, 0] + jnp.log(safe_l[:, 0])
+
+
+def _fwd_positions(q, k, v, sm_scale, causal, block_q, block_k, seg, H,
+                   window=None, with_lse=True):
+    """`_fwd` where segments and positions are data (`_fwd_positions_once`
+    has the arguments): traced and lowered ONCE a program, however many
+    layers call it with the same shapes. A serving program calls it a
+    layer, and without the inner jit each call's trace and Mosaic lowering
+    are paid again (Kimi's chunk programs of seven calls: 5.6 s a program
+    against 2.4, PR 37). Raises ValueError where the block table would not
+    fit in scalar memory."""
+    tiles = (q.shape[0] // H) * (q.shape[1] // block_q) * (
+        k.shape[1] // block_k)
+    if tiles > _TABLE_TILES:
+        raise ValueError(
+            f"{tiles} (q block, k block) tiles of {block_q} x {block_k}: "
+            f"the block table holds {_TABLE_TILES} in scalar memory")
+    return _fwd_positions_once(
+        q, k, v, seg, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, H=H, window=window, with_lse=with_lse,
+        interpret=_interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "causal", "block_q", "block_k", "H", "window", "with_lse",
+    "interpret"))
+def _fwd_positions_once(q, k, v, seg, *, sm_scale, causal, block_q, block_k,
+                        H, window, with_lse, interpret):
+    """`_fwd` where segments and positions are data: `seg` is (segq (B, 2,
+    Sq), segk (B, 2, Sk)) int32 [segment id; causal position-within-
+    sequence] rows. window: optional int, with `causal`: a query also sees
+    no key `window` or more positions behind it (forward only:
+    `flash_attention_chunk_gqa`). with_lse=False, where nothing will be
+    differentiated, returns None for lse and the kernel does not work it
+    out (a column laid along lanes, a q block at a time).
+
+    The block rule is worked out ONCE, outside the kernel, over every
+    tile (`_seg_block_table`) and handed over in scalar memory: the
+    kernel reads one word instead of reducing four vectors to scalars a
+    step, and the K/V (and key-row) fetches of a q block are
+    clamped to the first and last tile it computes, so the skipped tiles
+    before and after them (a chunk's future, the keys behind its window,
+    the table past the sequence's end) are not fetched at all: the
+    pipeline fetches nothing where the block index does not change."""
+    BH, Sq, D = q.shape
+    Sk = k.shape[1]
+    nq, nk = Sq // block_q, Sk // block_k
+    segq, segk = seg
+    contributes = _seg_block_table(segq, segk, block_q, block_k, causal,
+                                   window)
+    first = jnp.argmax(contributes, axis=-1).astype(jnp.int32)
+    last = np.int32(nk - 1) - jnp.argmax(
+        contributes[..., ::-1], axis=-1).astype(jnp.int32)
+    span = jnp.stack([first, last], axis=-1)       # (B, nq, 2)
+    # The index maps are evaluated some steps past the last one (PR 28:
+    # `kernels/paged_attention.py`): `span` ends in zeros enough for a
+    # whole further row of q blocks, and `kblock` clips whatever it reads.
+    table, span = (jnp.pad(a.reshape(-1), (0, -a.size % 128 + tail))
+                   for a, tail in ((contributes.astype(jnp.int32), 128),
+                                   (span, 128 * (1 + nq // 64))))
+
+    def bdiv(b):
+        return jax.lax.div(b, np.int32(H))         # as `_extra_in_specs`
+
+    def kblock(b, i, j, span):
+        at = (bdiv(b) * np.int32(nq) + i) * np.int32(2)
+        j = jnp.minimum(jnp.maximum(j, span[at]), span[at + np.int32(1)])
+        return jnp.clip(j, _I0, np.int32(nk - 1))
+
+    kernel = functools.partial(
+        _fwd_positions_kernel, sm_scale=sm_scale, causal=causal,
+        block_q=block_q, block_k=block_k, nq=nq, nk=nk, H=H, window=window)
+    kv_spec = pl.BlockSpec(
+        (1, block_k, D), lambda b, i, j, table, span: (
+            b, kblock(b, i, j, span), _I0))
+    out, *lse3 = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j, *_: (b, i, _I0)),
+                kv_spec, kv_spec,
+                # rows: [segment id, causal position-within-sequence]
+                pl.BlockSpec((1, 2, block_q),
+                             lambda b, i, j, *_: (bdiv(b), _I0, i)),
+                pl.BlockSpec((1, 2, block_k),
+                             lambda b, i, j, table, span: (
+                                 bdiv(b), _I0, kblock(b, i, j, span))),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j, *_: (b, i, _I0)),
+                pl.BlockSpec((1, 1, block_q), lambda b, i, j, *_: (b, _I0, i)),
+            ][:1 + with_lse],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
+                pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
+        ][:1 + with_lse],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="flash_attention_fwd",
+    )(table, span, q, k, v, segq, segk)
+    return out, (lse3[0][:, 0, :] if with_lse else None)
 
 
 def _dq_kernel(*refs, sm_scale, causal, block_q, block_k, nk, q_offset,
@@ -574,15 +760,15 @@ def _int_zero(x):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_core_seg(q, k, v, segq, segk, sm_scale, causal, block_q, block_k,
                     H):
-    out, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                  seg=(segq, segk), H=H)
+    out, _ = _fwd_positions(q, k, v, sm_scale, causal, block_q, block_k,
+                            (segq, segk), H, with_lse=False)
     return out
 
 
 def _flash_core_seg_fwd(q, k, v, segq, segk, sm_scale, causal, block_q,
                         block_k, H):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                    seg=(segq, segk), H=H)
+    out, lse = _fwd_positions(q, k, v, sm_scale, causal, block_q, block_k,
+                              (segq, segk), H)
     return out, (q, k, v, out, lse, segq, segk)
 
 
@@ -805,6 +991,18 @@ def chunk_gqa_unsupported_reason(s, t, heads, kv_heads, d, dtype):
                               (1, t, 1, d), dtype)
 
 
+def _chunk_gqa_keys(t, d):
+    """The count of keys `flash_attention_chunk_gqa` runs over `t` gathered
+    ones: `t`, or the next count, 128 at a time, that divides into key
+    tiles of half the largest or more. (A window of 4,096 and a chunk of
+    512 or 1,024 tokens gather 37 and 41 x 128 keys, which divide into
+    128-key tiles only: such a call took longer than the 2,048-token
+    chunk's, PR 37.)"""
+    while _pick_block_k(t, d) < min(_block_cap(d) // 2, t):
+        t += 128
+    return t
+
+
 def flash_attention_chunk_gqa(q, k, v, q_positions, kv_positions, *,
                               sm_scale=None, window=None):
     """ONE sequence's prefill chunk over its gathered keys, grouped-query
@@ -814,11 +1012,15 @@ def flash_attention_chunk_gqa(q, k, v, q_positions, kv_positions, *,
     (S, H, D).
 
     The G = H / KVH query heads of a KV head are laid along the query
-    axis, (KVH, G * S, D) against (KVH, T, D), so K and V are never
+    axis, (KVH, S * G, D) against (KVH, T, D), so K and V are never
     repeated G times (at 128 query heads over 8 that is 16 x a 13,056-key
-    table a layer). Positions are data, as in the varlen form: key
-    blocks wholly in a query block's future, or wholly behind its
-    window, are skipped (`_block_contributes`)."""
+    table a layer). A position's G heads lie side by side, so a q block
+    of 1,024 rows spans 1,024 / G positions and few of its key blocks
+    straddle the causal or the window's edge. Positions are data, as in
+    the varlen form: key blocks wholly in a query block's future, or
+    wholly behind its window, are neither computed nor fetched
+    (`_fwd_positions`); where `T` tiles badly the keys are padded with
+    such blocks' kind, keys in every query's future (`_chunk_gqa_keys`)."""
     S, H, D = q.shape
     T, KVH, _ = k.shape
     G = H // KVH
@@ -827,18 +1029,21 @@ def flash_attention_chunk_gqa(q, k, v, q_positions, kv_positions, *,
         raise ValueError(why)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
-    qf = jnp.transpose(q.reshape(S, KVH, G, D), (1, 2, 0, 3))
-    qf = qf.reshape(KVH, G * S, D)
-    kf, vf = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
-    qpos = jnp.tile(q_positions.astype(jnp.int32), G)
-    kpos = kv_positions.astype(jnp.int32)
-    segq = jnp.stack([jnp.ones_like(qpos), qpos])[None]      # (1, 2, G*S)
-    segk = jnp.stack([jnp.ones_like(kpos), kpos])[None]
-    out, _ = _fwd(qf, kf, vf, float(sm_scale), True,
-                  int(_pick_block_q(G * S, D)), int(_pick_block_k(T, D)),
-                  seg=(segq, segk), H=KVH, window=window)
-    out = jnp.transpose(out.reshape(KVH, G, S, D), (2, 0, 1, 3))
-    return out.reshape(S, H, D)
+    pad = _chunk_gqa_keys(T, D) - T
+    qf = jnp.swapaxes(q.reshape(S, KVH, G, D), 0, 1).reshape(KVH, S * G, D)
+    kf, vf = (jnp.pad(jnp.swapaxes(x, 0, 1), ((0, 0), (0, pad), (0, 0)))
+              for x in (k, v))
+    qpos = jnp.repeat(q_positions.astype(jnp.int32), G)
+    kpos = jnp.pad(kv_positions.astype(jnp.int32), (0, pad),
+                   constant_values=np.iinfo(np.int32).max)
+    # rows: [segment id, position]; one segment
+    seg = (jnp.stack([jnp.ones_like(qpos), qpos])[None],
+           jnp.stack([jnp.ones_like(kpos), kpos])[None])
+    out, _ = _fwd_positions(
+        qf, kf, vf, float(sm_scale), True, int(_pick_block_q(S * G, D)),
+        int(_pick_block_k(T + pad, D)), seg, KVH, window=window,
+        with_lse=False)
+    return jnp.swapaxes(out.reshape(KVH, S, G, D), 0, 1).reshape(S, H, D)
 
 
 def flashmask_attention_bshd(q, k, v, startend_row_indices, causal=True,

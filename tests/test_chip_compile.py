@@ -16,6 +16,8 @@ libtpu keeps its lock); all cases live in this ONE file so one xdist
 worker owns them; JAX's persistent cache is off around them (an entry
 compiled for a described chip cannot be read back without one).
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,6 +82,42 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+# a `tpu_custom_call`'s configuration in a program's lowered text
+KERNEL_CONFIG = r'backend_config = "((?:[^"\\]|\\.)*)"'
+
+
+def _kernel_bodies(lowered_text):
+    """The Mosaic module of every `tpu_custom_call` in a program's LOWERED
+    text, as MLIR text without debug locations. The lowered text carries
+    each kernel as serialized bytecode whose locations are the kernel
+    file's line numbers: decoded and printed without them, an edit
+    elsewhere in the file leaves the text as it was."""
+    import base64
+    import json
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    bodies = []
+    for found in re.finditer(KERNEL_CONFIG, lowered_text):
+        config = json.loads(found.group(1).replace("\\22", '"')
+                            .replace("\\5C", "\\"))
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True   # `stable_mosaic`'s ops
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"]))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+    return bodies
+
+
+def _matmul_operands(body):
+    """[(lhs element type, rhs element type)] of a kernel's products."""
+    import re
+    return re.findall(
+        r'tpu\.matmul"\(.*?: \(vector<[0-9x]+x(\w+)>, vector<[0-9x]+x(\w+)>',
+        body)
+
+
 # Train phase: batch 2 x seq 2048, 32 heads x 128 (LlamaConfig() widths).
 QKV = (2, 2048, 32, 128)
 
@@ -103,13 +141,79 @@ def test_flash_backward(for_chip, one_chip):
     assert text.count(MARKER) >= 2          # forward + backward kernels
 
 
-def test_flash_varlen(for_chip, one_chip):
-    q = _sds((1, 4096, 32, 128), BF16, one_chip)
-    seg = _sds((1, 4096), jnp.int32, one_chip)
-    text = _compiled_text(
-        lambda q, k, v, sq, sk: fa.flash_attention_varlen_bshd(
-            q, k, v, sq, sk, causal=True), q, q, q, seg, seg)
-    assert MARKER in text
+TRAIN_FORM_TEXTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "data", "flash_train_form_texts.json")
+
+
+def _train_form_digests(one_chip):
+    """SHA-256 of the text lowered for the described chip of
+    `flash_attention_bshd` at the train cell's shape, forward and
+    forward + backward, each kernel's module in `_kernel_bodies`' form
+    (the serialized form carries the kernel file's line numbers)."""
+    import hashlib
+    import re
+    q = _sds(QKV, BF16, one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention_bshd(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    digests = {}
+    for name, fn in (
+            ("forward", lambda q, k, v: fa.flash_attention_bshd(
+                q, k, v, causal=True)),
+            ("forward_backward", jax.grad(loss, argnums=(0, 1, 2)))):
+        text = jax.jit(fn).lower(q, q, q).as_text()
+        bodies = _kernel_bodies(text)
+        assert len(bodies) == {"forward": 1, "forward_backward": 3}[name]
+        outer = re.sub(KERNEL_CONFIG, "backend_config = <kernel>", text)
+        digests[name] = hashlib.sha256(
+            "\n".join([outer] + bodies).encode()).hexdigest()
+    return digests
+
+
+def test_the_train_forms_lower_to_the_text_they_had(for_chip, one_chip):
+    """The plain-causal forward and the two backward kernels, which the
+    train step runs, are the kernels of the commit the data file names
+    (PR 37 changed the forward where positions are data, and only there:
+    PR 36 was refused for what a change to all forms cost the train cell's
+    set-up). Written by `python tests/test_chip_compile.py` from a
+    `git archive` of that commit; skips under another jax."""
+    import json
+    with open(TRAIN_FORM_TEXTS) as f:
+        kept = json.load(f)
+    if kept["jax"] != jax.__version__:
+        pytest.skip(f"the texts were lowered under jax {kept['jax']}")
+    assert _train_form_digests(one_chip) == kept["sha256"], (
+        f"a train form no longer lowers to the text of {kept['commit']}")
+
+
+@pytest.mark.parametrize("case", ["d128", "d128-positions", "kimi-d192",
+                                  "table-full"])
+def test_flash_varlen(for_chip, one_chip, case):
+    """`d128`: positions from the segment ids, `nn.functional`'s default.
+    `kimi-d192`: 2,048 queries over 5,120 keys with explicit positions,
+    `serve-reasoning-decode`'s chunk (tiles of 512). `table-full`: two
+    packed rows of 131,072 tokens, the most tiles the block table takes in
+    scalar memory (`_TABLE_TILES`)."""
+    B, Sq, Sk, H, D = {"d128": (1, 4096, 4096, 32, 128),
+                       "d128-positions": (1, 4096, 4096, 32, 128),
+                       "kimi-d192": (1, 2048, 5120, 64, 192),
+                       "table-full": (2, 131072, 131072, 1, 192)}[case]
+    assert B * (Sq // fa._pick_block_q(Sq, D)) * (
+        Sk // fa._pick_block_k(Sk, D)) <= fa._TABLE_TILES
+    q, k = (_sds((B, n, H, D), BF16, one_chip) for n in (Sq, Sk))
+    segq, segk = (_sds((B, n), jnp.int32, one_chip) for n in (Sq, Sk))
+    positions = (segq, segk) if case in ("d128-positions", "kimi-d192") \
+        else (None, None)
+    lowered = jax.jit(
+        lambda q, k, v, sq, sk, pq, pk: fa.flash_attention_varlen_bshd(
+            q, k, v, sq, sk, causal=True, q_positions=pq, kv_positions=pk)
+    ).lower(q, k, k, segq, segk, *positions)
+    assert MARKER in lowered.compile().as_text()
+    body, = _kernel_bodies(lowered.as_text())
+    # both products take their operands as stored
+    assert _matmul_operands(body) == [("bf16", "bf16")] * 2
 
 
 def test_flashmask(for_chip, one_chip):
@@ -225,19 +329,28 @@ def test_paged_attention_decode_mixed_context_cell(for_chip, one_chip,
     assert "paged_attention_decode" in text and MARKER in text
 
 
-@pytest.mark.parametrize("keys,window", [(392 * 16, 4096), (816 * 16, None)],
-                         ids=["window", "full"])
-def test_flash_chunk_gqa_mixed_context_cell(for_chip, one_chip, keys,
+@pytest.mark.parametrize(
+    "S,keys,window", [(2048, 392 * 16, 4096), (2048, 816 * 16, None),
+                      (1024, 328 * 16, 4096), (512, 296 * 16, 4096)],
+    ids=["window", "full", "window-s1024", "window-s512"])
+def test_flash_chunk_gqa_mixed_context_cell(for_chip, one_chip, S, keys,
                                             window):
-    S, H, KVH, D = 2048, 128, 8, 128
+    """The cell's chunk buckets over what `Cohere2MoeAttention._chunk_keys`
+    gathers for them in a window layer (the two smaller ones' counts are
+    padded by the kernel), and the table in the full one."""
+    H, KVH, D = 128, 8, 128
     assert fa.chunk_gqa_unsupported_reason(S, keys, H, KVH, D, BF16) is None
     kv = _sds((keys, KVH, D), BF16, one_chip)
-    text = _compiled_text(
+    lowered = jax.jit(
         lambda q, k, v, qp, kp: fa.flash_attention_chunk_gqa(
-            q, k, v, qp, kp, window=window),
+            q, k, v, qp, kp, window=window)).lower(
         _sds((S, H, D), BF16, one_chip), kv, kv,
         _sds((S,), jnp.int32, one_chip), _sds((keys,), jnp.int32, one_chip))
+    text = lowered.compile().as_text()
     assert "flash_attention_fwd" in text and MARKER in text
+    body, = _kernel_bodies(lowered.as_text())
+    # both products take their operands as stored
+    assert _matmul_operands(body) == [("bf16", "bf16")] * 2
 
 
 def test_quant_matmul(for_chip, one_chip):
@@ -563,3 +676,20 @@ def test_the_ids_of_a_launch_ahead_are_built_from_rows_alone(for_chip,
     sizes = [n for body in _hlo_computations(text).values()
              for _, n, _, _ in body if n is not None]
     assert sizes and max(sizes) <= 1024
+
+
+if __name__ == "__main__":
+    # writes tests/data/flash_train_form_texts.json from the checkout on
+    # PYTHONPATH (a `git archive` of the commit the train forms are held to):
+    #   JAX_PLATFORMS=cpu PYTHONPATH=<that checkout> TEXTS_OF=<commit> \
+    #       python tests/test_chip_compile.py > tests/data/flash_train_form_texts.json
+    import json
+    import sys
+    from jax.experimental import topologies
+    fa._INTERPRET_CACHE[0] = False
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    print("kernels of", fa.__file__, file=sys.stderr)
+    print(json.dumps({"commit": os.environ["TEXTS_OF"],
+                      "jax": jax.__version__,
+                      "sha256": _train_form_digests(chip)}, indent=1))
