@@ -20,15 +20,22 @@ the entry's width a sequence and returns H rows of width R.
 
 Design (the page-16 lessons of `paged_attention.py`, PR 28): the block
 table and lengths ride scalar prefetch; the grid is the flat list of the
-rows' LIVE steps; a step gathers `fold` pages (one BlockSpec each) and
-computes on them as one token tile of T = fold * page_size tokens: one
-(H, R + Dr) x (R + Dr, T) dot, one online-softmax update on lane-dense
-(H, T) scores, one (H, T) x (T, R) dot. H heads share each entry's 2 x
-(R + Dr) bytes (1,152 as published): 2 H (2 R + Dr) / (2 (R + Dr)) =
-121 FLOP a byte at H 64 against a v5e's ridge of 240, so bandwidth and
-the MXU sit side by side: the dots take the cache's own type (bfloat16 on
-the chip: an fp32 upcast as in the GQA kernel would make it
-compute-bound) and accumulate in float32.
+rows' LIVE steps; a step computes on a token tile of T = fold * page_size
+tokens: one (H, R + Dr) x (R + Dr, T) dot, one online-softmax update on
+lane-dense (H, T) scores, one (H, T) x (T, R) dot. The kernel gathers a
+tile's `fold` pages itself: the pool stays in HBM (`pl.ANY`), and step w
+starts the copies of step w + 1's pages into the other half of a two-tile
+VMEM buffer (the steps run in order, so the next step may be the next
+row's first) and waits on its own. Why not a BlockSpec a page: the
+pipeline pays an index map, a compare and a copy's start and wait for
+every block, 80 ns for a 16-token page, 0.87 ms a layer at the serving
+cell's call (v5e), where moving the page takes 25. Dead slots of a row's
+last tile name page 0, the pad page: they are copied and masked. H heads
+share each entry's 2 x (R + Dr) bytes (1,152 as published): 2 H (2 R +
+Dr) / (2 (R + Dr)) = 121 FLOP a byte at H 64 against a v5e's ridge of
+240, so bandwidth and the MXU sit side by side: the dots take the
+cache's own type (bfloat16 on the chip: an fp32 upcast as in the GQA
+kernel would make it compute-bound) and accumulate in float32.
 """
 from __future__ import annotations
 
@@ -47,7 +54,11 @@ __all__ = ["mla_paged_decode", "mla_paged_write", "mla_page_bytes",
            "mla_entry_width", "check_supported_mla", "mla_fold_pages"]
 
 _I0 = np.int32(0)
-_TILE_TOKENS = 256
+# A step's token tile, the faster of 256 and 512 at the serving cell's call
+# with the kernel's own copies: 0.642 / 0.487 ms (v5e). Each step
+# costs about 0.5 us however few tokens it holds; a row's last tile costs
+# the bytes of its dead slots.
+_TILE_TOKENS = 512
 
 
 def mla_entry_width(latent_rank, rope_dim) -> int:
@@ -83,20 +94,46 @@ def check_supported_mla(num_heads, latent_rank, rope_dim, page_size, dtype):
 
 
 def mla_fold_pages(page_size, max_pages) -> int:
-    """Pages gathered per grid step: a 256-token tile, clamped to the
-    table (the kernel and the legality test share this rule)."""
+    """Pages gathered per grid step: a `_TILE_TOKENS` tile, clamped to
+    the table (the kernel and the legality test share this rule)."""
     return max(1, min(max(_TILE_TOKENS, page_size) // page_size, max_pages))
 
 
 def _mla_decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref,
-                       q_ref, *rest, sm_scale, page_size, fold, rank):
-    page_refs = rest[:fold]
-    o_ref, acc_ref, m_ref, l_ref = rest[fold:]
+                       q_ref, cache_ref, o_ref, buf_ref, sem_ref, acc_ref,
+                       m_ref, l_ref, *, sm_scale, page_size, fold, rank):
     w = pl.program_id(0)
     b = row_ref[w]
     i = step_ref[w]
     sl = sl_ref[b]
     tile_tokens = fold * page_size
+    half = jax.lax.rem(w, np.int32(2))
+
+    def rows(into, f):
+        """Page f's rows of half `into` of the buffer."""
+        return buf_ref.at[into, pl.ds(f * page_size, page_size)]
+
+    def gather(step, into):
+        """Start the `fold` copies of flat step `step`'s pages into half
+        `into` of the buffer, one after another along the token axis."""
+        first = first_ref[step]
+        for f in range(fold):
+            pltpu.make_async_copy(cache_ref.at[slots_ref[first + f]],
+                                  rows(into, f), sem_ref.at[into]).start()
+
+    @pl.when(w == 0)
+    def _first():
+        gather(w, half)
+
+    # the steps run in order, so the next step's pages (of this row or
+    # the next) stream in while this one computes
+    @pl.when(w + 1 < pl.num_programs(0))
+    def _ahead():
+        gather(w + 1, 1 - half)
+
+    for f in range(fold):     # a wait counts its destination's bytes
+        pltpu.make_async_copy(cache_ref.at[_I0], rows(half, f),
+                              sem_ref.at[half]).wait()
 
     @pl.when(i == 0)
     def _init():
@@ -104,8 +141,7 @@ def _mla_decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pages = [r[0] for r in page_refs]                 # (page, R + Dr) each
-    tile = pages[0] if fold == 1 else jnp.concatenate(pages, axis=0)
+    tile = buf_ref[half]                              # (T, R + Dr)
     q = q_ref[0]                                      # (H, R + Dr)
     s = jax.lax.dot_general(q, tile, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -150,6 +186,21 @@ def mla_paged_decode(q, cache, block_tables, seq_lens, *, rank, sm_scale):
         raise ValueError(f"entry width {W} is not whole 128-lane tiles "
                          "(mla_entry_width)")
     check_supported_mla(H, rank, 2, page_size, cache.dtype)
+    return _mla_decode_once(q, cache, block_tables, seq_lens, rank=rank,
+                            sm_scale=float(sm_scale),
+                            interpret=_interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret"))
+def _mla_decode_once(q, cache, block_tables, seq_lens, *, rank, sm_scale,
+                     interpret):
+    """`mla_paged_decode`'s call, traced and lowered ONCE a program however
+    many layers make it, as `flash_attention._fwd_positions_once` is: the
+    decode program's seven calls lower in 0.17 s for a described v5e, and
+    in 1.10 s without the inner jit (a BlockSpec a page lowered in 0.60:
+    the copies make the kernel's body longer)."""
+    B, H, W = q.shape
+    page_size = cache.shape[1]
     max_pages = block_tables.shape[1]
     bt = block_tables.astype(jnp.int32)
     sl = jnp.minimum(seq_lens.astype(jnp.int32), max_pages * page_size)
@@ -168,21 +219,17 @@ def mla_paged_decode(q, cache, block_tables, seq_lens, *, rank, sm_scale):
     def row_block(w, slots, first_of, sl, row_of, step_of):
         return row_of[w], _I0, _I0
 
-    def page_spec(f):
-        return pl.BlockSpec(
-            (1, page_size, W),
-            lambda w, slots, first_of, *_, f=f: (
-                slots[first_of[w] + f], _I0, _I0))
-
-    kernel = functools.partial(_mla_decode_kernel, sm_scale=float(sm_scale),
+    kernel = functools.partial(_mla_decode_kernel, sm_scale=sm_scale,
                                page_size=page_size, fold=fold, rank=rank)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(total,),
-        in_specs=([pl.BlockSpec((1, H, W), row_block)]
-                  + [page_spec(f) for f in range(fold)]),
+        in_specs=[pl.BlockSpec((1, H, W), row_block),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, H, rank), row_block),
-        scratch_shapes=[pltpu.VMEM((H, rank), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, fold * page_size, W), cache.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((H, rank), jnp.float32),
                         pltpu.VMEM((H, _STATS_LANES), jnp.float32),
                         pltpu.VMEM((H, _STATS_LANES), jnp.float32)],
     )
@@ -192,9 +239,9 @@ def mla_paged_decode(q, cache, block_tables, seq_lens, *, rank, sm_scale):
         out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret_mode(),
+        interpret=interpret,
         name="mla_paged_decode",
-    )(*prefetch, q.astype(cache.dtype), *([cache] * fold))
+    )(*prefetch, q.astype(cache.dtype), cache)
 
 
 def mla_paged_write(cache, entries, block_tables, lengths, starts):

@@ -329,6 +329,25 @@ def test_paged_attention_decode_mixed_context_cell(for_chip, one_chip,
     assert "paged_attention_decode" in text and MARKER in text
 
 
+# The latent decode kernel at the engine's small buckets (B 1-8, tables of
+# 1-16 pages), where the paged kernel's BlockSpec form first halted a chip by
+# reading past its scalar-prefetch arrays: a tile here is the whole table, and the copies a
+# step starts for the next one read the step arrays one entry on.
+@pytest.mark.parametrize("B,table", [(1, 1), (2, 4), (2, 8), (8, 16)],
+                         ids=["b1-p1", "b2-p4", "b2-p8", "b8-p16"])
+def test_mla_decode_small_buckets(for_chip, one_chip, B, table):
+    from paddle_tpu.kernels.mla_attention import mla_paged_decode
+    H, W, pages, page = 64, 640, 1024, 16
+    text = _compiled_text(
+        lambda q, c, bt, sl: mla_paged_decode(q, c, bt, sl, rank=512,
+                                              sm_scale=0.1),
+        _sds((B, H, W), BF16, one_chip),
+        _sds((pages, page, W), BF16, one_chip),
+        _sds((B, table), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip))
+    assert "mla_paged_decode" in text and MARKER in text
+
+
 @pytest.mark.parametrize(
     "S,keys,window", [(2048, 392 * 16, 4096), (2048, 816 * 16, None),
                       (1024, 328 * 16, 4096), (512, 296 * 16, 4096)],
