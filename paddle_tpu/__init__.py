@@ -7,7 +7,11 @@ distributed, jit, vision, profiler.
 """
 from __future__ import annotations
 
-import os
+import time as _time
+
+_T_IMPORT = _time.perf_counter()     # setup.import starts here (compile_log)
+
+import os  # noqa: E402
 
 # 64-bit dtypes on (paddle's default int dtype is int64). Floats still default
 # to float32 via get_default_dtype; float64 only on explicit request.
@@ -118,3 +122,10 @@ def enable_static():
 
 def in_dynamic_mode():
     return True
+
+
+# setup.import ends here: recorded, not entered, since the profiler that
+# would annotate it could not be imported before the package
+profiler.compile_log.record_span("setup.import", _T_IMPORT,
+                                 _time.perf_counter())
+del _T_IMPORT, _time

@@ -94,6 +94,10 @@ def to_static_report(reset=False):
         "compile_counters": _compile_log.counters(),
         "compile_seconds": _compile_log.duration_totals_s(),
         "compile_events_dropped": _compile_log.dropped(),
+        # set-up spans: the import, parameter draws and program
+        # builds, each build's trace/lower/compile/cache_load; exact
+        # process totals that reset=True leaves
+        "setup": _compile_log.setup_totals(),
     }
     if reset:
         _fallback_registry.clear()
@@ -368,7 +372,14 @@ class TracedFunction:
             # on a fresh entry this is the trace and the compile
             # (compile_log says so); in steady state, the enqueue
             with _profiler.RecordEvent("to_static.dispatch"):
-                out_arrays, new_state = jitted(state, tensor_arrays)
+                build = None if t0 is None else _compile_log.setup_span(
+                    "setup.program_build", fn=self._span_fn)
+                try:
+                    out_arrays, new_state = jitted(state, tensor_arrays)
+                finally:
+                    if build is not None:
+                        # a build: a watched call under which JAX compiled
+                        build.close(keep=build.n_stages > 0)
         except _graph_break_errors() as e:
             if self._full_graph:
                 raise RuntimeError(
@@ -381,7 +392,7 @@ class TracedFunction:
             return self._graph_break(key, state, e, args, kwargs)
         if t0 is not None:
             self._note_compiled(entry, state, tensor_arrays,
-                                time.perf_counter() - t0)
+                                time.perf_counter() - t0, build.stages)
         with _profiler.RecordEvent("to_static.load_state"):
             self._bundle.load(new_state)
             self._clear_tracer_grads()
@@ -413,13 +424,14 @@ class TracedFunction:
         return getattr(self._callable, "__qualname__",
                        getattr(self._callable, "__name__", "<fn>"))
 
-    def _note_compiled(self, entry, state, tensor_arrays, dt):
+    def _note_compiled(self, entry, state, tensor_arrays, dt, stages):
         """A still-watched (fresh or not-yet-stable) call just
         finished. Fresh: stamp the entry and log the trace/retrace.
         Warm: if jax recompiled underneath the guard entry (lazily
         created optimizer state grew the donated pytree — see
         _CacheEntry), log it and refresh the entry to the NEW program;
-        otherwise mark the entry stable and stop timing calls."""
+        otherwise mark the entry stable and stop timing calls. A logged
+        event carries the call's compile `stages` (compile_log)."""
         if not entry.fresh:
             size = entry.jax_cache_size()
             if size is None or size == entry.n_programs:
@@ -432,7 +444,8 @@ class TracedFunction:
                 "retrace", name=self._fn_name(), duration_s=dt,
                 detail={"jax_internal": True,
                         "programs": self._compiled_count,
-                        "cache_size": len(self._cache)})
+                        "cache_size": len(self._cache),
+                        "stages": dict(stages)})
             return
         entry.fresh = False
         entry.n_programs = entry.jax_cache_size()
@@ -445,7 +458,8 @@ class TracedFunction:
         _compile_log.log_event(
             kind, name=self._fn_name(), duration_s=dt,
             detail={"programs": self._compiled_count,
-                    "cache_size": len(self._cache)})
+                    "cache_size": len(self._cache),
+                    "stages": dict(stages)})
 
     def _stamp_entry(self, entry, state, tensor_arrays, dt):
         """Record the just-compiled call's accounting context on the

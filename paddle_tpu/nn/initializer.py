@@ -14,6 +14,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..framework.random import rng_key
+from ..profiler import compile_log as _compile_log
 
 __all__ = [
     "Initializer", "Constant", "Normal", "TruncatedNormal", "Uniform",
@@ -159,19 +160,25 @@ class Orthogonal(Initializer):
 
 # default initializer used by create_parameter
 def _init_tensor(shape, dtype, initializer=None, is_bias=False):
-    if initializer is None:
-        initializer = _global_initializer["bias" if is_bias else "weight"]
-    if initializer is None:
-        initializer = Constant(0.0) if is_bias else XavierUniform()
-    if callable(initializer) and not isinstance(initializer, Initializer):
-        # support paddle-style ParamAttr(initializer=...) or plain callables
-        init = initializer
-        arr = init(shape, dtype)
-        arr = arr._data if isinstance(arr, Tensor) else arr
-    else:
-        arr = initializer(shape, dtype)
-    t = Tensor(arr, stop_gradient=False)
-    t._is_param = True
+    # a setup.param_init span: the draw's host time (dispatched, never
+    # synced here) and the compiles of the initializer's ops
+    with _compile_log.setup_span("setup.param_init") as span:
+        if initializer is None:
+            initializer = _global_initializer["bias" if is_bias else "weight"]
+        if initializer is None:
+            initializer = Constant(0.0) if is_bias else XavierUniform()
+        if callable(initializer) and not isinstance(initializer, Initializer):
+            # support paddle-style ParamAttr(initializer=...) or plain callables
+            init = initializer
+            arr = init(shape, dtype)
+            arr = arr._data if isinstance(arr, Tensor) else arr
+        else:
+            arr = initializer(shape, dtype)
+        t = Tensor(arr, stop_gradient=False)
+        t._is_param = True
+        span.counts["leaves"] = 1
+        span.counts["bytes"] = math.prod(t._data.shape) \
+            * np.dtype(t._data.dtype).itemsize
     return t
 
 

@@ -490,6 +490,38 @@ class SummaryView(_enum.Enum):
 # ---------------------------------------------------------------------------
 from . import compile_log            # noqa: E402
 from . import cost                   # noqa: E402
+
+# Set-up spans (compile_log): each enters a RecordEvent of its
+# name, and JAX's compile stages are attributed to the innermost open one.
+# JAX reports a stage's start (a scalar at entry) and its span at exit,
+# on one thread and on one clock, nested as they ran.
+compile_log.set_annotation(RecordEvent)
+_STAGE_OF = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+             "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _stage_began(event, value, **_):
+    stage = _STAGE_OF.get(event)
+    if stage is not None:
+        compile_log.stage_began(stage, value)
+
+
+def _stage_ended(event, start, end, **_):
+    stage = _STAGE_OF.get(event)
+    if stage is not None:
+        compile_log.stage_ended(stage, start, end)
+
+
+def _stage_duration(event, seconds, **_):
+    if event == _CACHE_LOAD:          # inside a backend compile
+        compile_log.stage_nested("cache_load", seconds)
+
+
+jax.monitoring.register_scalar_listener(_stage_began)
+jax.monitoring.register_event_time_span_listener(_stage_ended)
+jax.monitoring.register_event_duration_secs_listener(_stage_duration)
 from . import exposition             # noqa: E402
 from .monitor import (TrainingMonitor, active_monitor,  # noqa: E402
                       grad_global_norm)

@@ -2,8 +2,7 @@
 
 Born in `serving/` for `ServingMetrics` (ISSUE 10), generalized here in
 ISSUE 11 so the TRAINING side (`profiler.TrainingMonitor`) scrapes
-through the same renderer — `paddle_tpu.serving.exposition` remains as
-a back-compat shim. Registry-driven by construction: the renderer walks
+through the same renderer. Registry-driven by construction: the renderer walks
 a LIVE `snapshot()` dict (the same no-hand-maintained-key-list contract
 the snapshot itself has with the counters dict and the reservoir
 registry), so the exposition can never disagree with `snapshot()` —

@@ -38,7 +38,7 @@ from .lora import (AdapterBusy, AdapterError, AdapterLoadError,
 from .spec import DraftModelProposer, NgramProposer, Proposer
 from .supervisor import RetryPolicy, StepSupervisor, classify_failure
 from .trace import FlightRecorder, RequestTrace, RequestTracer
-from .exposition import render_prometheus
+from ..profiler.exposition import render_prometheus
 from .fleet import (Channel, Fleet, FleetHandle, FleetServer, HttpFrontend,
                     PrefixAffinityRouter, ProcessFleet, RandomRouter,
                     Replica, ReplicaState, RoundRobinRouter, TokenStream,

@@ -778,8 +778,8 @@ class Fleet:
         a `replica="<name>"` label (per-replica visibility is the point
         of the labels; Prometheus aggregates in queries). TYPE lines
         are emitted once, on the merged block."""
-        from ..exposition import (metric_name, prometheus_lines,
-                                  sanitize_label_value)
+        from ...profiler.exposition import (
+            metric_name, prometheus_lines, sanitize_label_value)
         merged = self.merged_metrics()
         counter_keys = set(merged.counters) | {
             f"fleet_{k}" for k in self.counters}

@@ -1247,8 +1247,8 @@ class ProcessFleet:
         load, and the worker's own engine counters from its last
         heartbeat under a `worker="<name>"` label (mirroring the
         in-process fleet's `replica` labels; OBSERVABILITY.md)."""
-        from ..exposition import (metric_name, prometheus_lines,
-                                  sanitize_label_value)
+        from ...profiler.exposition import (
+            metric_name, prometheus_lines, sanitize_label_value)
         lines = prometheus_lines(
             {f"fleet_{k}": v for k, v in self.counters.items()},
             counter_keys={f"fleet_{k}" for k in self.counters},

@@ -542,7 +542,7 @@ class ServingMetrics:
         and registered-reservoir percentile surfaces with no
         hand-maintained name list. Keys in the counters dict are typed
         `counter`, everything else `gauge`."""
-        from .exposition import prometheus_lines
+        from ..profiler.exposition import prometheus_lines
         lines = prometheus_lines(self.snapshot(),
                                  counter_keys=set(self.counters),
                                  prefix=prefix, labels=labels,

@@ -74,27 +74,12 @@ class _TrackedProgram:
 
     def __call__(self, *args):
         if self.first_call_ms is None:
+            from ..profiler import compile_log
             t0 = time.perf_counter()
-            if self.from_disk and self.fallback is not None:
-                try:
-                    out = self.fn(*args)
-                except Exception as exc:                  # noqa: BLE001
-                    # Only a FATAL failure indicts the ENTRY (stale
-                    # avals, foreign executable). Transient device
-                    # errors and poison must propagate to the engine's
-                    # supervisor — its retry path owns the donated-
-                    # buffer hazard, and a perfectly good entry must
-                    # not be rejected for the device's flakiness.
-                    from .supervisor import FATAL, classify_failure
-                    if classify_failure(exc) != FATAL:
-                        raise
-                    self.fn = self.fallback()
-                    self.from_disk = False
-                    if self.on_reject is not None:
-                        self.on_reject()
-                    out = self.fn(*args)
-            else:
-                out = self.fn(*args)
+            with compile_log.setup_span(
+                    "setup.program_build", family=str(self.key[0]),
+                    key=repr(self.key)[:120]) as build:
+                out = self._first_call(args)
             dt = time.perf_counter() - t0
             self.first_call_ms = round(dt * 1e3, 3)
             try:
@@ -102,13 +87,33 @@ class _TrackedProgram:
                 self.arg_avals = shape_structs(list(args))
             except Exception:
                 self.arg_avals = None
-            from ..profiler import compile_log
             compile_log.log_event(
                 "program_compile", name=str(self.key[0]), duration_s=dt,
                 detail={"key": repr(self.key)[:120],
-                        "from_disk": self.from_disk})
+                        "from_disk": self.from_disk,
+                        "stages": dict(build.stages)})
             return out
         return self.fn(*args)
+
+    def _first_call(self, args):
+        if not (self.from_disk and self.fallback is not None):
+            return self.fn(*args)
+        try:
+            return self.fn(*args)
+        except Exception as exc:                  # noqa: BLE001
+            # Only a FATAL failure indicts the ENTRY (stale avals,
+            # foreign executable). Transient device errors and poison
+            # must propagate to the engine's supervisor — its retry path
+            # owns the donated-buffer hazard, and a perfectly good entry
+            # must not be rejected for the device's flakiness.
+            from .supervisor import FATAL, classify_failure
+            if classify_failure(exc) != FATAL:
+                raise
+            self.fn = self.fallback()
+            self.from_disk = False
+            if self.on_reject is not None:
+                self.on_reject()
+            return self.fn(*args)
 
     def lower(self):
         """Re-lower this program from the recorded arg avals. Under

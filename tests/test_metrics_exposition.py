@@ -17,7 +17,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import (Fleet, PrefixAffinityRouter,
                                 ServingEngine, ServingMetrics)
-from paddle_tpu.serving.exposition import (metric_name,
+from paddle_tpu.profiler.exposition import (metric_name,
                                            parse_exposition_names,
                                            prometheus_lines,
                                            render_prometheus)
