@@ -16,25 +16,34 @@ Design:
     int32 block table;
   * decode query (B, H, D) is viewed as (B, KVH, G, D) with G = H//KVH
     grouped-query heads sharing one KV head;
-  * the block table and per-sequence lengths ride scalar prefetch; a
-    grid step GATHERS `fold` pages of one sequence (one BlockSpec
-    each, all kv heads, `block_tables[b, i*fold + f]`, double-buffered
-    HBM->VMEM) and COMPUTES on them as one token tile of T = fold *
-    page_size tokens per kv head, whatever the page size
-    (`_fold_pages`: 256 tokens or two pages a step, VMEM allowing) — a
-    page is only the unit of the gather;
+  * the block table and per-sequence lengths ride scalar prefetch; the
+    grid is the flat list of the rows' live steps (steps past
+    ceil(len/T) are not in it; its bound is their sum, a dynamic grid
+    bound), and scalar prefetch names each step's row, step and first
+    table slot;
+  * a step GATHERS up to `fold` pages of one sequence and COMPUTES on
+    them as one token tile of T = fold * page_size tokens a kv head,
+    whatever the page size (`_fold_pages`: 512 tokens or two pages a
+    step, VMEM allowing) — a page is only the unit of the gather. The
+    pool stays in HBM (`pl.ANY`); the kernel starts the copies of the
+    NEXT step's pages into the other half of a two-tile VMEM buffer
+    before it waits on its own (`_decode_kernel`), and copies only the
+    pages that hold a key the row's query sees (`decode_page_span`):
+    the dead slots of a row's last tile, and a window layer's slots
+    before its first visible key, are never read;
   * online softmax over token tiles with (KVH, G, 128) lane-broadcast
-    running stats and the kv heads batched in one dot_general; steps
-    past ceil(len/T) are not in the grid: it is the flat list of the
-    rows' live steps, its bound their sum (a dynamic grid bound), and
-    scalar prefetch names each step's row, step and first table slot;
-  * positions >= seq_len inside the last tile are masked in-block.
+    running stats and the kv heads batched in one dot_general;
+  * positions >= seq_len (and, in a window layer, before the first
+    visible key) inside a tile are masked in-block.
 
-What bounds it (PR 28, v5e; `_decode_kernel` has the numbers): with
-page-sized compute tiles the kernel was bound by what it did with a
-page once in VMEM (82 GB/s at page 16); on token tiles it streams the
-live KV at 420-560 GB/s at page 16 and 740 GB/s at page 128, and what
-is left at page 16 is the scalar core's work per gathered page. MXU
+What bounds it (v5e; `_decode_kernel` has the history): with page-sized
+compute tiles the kernel was bound by what it did with a page once in
+VMEM (82 GB/s at page 16); on token tiles it streamed the live KV
+at 420-560 GB/s at page 16 and 740 GB/s at page 128, and what was left
+at page 16 was the pipeline's work per gathered page, one BlockSpec a
+page. Copies the kernel starts itself take that work off the page;
+`_gather_plan` says where Mosaic lets it (a pool whose minor dimension
+is whole 128-lane tiles) and where the pipeline still fetches. MXU
 utilisation is irrelevant at decode G sizes.
 
 Quantized KV pages (ISSUE 6): the cache may instead hold int8 values
@@ -59,6 +68,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +80,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _interpret_mode
 
 __all__ = ["paged_attention_decode", "paged_attention_decode_tp",
+           "decode_page_span", "paged_decode_page_counts",
            "paged_cache_write",
            "paged_cache_write_range", "paged_cache_write_span",
            "alloc_paged_cache", "check_supported_paged", "paged_blockspecs",
@@ -116,17 +127,83 @@ def paged_page_bytes(num_kv_heads, page_size, head_dim, kv_dtype=None):
     return 2 * num_kv_heads * page_size * (head_dim * width + scale_b)
 
 
+def decode_page_span(seq_lens, page_size, window=None, xp=jnp):
+    """The pages of a row's table that hold a key its query sees:
+    [first, end), end = ceil(len / page_size), first = 0 or, in a layer
+    that attends to the last `window` positions, the page of key len -
+    window. The ONE rule the kernel copies by and the engine counts by
+    (`paged_decode_page_counts`); `xp` is jnp (the kernel's int32
+    scalars: lax division, which Mosaic lowers under x64 where `//`
+    does not) or np (the engine's host arithmetic)."""
+    if xp is np:
+        div = operator.floordiv
+    else:
+        def div(a, d):
+            return jax.lax.div(a, np.int32(d))
+    end = div(seq_lens + np.int32(page_size - 1), page_size)
+    if window is None:
+        return end * 0, end
+    return div(xp.maximum(seq_lens - np.int32(window), 0), page_size), end
+
+
+def _tile_pages(sl, step, page_size, fold, window):
+    """[lo, hi): the pages of a row's tile `step` (of `fold` pages) that
+    `decode_page_span` names, as int32 scalars in 0 .. fold."""
+    first, end = decode_page_span(sl, page_size, window)
+    base = step * np.int32(fold)
+    return (jnp.clip(first - base, _I0, np.int32(fold)),
+            jnp.clip(end - base, _I0, np.int32(fold)))
+
+
+def _gather_plan(page_size, head_dim, cache_dtype, quantized):
+    """How the kernel gathers each kind of page, from the shapes alone:
+    (values copied, joined, scales copied).
+
+    Mosaic starts a copy of ONE page out of a pool only where the pool's
+    minor dimension is whole 128-lane tiles: value pages where the head
+    dim is (D 128, 256), fp32 scale pages where the page is (page 128).
+    A kind it refuses is fetched by the pipeline, one BlockSpec a page,
+    each page's index clamped to the step's live pages. Copied value
+    pages are `joined`: each lands at its offset along the token axis of
+    a (KVH, T, D) buffer, which is then the tile as it stands, where a
+    page is whole sublane tiles of its type (16 tokens bfloat16, 8
+    float32, 32 int8); otherwise a page lands whole in a (fold, KVH,
+    page_size, D) buffer and the upcast pages are joined."""
+    values = head_dim % 128 == 0
+    rows = 32 // jnp.dtype(cache_dtype).itemsize
+    return (values, values and page_size % rows == 0,
+            quantized and page_size % 128 == 0)
+
+
 def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
-                   *rest_refs, sm_scale, page_size, fold, quantized=False,
+                   *refs, sm_scale, page_size, fold, plan, quantized=False,
                    window=None):
     """One step of the flat grid: `row_ref[w]`'s step `step_ref[w]`. It
-    GATHERS `fold` pages (one BlockSpec fetch each, all kv heads) and
-    COMPUTES on them as one token tile: the pages are upcast and joined
-    along the token axis into a (KVH, T, D) K tile and V tile, T = fold
-    * page_size, and the step is one batched (G, D) x (D, T) dot, one
-    mask / max / exp / sum over lane-dense (KVH, G, T) scores, one
-    update of m / l / acc and one batched (G, T) x (T, D) dot. A page
-    is only the unit of the gather.
+    GATHERS its tile's live pages and COMPUTES on them as one token tile
+    of T = fold * page_size tokens a kv head: one batched (G, D) x
+    (D, T) dot, one mask / max / exp / sum over lane-dense (KVH, G, T)
+    scores, one update of m / l / acc and one batched (G, T) x (T, D)
+    dot, in float32.
+
+    The gather (`_gather_plan`). The pool stays in HBM, and the step
+    copies its pages itself into one half of a two-tile VMEM buffer,
+    a page (all kv heads) at its offset along the token axis: step w
+    starts step w + 1's copies (of its row or the next: the flat grid
+    runs in order) into the other half before it waits on its own, so
+    the next tile streams in while this one computes. It copies only
+    the pages of `decode_page_span` (`_tile_pages`): none past the
+    row's length, none before a window's first visible key; the wait
+    runs over the same pages. Buffer rows that no copy of this step
+    wrote hold an earlier tile's pages, or the zeros step 0 wrote into
+    the V buffers: their scores are masked, and a finite V row times a
+    zero weight adds nothing (an unwritten NaN would).
+
+    What bounds it. Before the kernel gathered its own pages a page was
+    one BlockSpec of the pipeline, which pays an index map, a compare
+    and a copy's start and wait a block: 62 ns a 32 KB page at page 16
+    (v5e), where moving it takes 40; and a row's last tile fetched its
+    dead slots (the pad page) and masked them. Own copies cost the
+    latent kernel (`mla_attention.py`) about 35 ns a 20 KB page.
 
     History. Round 4 (v5e, B16 KVH8 D128 S2048): one 16-token page a
     step ran at 78 GB/s; folding the FETCHES to 128 tokens a step gave
@@ -150,40 +227,97 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
     2,048-token rows 1.38 -> 0.24 ms at page 16 (558 GB/s) and 0.295 ->
     0.18 at page 128 (736 GB/s).
 
-    quantized=True streams int8 value pages plus their fp32 per-slot
-    scale pages (same gathered page ids) and dequantizes each page on
-    the VMEM side before the join — K/V bytes moved drop ~2x.
+    quantized=True gathers the int8 value pages and their fp32 per-slot
+    scale pages (same slot ids) and dequantizes each page in fp32 before
+    the join — K/V bytes moved drop ~2x.
 
     window=w (a layer that attends to the last w positions): the row's
     steps begin at the tile that holds its first visible key, len - w
     (`_live_steps`; `step_ref` stays the tile's index in the row's
     table), the running stats are reset there, and the keys before it
     inside that tile are masked."""
-    k_refs = rest_refs[:fold]
-    v_refs = rest_refs[fold:2 * fold]
-    if quantized:
-        ks_refs = rest_refs[2 * fold:3 * fold]
-        vs_refs = rest_refs[3 * fold:4 * fold]
-        o_ref, acc_ref, m_ref, l_ref = rest_refs[4 * fold:]
-    else:
-        ks_refs = vs_refs = (None,) * fold
-        o_ref, acc_ref, m_ref, l_ref = rest_refs[2 * fold:]
+    values_copied, joined, scales_copied = plan
+    refs = list(refs)
+
+    def take(n):
+        out = refs[:n]
+        del refs[:n]
+        return out
+
+    n_val = 1 if values_copied else fold
+    n_scale = (1 if scales_copied else fold) if quantized else 0
+    k_in, v_in, ks_in, vs_in = take(n_val), take(n_val), take(n_scale), \
+        take(n_scale)
+    o_ref, = take(1)
+    k_buf, v_buf = take(2) if values_copied else (None, None)
+    ks_buf, vs_buf = take(2) if scales_copied else (None, None)
+    sem_ref, = take(1) if values_copied or scales_copied else (None,)
+    acc_ref, m_ref, l_ref = refs
     sm_scale = np.float32(sm_scale)
     w = pl.program_id(0)
     b = row_ref[w]
     i = step_ref[w]
     sl = sl_ref[b]
     tile_tokens = fold * page_size
+    half = jax.lax.rem(w, np.int32(2))
+    copied = []                        # (pool, buffer, joined)
+    if values_copied:
+        copied += [(k_in[0], k_buf, joined), (v_in[0], v_buf, joined)]
+    if scales_copied:
+        copied += [(ks_in[0], ks_buf, False), (vs_in[0], vs_buf, False)]
 
-    def tile(page_refs, scale_refs):
-        """The step's gathered pages as one (KVH, T, D) fp32 tile. A
-        page's (page_size, D) slice is whole sublane tiles once it is
-        fp32, so the join is tile placement, not a relayout."""
+    def each_page(step, into, act):
+        """act(copy) for every copy of flat step `step`'s live pages into
+        half `into` of the buffers."""
+        lo, hi = _tile_pages(sl_ref[row_ref[step]], step_ref[step],
+                             page_size, fold, window)
+        slot0 = first_ref[step]
+
+        @pl.loop(lo, hi)
+        def _(f):
+            slot = slots_ref[slot0 + f]
+            for pool, buf, along_tokens in copied:
+                if along_tokens:
+                    rows = pl.ds(pl.multiple_of(f * np.int32(page_size),
+                                                page_size), page_size)
+                    src, dst = pool.at[slot], buf.at[into, :, rows]
+                else:
+                    src, dst = pool.at[pl.ds(slot, 1)], buf.at[into, f]
+                act(pltpu.make_async_copy(src, dst, sem_ref.at[into]))
+
+    if copied:
+        @pl.when(w == 0)
+        def _first():
+            for buf in (v_buf, vs_buf):
+                if buf is not None:
+                    buf[...] = jnp.zeros_like(buf)
+            each_page(w, half, lambda c: c.start())
+
+        # the steps run in order, so the next step's pages (of this row
+        # or the next) stream in while this one computes
+        @pl.when(w + 1 < pl.num_programs(0))
+        def _ahead():
+            each_page(w + 1, 1 - half, lambda c: c.start())
+
+        each_page(w, half, lambda c: c.wait())
+
+    def tile(pages_in, buf, scales_in, scale_buf):
+        """The step's pages as one (KVH, T, D) fp32 tile."""
+        if joined and not quantized:
+            return buf[half].astype(jnp.float32)
         pages = []
-        for page_ref, scale_ref in zip(page_refs, scale_refs):
-            page = page_ref[0].astype(jnp.float32)      # (KVH, page, D)
-            if quantized:
-                page = page * scale_ref[0][:, :, None]  # fp32 dequant
+        for f in range(fold):
+            if not values_copied:
+                page = pages_in[f][0]
+            elif joined:
+                page = buf[half, :, f * page_size:(f + 1) * page_size]
+            else:
+                page = buf[half, f, 0]
+            page = page.astype(jnp.float32)             # (KVH, page, D)
+            if quantized:                               # fp32 dequant
+                scale = (scale_buf[half, f, 0] if scales_copied
+                         else scales_in[f][0])
+                page = page * scale[:, :, None]
             pages.append(page)
         return pages[0] if fold == 1 else jnp.concatenate(pages, axis=1)
 
@@ -200,7 +334,7 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)                # (KVH, G, D)
-    s = jax.lax.dot_general(q, tile(k_refs, ks_refs),
+    s = jax.lax.dot_general(q, tile(k_in, k_buf, ks_in, ks_buf),
                             (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32)
     s = s * sm_scale                                # (KVH, G, T)
@@ -220,7 +354,7 @@ def _decode_kernel(slots_ref, first_ref, sl_ref, row_ref, step_ref, q_ref,
         l_prev * alpha + jnp.sum(p, axis=2, keepdims=True), l_ref.shape)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, tile(v_refs, vs_refs), (((2,), (1,)), ((0,), (0,))),
+        p, tile(v_in, v_buf, vs_in, vs_buf), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
 
     @pl.when((i + 1) * tile_tokens >= sl)
@@ -266,14 +400,16 @@ def check_supported_paged(q_shape, cache_shape, dtype, kv_dtype=None):
                          "pages)")
 
 
-# A grid step's token tile: 256 tokens, or two pages where a page is
-# larger — as wide as the round-4 sweep found best at page 128 (2-page
-# steps) and the best of 128 / 256 / 512 at page 16 over ragged serving
-# lengths (PR 28, the serving cell's call: 0.32 / 0.31 / 0.35 ms; 512
-# wins by a tenth only where every row fills its table) — VMEM
-# allowing: a tile token costs its K and V page slices double-buffered
-# plus both fp32 upcasts.
-_TILE_TOKENS = 256
+# A grid step's token tile: 512 tokens, or two pages where a page is
+# larger — VMEM allowing: a tile token costs its K and V pages in both
+# halves of the copy buffers plus both fp32 upcasts. With the kernel's
+# own copies a row's last tile copies no dead slot, so a wider tile costs
+# only its compute; at the serving cells' calls (v5e, the kernel alone,
+# two draws of lengths each) T 512 against 256: 0.235, 0.245 against
+# 0.248, 0.261 ms at B 64 x 64 pages; 0.998, 1.034 against 1.018, 1.053
+# in a 4,096 window and 1.592, 1.487 against 1.572, 1.509 over the
+# whole 816-page table at B 48 x 16 query heads a KV head.
+_TILE_TOKENS = 512
 _TILE_VMEM_BYTES = 8 << 20
 
 
@@ -292,28 +428,43 @@ def _fold_pages(page_size, max_pages, num_kv_heads, head_dim, cache_dtype):
 def paged_blockspecs(B, H, KVH, D, page_size, num_pages, max_pages=None,
                      quantized=False):
     """The exact (block_shape, array_shape) pairs the pallas_call below
-    constructs — including the `fold` repetition of the k/v page specs
-    the gather uses — plus the VMEM scratch shapes; enumerable for
-    the static legality test without running the kernel. quantized=True
-    appends the fp32 scale-page specs ((1, KVH, page_size) blocks over
-    (num_pages, KVH, page_size) arrays — legal because both trailing
-    block dims equal the array dims) the int8 path adds; the pages are
-    int8 then, bf16 otherwise (the two dtypes the engine stores)."""
+    constructs, plus its scratch shapes (the semaphores' included);
+    enumerable for the static legality test without running the kernel.
+    The q and out blocks are (1, KVH, G, D). A pool the kernel copies
+    from itself (`_gather_plan`) is a `pl.ANY` input whose block is the
+    whole array, with a two-tile buffer in scratch; a pool the pipeline
+    fetches is `fold` page blocks. The pages are int8 quantized (their
+    fp32 scale pools (num_pages, KVH, page_size) beside them), bf16
+    otherwise: the two dtypes the engine stores."""
     G = H // KVH
     if max_pages is None:
         max_pages = num_pages
-    fold = _fold_pages(page_size, max_pages, KVH, D,
-                       jnp.int8 if quantized else jnp.bfloat16)
-    page = ((1, KVH, page_size, D), (num_pages, KVH, page_size, D))
-    scale = ((1, KVH, page_size), (num_pages, KVH, page_size))
+    cache_dtype = jnp.int8 if quantized else jnp.bfloat16
+    fold = _fold_pages(page_size, max_pages, KVH, D, cache_dtype)
+    values_copied, joined, scales_copied = _gather_plan(
+        page_size, D, cache_dtype, quantized)
+    pool = (num_pages, KVH, page_size, D)
+    scale_pool = (num_pages, KVH, page_size)
+
+    def gathered(array, copied):
+        return ([(array, array)] if copied
+                else [((1,) + array[1:], array)] * fold)
+
     specs = (
         [((1, KVH, G, D), (B, KVH, G, D))]                # q block
-        + [page] * fold                                   # k pages
-        + [page] * fold                                   # v pages
-        + ([scale] * (2 * fold) if quantized else [])     # k/v scale pages
+        + gathered(pool, values_copied) * 2               # k, v pages
+        + (gathered(scale_pool, scales_copied) * 2 if quantized else [])
         + [((1, KVH, G, D), (B, KVH, G, D))]              # out block
     )
-    scratch = [(KVH, G, D), (KVH, G, _STATS_LANES), (KVH, G, _STATS_LANES)]
+    scratch = []
+    if values_copied:
+        scratch += [(2, KVH, fold * page_size, D) if joined
+                    else (2, fold, 1, KVH, page_size, D)] * 2
+    if scales_copied:
+        scratch += [(2, fold, 1, KVH, page_size)] * 2
+    if values_copied or scales_copied:
+        scratch += [(2,)]                                 # DMA semaphores
+    scratch += [(KVH, G, D), (KVH, G, _STATS_LANES), (KVH, G, _STATS_LANES)]
     return specs, scratch
 
 
@@ -358,6 +509,26 @@ def _live_steps(seq_lens, tile_tokens, steps_per_row, fold, window=None):
     return ends[-1], row_of, step_of, first_of
 
 
+def paged_decode_page_counts(seq_lens, page_size, max_pages, num_kv_heads,
+                             head_dim, cache_dtype, window=None):
+    """(tile pages, copied pages) of one `paged_attention_decode` call
+    over a (B, max_pages) table, summed over its rows, by the kernel's
+    own rules and in host arithmetic (NumPy, no device work): the page
+    slots of the flat grid's live steps (`fold` a step, `_live_steps`:
+    one step for an empty row, a window layer's steps from its first
+    visible key's tile) and the pages the kernel copies
+    (`decode_page_span`). Their ratio is the share of a gathered tile
+    that is live."""
+    fold = _fold_pages(page_size, max_pages, num_kv_heads, head_dim,
+                       cache_dtype)
+    steps_per_row = -(-max_pages // fold)
+    sl = np.minimum(np.asarray(seq_lens, np.int64), max_pages * page_size)
+    first, end = decode_page_span(sl, page_size, window, xp=np)
+    steps = np.clip(-(-end // fold), 1, steps_per_row)
+    steps = steps - np.minimum(first // fold, steps - 1)
+    return int(steps.sum()) * fold, int((end - first).sum())
+
+
 def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
                            sm_scale=None, k_scale=None, v_scale=None,
                            window=None):
@@ -383,8 +554,6 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
     Returns (B, H, D).
     """
     B, H, D = q.shape
-    num_pages, KVH, page_size, _ = k_cache.shape
-    max_pages = block_tables.shape[1]
     quantized = k_scale is not None or v_scale is not None
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
@@ -395,18 +564,36 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         raise ValueError("int8 cache needs k_scale/v_scale")
     check_supported_paged(q.shape, k_cache.shape, q.dtype,
                           kv_dtype="int8" if quantized else None)
-    G = H // KVH
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
+    return _paged_decode_once(q, k_cache, v_cache, block_tables, seq_lens,
+                              k_scale, v_scale, sm_scale=float(sm_scale),
+                              window=window, interpret=_interpret_mode())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "window", "interpret"))
+def _paged_decode_once(q, k_cache, v_cache, block_tables, seq_lens, k_scale,
+                       v_scale, *, sm_scale, window, interpret):
+    """`paged_attention_decode`'s call, traced and lowered ONCE a program
+    however many layers make it (as `mla_attention._mla_decode_once` and
+    `flash_attention._fwd_positions_once` are): the kernel's body starts
+    and waits on its copies itself and lowers longer than a BlockSpec
+    pipeline's."""
+    B, H, D = q.shape
+    num_pages, KVH, page_size, _ = k_cache.shape
+    max_pages = block_tables.shape[1]
+    quantized = k_scale is not None
+    G = H // KVH
     qg = q.reshape(B, KVH, G, D)
     bt = block_tables.astype(jnp.int32)
     # a length past the table reads the whole table, and no further
     sl = jnp.minimum(seq_lens.astype(jnp.int32), max_pages * page_size)
 
-    # A step gathers `fold` pages and computes on them as one token
+    # A step gathers up to `fold` pages and computes on them as one token
     # tile (_decode_kernel has the measurements). Pad the block table
-    # to a fold multiple; padded slots reuse page 0 and are masked by
-    # seq_lens.
+    # to a fold multiple; a padded slot lies past every row's length and
+    # is never copied.
     fold = _fold_pages(page_size, max_pages, KVH, D, k_cache.dtype)
     if max_pages % fold != 0:
         pad = fold - max_pages % fold
@@ -426,44 +613,55 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
     prefetch = [jnp.pad(a, (0, -a.shape[0] % 128 + 128))
                 for a in (bt.reshape(-1), first_of, sl, row_of, step_of)]
 
-    kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                               page_size=page_size, fold=fold,
+    plan = _gather_plan(page_size, D, k_cache.dtype, quantized)
+    values_copied, joined, scales_copied = plan
+    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
+                               page_size=page_size, fold=fold, plan=plan,
                                quantized=quantized, window=window)
 
     def row_block(w, slots, first_of, sl, row_of, step_of):
         return row_of[w], _I0, _I0, _I0
 
-    def page_spec(f):
-        return pl.BlockSpec(
-            (1, KVH, page_size, D),
-            lambda w, slots, first_of, *_, f=f: (
-                slots[first_of[w] + f], _I0, _I0, _I0))
+    def gathered(pool, copied):
+        """(in_specs, args, scratch) of one pool: copied by the kernel,
+        or fetched a page a BlockSpec with each page's index clamped to
+        the step's live pages (a dead slot re-reads a live page)."""
+        if copied:
+            return [pl.BlockSpec(memory_space=pl.ANY)], [pool], [
+                pltpu.VMEM((2, KVH, fold * page_size, D) if joined
+                           and pool.ndim == 4 else
+                           (2, fold, 1) + pool.shape[1:], pool.dtype)]
 
-    def scale_spec(f):
-        # same gathered page id as the value page it scales
-        return pl.BlockSpec(
-            (1, KVH, page_size),
-            lambda w, slots, first_of, *_, f=f: (
-                slots[first_of[w] + f], _I0, _I0))
+        def page_spec(f):
+            def index(w, slots, first_of, sl, row_of, step_of):
+                lo, hi = _tile_pages(sl[row_of[w]], step_of[w], page_size,
+                                     fold, window)
+                g = jnp.maximum(jnp.minimum(np.int32(f), hi - 1), lo)
+                return (slots[first_of[w] + g],) + (_I0,) * (pool.ndim - 1)
+            return pl.BlockSpec((1,) + pool.shape[1:], index)
+        return [page_spec(f) for f in range(fold)], [pool] * fold, []
 
-    scale_specs = ([scale_spec(f) for f in range(fold)] * 2
-                   if quantized else [])
-    scale_args = ([k_scale] * fold + [v_scale] * fold) if quantized else []
+    in_specs, args, scratch = [pl.BlockSpec((1, KVH, G, D), row_block)], \
+        [qg], []
+    pools = [(k_cache, values_copied), (v_cache, values_copied)]
+    if quantized:
+        pools += [(k_scale, scales_copied), (v_scale, scales_copied)]
+    for pool, copied in pools:
+        specs, pool_args, pool_scratch = gathered(pool, copied)
+        in_specs += specs
+        args += pool_args
+        scratch += pool_scratch
+    if scratch:
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(total,),
-        in_specs=(
-            [pl.BlockSpec((1, KVH, G, D), row_block)]
-            + [page_spec(f) for f in range(fold)]      # k pages
-            + [page_spec(f) for f in range(fold)]      # v pages
-            + scale_specs                              # k/v scale pages
-        ),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KVH, G, D), row_block),
-        scratch_shapes=[
+        scratch_shapes=scratch + [
             pltpu.VMEM((KVH, G, D), jnp.float32),
             pltpu.VMEM((KVH, G, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((KVH, G, _STATS_LANES), jnp.float32),
-        ],
+            pltpu.VMEM((KVH, G, _STATS_LANES), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
@@ -471,9 +669,9 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret_mode(),
+        interpret=interpret,
         name="paged_attention_decode",
-    )(*prefetch, qg, *([k_cache] * fold), *([v_cache] * fold), *scale_args)
+    )(*prefetch, *args)
     return out.reshape(B, H, D)
 
 

@@ -219,6 +219,12 @@ _LAUNCHES = {
 _WINDOW_COUNTERS = ("kv_window_pages_held", "kv_window_pages_full",
                     "kv_window_pages_released")
 
+# What an engine whose layers decode through `paged_attention_decode`
+# counts a plain decode launch (PERF.md section 3), by the kernel's own
+# rules (`paged_decode_page_counts`): the page slots of the kernel's live
+# steps, and the pages it copies, over the launch's rows and those layers.
+_GATHER_COUNTERS = ("decode_kv_tile_pages", "decode_kv_pages_copied")
+
 # Ceiling on decode_steps (K): each launch runs K decode iterations in
 # one device-side scan, and a device loop of 4096 iterations once left
 # a chip UNAVAILABLE for minutes (the tpu-lint A4 wedge cap is 512).
@@ -786,9 +792,20 @@ class ServingEngine:
         # tokens are fetched. A dense model names none and its programs
         # return an empty pytree there.
         self._model_counters = tuple(model.paged_counters)
-        for name in self._model_counters + (_WINDOW_COUNTERS
-                                            if self.allocator.windows
-                                            else ()):
+        # a layer whose entry is K and V pages of (KVH, page, D) decodes
+        # through `paged_attention_decode` (models/paged.py): the shape
+        # its gather is counted by — KVH a shard's under TP — and each
+        # group's (window, layers)
+        page, cache_dtype, pspec = spec.entries[0]
+        self._gather_shape = None
+        if len(page) == 3:
+            self._gather_shape = (
+                page[0] // (self.tp if pspec is not None else 1), page[2],
+                cache_dtype, tuple((win, self._layer_groups.count(g))
+                                   for g, win in enumerate(spec.windows)))
+        for name in self._model_counters + (
+                _WINDOW_COUNTERS if self.allocator.windows else ()) + (
+                _GATHER_COUNTERS if self._gather_shape else ()):
             self.metrics.counters.setdefault(name, 0)
         # bytes-moved accounting (ServingMetrics): one token's K+V
         # across every layer, scales included — GLOBAL bytes (the sum
@@ -1531,6 +1548,7 @@ class ServingEngine:
                 written=len(live) * self.kv_bytes_per_token,
                 read=context * self.kv_bytes_per_token)
             self.metrics.on_decode(len(live))
+            self._count_gather(flight.sl, P)
         return [(r, toks[flight.row[r]], oks[flight.row[r]]) for r in live]
 
     def _fire_nan(self, oks, reqs):
@@ -2056,6 +2074,22 @@ class ServingEngine:
                 host_prefix_hits=self.radix.num_host_hits,
                 host_pages_dropped=self.radix.num_host_dropped_pages)
         return out
+
+    def _count_gather(self, sl, P):
+        """`_GATHER_COUNTERS` of one plain decode launch: its rows'
+        lengths `sl` (padded rows 0) over its P-page table, in every
+        layer that decodes through `paged_attention_decode`. Host
+        arithmetic over the launch's inputs: no sync."""
+        if self._gather_shape is None:
+            return
+        from ..kernels.paged_attention import paged_decode_page_counts
+        kvh, head_dim, cache_dtype, groups = self._gather_shape
+        c = self.metrics.counters
+        for window, layers in groups:
+            tiles, copied = paged_decode_page_counts(
+                sl, self.page_size, P, kvh, head_dim, cache_dtype, window)
+            c["decode_kv_tile_pages"] += layers * tiles
+            c["decode_kv_pages_copied"] += layers * copied
 
     def _window_gauges(self) -> dict:
         """update_gauges kwargs of the windowed layer groups, empty for

@@ -292,14 +292,16 @@ def test_paged_attention_decode_tp4(for_chip, chip_mesh):
     assert MARKER in text
 
 
-# The serving cell's own call (benchmarks/: B 64 x a 64-page table at page
-# 16, and the engine sends q as f32), and the same on the KVH 8/4 = 2
-# heads of a TP-4 shard.
-@pytest.mark.parametrize("H,KVH", [(32, 8), (8, 2)],
-                         ids=["cell", "kvh2-shard"])
-def test_paged_attention_decode_serving_cell(for_chip, one_chip, H, KVH):
+# The serving cells' own calls (benchmarks/: `serve-offline-decode`'s B 64
+# x a 64-page table at page 16, and the engine sends q as f32; the same on
+# the KVH 8/4 = 2 heads of a TP-4 shard; `serve-chat-steady`'s B 32 x 160).
+@pytest.mark.parametrize("B,H,KVH,table", [(64, 32, 8, 64), (64, 8, 2, 64),
+                                           (32, 32, 8, 160)],
+                         ids=["cell", "kvh2-shard", "chat-steady"])
+def test_paged_attention_decode_serving_cell(for_chip, one_chip, B, H, KVH,
+                                             table):
     from paddle_tpu.kernels.paged_attention import paged_attention_decode
-    B, D, pages, page, table = 64, 128, 4096, 16, 64
+    D, pages, page = 128, 4096, 16
     cache = _sds((pages, KVH, page, D), BF16, one_chip)
     text = _compiled_text(
         paged_attention_decode, _sds((B, H, D), jnp.float32, one_chip),
@@ -326,6 +328,49 @@ def test_paged_attention_decode_mixed_context_cell(for_chip, one_chip,
         _sds((B, H, D), BF16, one_chip), cache, cache,
         _sds((B, table), jnp.int32, one_chip),
         _sds((B,), jnp.int32, one_chip))
+    assert "paged_attention_decode" in text and MARKER in text
+
+
+# The paged decode kernel at the engine's small buckets (B 1, 2, 8 x tables
+# of 1, 4, 8, 16 pages), where its BlockSpec form first halted a chip by
+# reading past its scalar-prefetch arrays: a tile here is the whole table,
+# and the copies a step starts for the next one read the step arrays one
+# entry on.
+@pytest.mark.parametrize("table", [1, 4, 8, 16])
+@pytest.mark.parametrize("B", [1, 2, 8])
+def test_paged_decode_small_buckets(for_chip, one_chip, B, table):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    H, KVH, D, pages, page = 32, 8, 128, 1024, 16
+    cache = _sds((pages, KVH, page, D), BF16, one_chip)
+    text = _compiled_text(
+        paged_attention_decode, _sds((B, H, D), jnp.float32, one_chip),
+        cache, cache, _sds((B, table), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip))
+    assert "paged_attention_decode" in text and MARKER in text
+
+
+# Each way `_gather_plan` gathers a kind of page, where Mosaic takes a
+# copy of one page and where it does not: pages laid whole (bf16 page 8),
+# value pages fetched by the pipeline (head dim 64), int8 value pages
+# copied beside scale pages the pipeline fetches (page 16, 32).
+@pytest.mark.parametrize("page,D,kv_dtype", [(8, 128, None), (16, 64, None),
+                                             (16, 128, "int8"),
+                                             (32, 128, "int8")],
+                         ids=["whole-pages", "pipelined-d64", "int8-p16",
+                              "int8-p32"])
+def test_paged_decode_gather_forms(for_chip, one_chip, page, D, kv_dtype):
+    from paddle_tpu.kernels.paged_attention import paged_attention_decode
+    B, H, KVH, pages = 8, 32, 8, 1024
+    cache = _sds((pages, KVH, page, D), jnp.int8 if kv_dtype else BF16,
+                 one_chip)
+    scale = _sds((pages, KVH, page), jnp.float32, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, bt, sl, ks, vs: paged_attention_decode(
+            q, k, v, bt, sl, k_scale=ks if kv_dtype else None,
+            v_scale=vs if kv_dtype else None),
+        _sds((B, H, D), BF16, one_chip), cache, cache,
+        _sds((B, 2048 // page), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip), scale, scale)
     assert "paged_attention_decode" in text and MARKER in text
 
 

@@ -5,11 +5,17 @@ scratch layout had no static legality coverage, and interpret=True on
 CPU provably hides Mosaic tiling violations (round 1's bench died on
 exactly that). These tests sweep realistic serving shapes over the EXACT
 (block, array) pairs and scratch shapes the pallas_call constructs
-(`kernels/paged_attention.py::paged_blockspecs`).
+(`kernels/paged_attention.py::paged_blockspecs`): the q and out blocks,
+the pools the kernel copies from itself as `pl.ANY` inputs (or the page
+blocks the pipeline fetches where Mosaic copies no single page), and the
+two-tile buffers and semaphores within the VMEM budget.
 """
+import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.kernels.paged_attention import (check_supported_paged,
+from paddle_tpu.kernels.paged_attention import (_TILE_VMEM_BYTES,
+                                                _fold_pages, _gather_plan,
+                                                check_supported_paged,
                                                 paged_blockspecs)
 from tests.test_flash_blockspec_legality import mosaic_legal
 
@@ -35,29 +41,52 @@ def test_paged_blockspecs_tpu_legal(B, H, KVH, D, page, S, quantized):
                           kv_dtype="int8" if quantized else None)
     specs, scratch = paged_blockspecs(B, H, KVH, D, page, num_pages,
                                       quantized=quantized)
-    page_spec = ((1, KVH, page, D), (num_pages, KVH, page, D))
-    fold = specs.count(page_spec) // 2
+    cache_dtype = jnp.int8 if quantized else jnp.bfloat16
+    fold = _fold_pages(page, num_pages, KVH, D, cache_dtype)
     assert fold >= 1 and fold * page >= min(128, S)     # a token tile a step
+    values, joined, scales = _gather_plan(page, D, cache_dtype, quantized)
+    pool = (num_pages, KVH, page, D)
+    scale_pool = (num_pages, KVH, page)
+    # a pool the kernel copies from is one `pl.ANY` input (its block the
+    # whole array); a pool the pipeline fetches is `fold` page blocks
+    want = ([(pool, pool)] if values
+            else [((1, KVH, page, D), pool)] * fold) * 2
     if quantized:
-        # the int8 path streams a scale page per value page: 2*fold
-        # extra specs, every one (1, KVH, page) over the page-major
-        # fp32 scale array
-        assert len(specs) == 2 + 4 * fold
-        assert specs.count(((1, KVH, page), (num_pages, KVH, page))) \
-            == 2 * fold
-    else:
-        assert len(specs) == 2 + 2 * fold
+        want += ([(scale_pool, scale_pool)] if scales
+                 else [((1, KVH, page), scale_pool)] * fold) * 2
+    assert specs[1:-1] == want
     for block, array in specs:
         assert mosaic_legal(block, array), (
             f"illegal block {block} for array {array} "
             f"(H={H} KVH={KVH} D={D} page={page} quant={quantized})")
+    # Mosaic copies ONE page out of a pool only where the pool's minor
+    # dimension is whole 128-lane tiles
+    assert values == (D % 128 == 0)
+    assert scales == (quantized and page % 128 == 0)
+    # the two-tile buffers, then the semaphores, then the accumulator and
+    # the running stats
+    n_buf = 2 * values + 2 * scales
+    bufs = scratch[:n_buf]
+    assert all(b[0] == 2 for b in bufs)                 # two tiles each
+    if values:
+        assert bufs[0] == ((2, KVH, fold * page, D) if joined
+                           else (2, fold, 1, KVH, page, D))
+    if scales:
+        assert bufs[2] == (2, fold, 1, KVH, page)
+    assert len(scratch) == n_buf + bool(n_buf) + 3
+    if n_buf:
+        assert scratch[n_buf] == (2,)                   # DMA semaphores
+    # the VMEM the kernel holds: both tiles of the K and V buffers and the
+    # two fp32 tiles it computes on, within `_fold_pages`' budget
+    itemsize = jnp.dtype(cache_dtype).itemsize
+    tile = KVH * fold * page * D
+    assert 2 * 2 * tile * itemsize + 2 * tile * 4 <= _TILE_VMEM_BYTES
     # scratch refs: the kernel sub-slices the lane dim (m_ref[:, :, :1]),
     # which Mosaic only supports from offset 0 on a 128-lane-aligned
     # buffer; the accumulator's lanes are the head_dim
-    for shape in scratch:
-        assert shape[-1] % 128 == 0 or shape[-1] % 64 == 0, shape
-        assert shape[-1] >= 64, shape
-    stats = scratch[1:]
+    acc, *stats = scratch[-3:]
+    assert acc == (KVH, H // KVH, D)
+    assert acc[-1] % 64 == 0 and acc[-1] >= 64, acc
     assert all(s[-1] == 128 for s in stats), (
         "running-stat buffers must be exactly 128 lanes (lane-broadcast "
         f"max/sum): {stats}")
